@@ -28,7 +28,7 @@ Two implementations behind `MXNET_DECODE_KERNEL`:
           K/V block index maps read the page table via scalar
           prefetch (PrefetchScalarGridSpec) — pages stream HBM->VMEM
           per grid step instead of materializing the gathered
-          context. Interpret-mode on CPU, compiled on TPU.
+          context. Compiled on a TPU, interpreted elsewhere.
 
 The knob is read through `passes.codegen_config()` (one switch
 surface with the MXNET_FUSION_* kernel-generation flags); the
@@ -42,6 +42,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .. import utils as _utils
 from . import quant as _quant
 
 NEG_INF = -1e30
@@ -131,13 +132,28 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
 
 
 # ---------------------------------------------------------------- pallas
-def _paged_attn_kernel(page_size):
+def _paged_attn_kernel(page_size, quantized):
     """Kernel body on a (B, Bp) grid: one (page, row) tile per step,
-    online-softmax accumulated in VMEM scratch across the Bp axis."""
+    online-softmax accumulated in VMEM scratch across the Bp axis.
+
+    One query per row makes the step memory-bound (a page of K and V
+    in, H*D out), so the two contractions run on the VPU as
+    multiply + reduce with the page in its stored (P, H, D)
+    orientation: scores stay (P, H, 1) — head on sublanes, keepdims —
+    and broadcast back over D without a relayout. (An MXU dot would
+    need the head batch dimension leading on both operands; Mosaic
+    refuses "hd,phd->hp".) Quantized pools carry two extra scale refs
+    (one per K/V page, gathered by the SAME page-table index maps)
+    that dequantize each int8 page as it lands in VMEM — the pool is
+    never upcast in HBM, which is the whole point of int8 pages."""
     from jax.experimental import pallas as pl
 
-    def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref):
+    def kernel(pt_ref, len_ref, q_ref, *refs):
+        if quantized:
+            k_ref, ks_ref, v_ref, vs_ref = refs[:4]
+        else:
+            k_ref, v_ref = refs[:2]
+        o_ref, acc_ref, m_ref, l_ref = refs[-4:]
         i = pl.program_id(1)
         nbp = pl.num_programs(1)
         b = pl.program_id(0)
@@ -151,67 +167,22 @@ def _paged_attn_kernel(page_size):
         qb = q_ref[0].astype(jnp.float32)          # (H, D)
         kb = k_ref[0].astype(jnp.float32)          # (P, H, D)
         vb = v_ref[0].astype(jnp.float32)
+        if quantized:
+            # per-(slot, head) dequant: (P, H, D) int8 * (P, H, 1) f32
+            kb = kb * ks_ref[0][..., None]
+            vb = vb * vs_ref[0][..., None]
         scale = 1.0 / math.sqrt(qb.shape[-1])
-        s = jnp.einsum("hd,phd->hp", qb, kb) * scale   # (H, P)
+        s = jnp.sum(qb[None] * kb, axis=-1, keepdims=True) * scale
         pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            jnp.int32, s.shape, 0)                      # (P, H, 1)
+        s = jnp.where(pos < len_ref[b], s, NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]         # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
         corr = jnp.exp(m_prev - m_new)
-        e = jnp.exp(s - m_new)                          # (H, P)
-        l_new = l_prev * corr + e.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
-            "hp,phd->hd", e, vb)
+        e = jnp.exp(s - m_new[None])                    # (P, H, 1)
+        l_ref[...] = l_prev * corr + e.sum(axis=0)
+        acc_ref[...] = acc_ref[...] * corr + jnp.sum(e * vb, axis=0)
         m_ref[...] = m_new
-        l_ref[...] = l_new
-
-        @pl.when(i == nbp - 1)
-        def _flush():
-            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-    return kernel
-
-
-def _paged_attn_kernel_int8(page_size):
-    """Quantized twin of `_paged_attn_kernel`: two extra scale refs
-    (one per K/V page, gathered by the SAME page-table index maps)
-    dequantize each int8 page as it lands in VMEM — the pool is never
-    upcast in HBM, which is the whole point of int8 pages."""
-    from jax.experimental import pallas as pl
-
-    def kernel(pt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-               o_ref, acc_ref, m_ref, l_ref):
-        i = pl.program_id(1)
-        nbp = pl.num_programs(1)
-        b = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
-
-        qb = q_ref[0].astype(jnp.float32)          # (H, D)
-        # per-(slot, head) dequant: (P, H, D) int8 * (P, H, 1) f32
-        kb = k_ref[0].astype(jnp.float32) * ks_ref[0][..., None]
-        vb = v_ref[0].astype(jnp.float32) * vs_ref[0][..., None]
-        scale = 1.0 / math.sqrt(qb.shape[-1])
-        s = jnp.einsum("hd,phd->hp", qb, kb) * scale   # (H, P)
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < len_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        e = jnp.exp(s - m_new)                          # (H, P)
-        l_new = l_prev * corr + e.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
-            "hp,phd->hd", e, vb)
-        m_ref[...] = m_new
-        l_ref[...] = l_new
 
         @pl.when(i == nbp - 1)
         def _flush():
@@ -224,8 +195,9 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                            scale=None):
     """Flash-style paged kernel; page ids drive the K/V block index
     maps through scalar prefetch, so only the pages a row actually
-    owns ever move HBM->VMEM. Quantized pools route through the int8
-    kernel body, whose scale planes ride the same index maps."""
+    owns ever move HBM->VMEM. Quantized pools' scale planes ride the
+    same index maps. Compiled on a TPU, interpreted elsewhere
+    (utils.pallas_interpret)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -243,19 +215,14 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         return pl.BlockSpec(
             bs, lambda bb, i, pt, ln: (pt[bb, i],) + (0,) * (len(bs) - 1))
 
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda bb, i, pt, ln: (bb, 0, 0)),
-        page_spec((1, p, h, d)),
-    ]
-    operands = [q, k_pages.data]
-    if quantized:
-        in_specs.append(page_spec((1, p, h)))
-        operands.append(k_pages.scale)
-    in_specs.append(page_spec((1, p, h, d)))
-    operands.append(v_pages.data)
-    if quantized:
-        in_specs.append(page_spec((1, p, h)))
-        operands.append(v_pages.scale)
+    in_specs = [pl.BlockSpec((1, h, d), lambda bb, i, pt, ln: (bb, 0, 0))]
+    operands = [q]
+    for pool in (k_pages, v_pages):
+        in_specs.append(page_spec((1, p, h, d)))
+        operands.append(pool.data)
+        if quantized:
+            in_specs.append(page_spec((1, p, h)))
+            operands.append(pool.scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,   # page_table, lengths
         grid=(b, bp),
@@ -268,13 +235,12 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             pltpu.VMEM((h, 1), jnp.float32),
         ],
     )
-    body = (_paged_attn_kernel_int8(p) if quantized
-            else _paged_attn_kernel(p))
     fn = pl.pallas_call(
-        body,
+        _paged_attn_kernel(p, quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=jax.default_backend() == "cpu",
+        interpret=_utils.pallas_interpret(),
+        name="paged_attention",
     )
     return fn(page_table, lengths, *operands)
 

@@ -425,6 +425,16 @@ class DecodeEngine:
                 _fill(top_ps, np.float32, 1.0))
 
     # ---------------------------------------------------------- warmup
+    def _masked_step_args(self, bucket):
+        """One decode program's arguments with every row masked (length
+        0, inactive, all-scratch table): what warmup, the calibration
+        harvest and `decode_program_text` dispatch or lower."""
+        r = self.step_rows
+        return (self._params, np.zeros((r,), np.int32), self._k,
+                self._v, np.zeros((r, bucket), np.int32),
+                np.zeros((r,), np.int32), np.zeros((r,), bool),
+                *self._samp_arrays(None, None, None, None))
+
     def warmup(self):
         """Pre-trace the full program grid: every prefill length
         bucket (full + tail when the prefix cache is on, for the
@@ -474,17 +484,11 @@ class DecodeEngine:
                         jnp.int32(0), self._dk, self._dv, full_ids,
                         *sargs)
                     tok.block_until_ready()
-        r = self.step_rows
         b = self.max_batch
-        dry = (np.zeros((r,), np.int32), np.zeros((r,), np.int32),
-               np.zeros((r,), bool))
-        sarr = self._samp_arrays(None, None, None, None)
         for bucket in self.page_buckets:
-            table = np.zeros((r, bucket), np.int32)
             self._decode_fns[bucket] = self._build_decode_fn(bucket)
-            out = self._run_decode(
-                self._decode_fns[bucket], self._params, dry[0],
-                self._k, self._v, table, dry[1], dry[2], *sarr)
+            out = self._run_decode(self._decode_fns[bucket],
+                                   *self._masked_step_args(bucket))
             out.block_until_ready()
             if self.spec_enabled:
                 self._propose_fns[bucket] = self._build_propose_fn(
@@ -515,16 +519,10 @@ class DecodeEngine:
                 return
             store = _profiling.calibration_store()
             platform = jax.default_backend()
-            b = self.step_rows
-            sarr = self._samp_arrays(None, None, None, None)
             for bucket in self.page_buckets:
                 t0 = _time.perf_counter()
-                out = self._run_decode(
-                    self._decode_fns[bucket], self._params,
-                    np.zeros((b,), np.int32), self._k, self._v,
-                    np.zeros((b, bucket), np.int32),
-                    np.zeros((b,), np.int32),
-                    np.zeros((b,), bool), *sarr)
+                out = self._run_decode(self._decode_fns[bucket],
+                                       *self._masked_step_args(bucket))
                 out.block_until_ready()
                 seconds = _time.perf_counter() - t0
                 store.record(self._digest, platform,
@@ -688,6 +686,14 @@ class DecodeEngine:
                 else np.asarray(k.scale[layer, page]),
                 None if v.scale is None
                 else np.asarray(v.scale[layer, page]))
+
+    def decode_program_text(self, bucket):
+        """Compiled (post-optimization) text of one warmed decode
+        program, lowered over the warmup's all-masked rows — how
+        chip_smoke.py sees whether the Pallas kernel
+        (`tpu_custom_call`) is really inside the step."""
+        return self._decode_fns[bucket].lower(
+            *self._masked_step_args(bucket)).compile().as_text()
 
     def probe_logits(self, tokens, page_table, lengths, active):
         """Eager (un-jitted) logits of one decode step over the

@@ -24,7 +24,10 @@ member and is bootstrapped through the normal re-grow transition.
 
 Runnable as `python -m mxnet_tpu.elastic.agent --connect HOST:PORT
 --entry pkg.mod:fn [--config JSON]` — the subprocess form
-ci/check_elastic.py drives.
+ci/check_elastic.py drives. Several agents on ONE host are a CPU-only
+tier for now (start them with JAX_PLATFORMS=cpu): a chip belongs to
+one process at a time, and the combine still runs on the host
+(ROADMAP C5).
 """
 from __future__ import annotations
 

@@ -11,10 +11,11 @@ executables, and serves with ZERO traces and ZERO compiles.
 
 Two storage layers cooperate:
 
-  * the XLA layer — jax's own persistent compilation cache
-    (`jax_compilation_cache_dir`), pointed at `<dir>/xla`. Even when
-    our executable blobs are stale (jaxlib upgrade), re-compiles hit
-    jax's cache and only the cheap re-trace is paid.
+  * the XLA layer — jax's own persistent compilation cache, at
+    `<dir>/xla` unless JAX_COMPILATION_CACHE_DIR places it elsewhere
+    (`place_jax_cache`). Even when our executable blobs are stale
+    (jaxlib upgrade), re-compiles hit jax's cache and only the cheap
+    re-trace is paid.
   * our layer — `<dir>/entries/<digest>/record.json` plus
     `exe-<kind>-<sighash>.bin` blobs. record.json carries an
     environment fingerprint (format version, framework + jaxlib
@@ -54,7 +55,8 @@ import threading
 from .utils.persist import atomic_write_json, read_json
 
 #: record.json / exe blob format — bump on incompatible layout change
-RECORD_VERSION = 1
+#: (2: exe blobs name the devices they were compiled for)
+RECORD_VERSION = 2
 
 _lock = threading.Lock()
 _stats = {
@@ -122,26 +124,54 @@ def clear_overlays():
 
 
 # ----------------------------------------------------- jax's own cache
+#: where the program keeps jax's persistent compile cache when the
+#: environment names no place: one fixed, git-ignored directory inside
+#: the checkout. The path is part of what lets a later run hit, so it
+#: is never built from /tmp, a pid, a time or a flag set.
+DEFAULT_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def place_jax_cache(default=None):
+    """Decide where jax's persistent compilation cache lives — the ONE
+    setter of `jax_compilation_cache_dir` in the program; call it
+    before the first compile. JAX_COMPILATION_CACHE_DIR, when set,
+    wins: jax has read it from the environment already and nothing is
+    set in code, so the cache can be placed from outside (the chip
+    tool's output directory). Otherwise the cache goes to `default`,
+    or to DEFAULT_JAX_CACHE_DIR. Returns the directory in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = default or DEFAULT_JAX_CACHE_DIR
+    # the dir must exist BEFORE the config update — jax resolves it
+    # eagerly
+    os.makedirs(path, exist_ok=True)
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def configure_jax_cache():
-    """Point jax's persistent compilation cache at `<dir>/xla` (once
-    per dir). The dir must exist BEFORE the config update — jax
-    resolves it eagerly. Best-effort: an old jax without the knobs
-    just skips the XLA layer."""
+    """The disk tier's XLA layer: jax's persistent compilation cache
+    at `<dir>/xla` (once per dir) unless the environment placed it
+    elsewhere (`place_jax_cache`), caching every compile however
+    short."""
     global _jax_cache_configured_for
     root = cache_dir()
     if not root or _jax_cache_configured_for == root:
         return
-    xla_dir = os.path.join(root, "xla")
     try:
-        os.makedirs(xla_dir, exist_ok=True)
-        import jax
+        place_jax_cache(default=os.path.join(root, "xla"))
+    except OSError:
+        return  # unwritable root: the record layer will say so too
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.0)
-        _jax_cache_configured_for = root
-    except Exception:
-        pass
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", 0.0)
+    _jax_cache_configured_for = root
 
 
 # --------------------------------------------------------- fingerprint
@@ -151,20 +181,15 @@ def env_fingerprint():
     rides along for diagnostics (not checked — our record layout is
     covered by `format`)."""
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jaxlib_version = jaxlib.__version__
-    except Exception:  # pragma: no cover - jaxlib always ships with jax
-        jaxlib_version = jax.__version__
     from . import __version__ as framework_version
 
     return {
         "format": RECORD_VERSION,
         "framework": framework_version,
         "jax": jax.__version__,
-        "jaxlib": jaxlib_version,
+        "jaxlib": jaxlib.__version__,
         "platform": jax.default_backend(),
     }
 
@@ -307,6 +332,10 @@ def store_executable(digest, kind, sighash, compiled, root=None):
             "payload": payload,
             "in_tree": in_tree,
             "out_tree": out_tree,
+            # jax reloads an executable onto ALL the backend's devices
+            # unless told which ones it was compiled for
+            "device_ids": [d.id for d in
+                           compiled.runtime_executable().local_devices()],
         })
     except Exception:
         return None
@@ -352,10 +381,14 @@ def load_executable(digest, kind, sighash):
                 _stats["disk_stale"] += 1
             continue
         try:
+            import jax
             from jax.experimental import serialize_executable as _se
 
+            by_id = {d.id: d for d in jax.devices()}
             compiled = _se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"])
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i]
+                                   for i in blob["device_ids"]])
         except Exception:
             # a payload this jaxlib can't rehydrate IS staleness,
             # whatever the fingerprint claimed

@@ -332,7 +332,14 @@ class FleetRouter:
                    "--connect", f"127.0.0.1:{self.port}",
                    "--id", rid,
                    "--heartbeat-ms", str(int(self.hb_s * 1e3))]
-            proc = subprocess.Popen(cmd)
+            # one process per replica is a CPU-only tier for now: a
+            # chip belongs to one process at a time, so replica
+            # processes on one host cannot each take it. Serving on
+            # chips needs one process hosting device-pinned replicas
+            # (ROADMAP B6/C6).
+            env = dict(os.environ)
+            env.setdefault("JAX_PLATFORMS", "cpu")
+            proc = subprocess.Popen(cmd, env=env)
         if proc is not None:
             with self._lock:
                 self._procs[rid] = proc

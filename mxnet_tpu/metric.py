@@ -141,12 +141,17 @@ class EvalMetric:
         for label, pred in zip(labels, preds):
             l, p = _device(label), _device(pred)
             ld, pd = l.devices(), p.devices()
-            if ld != pd and len(pd) == 1:
-                # per-device metric slices: the executor output is
-                # committed to its shard's device while the label slice
-                # may live on the default device — co-locate with an
-                # async device-to-device copy (no host round-trip)
-                l = jax.device_put(l, next(iter(pd)))
+            if ld != pd:
+                # the output is committed to its shard's device — or,
+                # from a fused mesh step, sharded over the mesh — while
+                # the label may live on the default device: co-locate
+                # with an async device-to-device copy (no host
+                # round-trip), replicated when the output spans a mesh
+                if isinstance(p.sharding, jax.sharding.NamedSharding):
+                    l = jax.device_put(l, jax.sharding.NamedSharding(
+                        p.sharding.mesh, jax.sharding.PartitionSpec()))
+                elif len(pd) == 1:
+                    l = jax.device_put(l, next(iter(pd)))
             self._pending.append(fn(l, p))
             self.num_inst += self._count_device(label, pred)
 

@@ -594,7 +594,7 @@ def onehot_encode(indices, out):
 def waitall():
     # jax dispatch is per-array; effectful waits happen on access. This
     # mirrors Engine::WaitForAll for API parity.
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else lambda: None)()
+    jax.effects_barrier()
 
 
 # ----------------------------------------------------------- save / load

@@ -710,7 +710,9 @@ def _regression_output(name, fwd, bwd, aliases=()):
         for s in label.shape[1:]:
             num_output *= s
         grad = grad_scale / num_output * bwd(out, label.reshape(out.shape))
-        return grad, jnp.zeros_like(label)
+        # the label keeps its own dtype under mixed-precision compute;
+        # the cotangent must come back in the data's
+        return grad.astype(out.dtype), jnp.zeros_like(label)
 
     _core.defvjp(_core_fwd, _core_bwd)
 

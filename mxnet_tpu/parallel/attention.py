@@ -14,7 +14,8 @@ Two implementations behind one entry point `attention(...)`:
 - impl='flash': Pallas kernel forward (MXU matmuls per block), with a
   custom_vjp whose backward recomputes via the XLA path (forward-memory
   win now; dedicated backward kernel is future work).
-Runs in interpret mode on CPU so tests exercise the same kernel code.
+Interpreted off the TPU so tests exercise the same kernel code; on a
+TPU the kernel is compiled or the call raises (utils.pallas_interpret).
 """
 from __future__ import annotations
 
@@ -24,6 +25,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import utils as _utils
 
 NEG_INF = -1e30
 
@@ -160,16 +163,15 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def attention(q, k, v, causal=False, scale=None, impl="xla",
-              block_q=128, block_k=128, interpret=None):
+              block_q=128, block_k=128):
     """Multi-head attention on (B, T, H, D) tensors."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if impl == "xla":
         return attention_reference(q, k, v, causal=causal, scale=scale)
     if impl == "flash":
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
         return _flash_attention(
-            q, k, v, causal, scale, block_q, block_k, interpret
+            q, k, v, causal, scale, block_q, block_k,
+            _utils.pallas_interpret(),
         )
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -19,20 +19,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax.lax, "pcast"):
-    def _pcast_varying(x, axis_name):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-else:
-    # older jax: shard_map has no varying-axis tracking, every
-    # per-device value is implicitly varying — identity is exact
-    def _pcast_varying(x, axis_name):
-        return x
+
+def _pcast_varying(x, axis_name):
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def _stage_apply(fn, params, x, stage_idx):
@@ -249,17 +241,10 @@ def pipeline_apply_hetero(stage_fns, flat_params, flat_auxs,
         )
         return outs, a_var[None]
 
-    kwargs = {}
-    if not hasattr(jax.lax, "pcast"):
-        # without pcast the replication checker cannot see that every
-        # lax.switch branch is uniformly device-varying; disable it
-        # (the modern checker validates this same program via pcast)
-        kwargs["check_rep"] = False
     fn_sharded = shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),
         out_specs=(P(), P(axis_name)),
-        **kwargs,
     )
     return fn_sharded(flat_params, flat_auxs, microbatches)

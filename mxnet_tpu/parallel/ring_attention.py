@@ -25,10 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 
@@ -43,12 +40,8 @@ def _ring_attention_shard(q, k, v, *, axis_name, causal, scale):
 
     # pcast: mark the accumulators as device-varying along the ring axis
     # so the fori_loop carry types match the (varying) body outputs.
-    # Older jax has no varying-axis tracking (every per-device value is
-    # implicitly varying) — identity there.
     def _varying(x):
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, (axis_name,), to="varying")
-        return x
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     o0 = _varying(jnp.zeros((b, h, t_local, d), jnp.float32))
     m0 = _varying(jnp.full((b, h, t_local), NEG_INF, jnp.float32))

@@ -27,24 +27,32 @@ Two halves, two call sites:
                           parity-verified kernel or a counted fallback
                           reason (platform / irregular_shapes /
                           unsupported_dtype / calibrated_slower /
-                          parity), and returns the node-index routing
+                          compile_refused / parity), and returns the
+                          node-index routing
                           plus the exec-cache key component — fused and
                           fallback binds never collide on one program.
 
 Templates (all (8, 128)-tile-aware through cost_model.tile_sublanes):
 
-  elementwise     same-shape chain, tiled (sublanes, 128) grid when the
-                  2-D view divides the f32 register tile, whole-array
+  elementwise     same-shape chain on a grid of VMEM-sized blocks (a
+                  whole number of register tiles each, `_tiling`) when
+                  the 2-D view divides the register tile, whole-array
                   single block in interpret mode otherwise
-  reduction       chain + absorbed axis=None reduce: one block, the
-                  kernel writes the (1, 1) scalar
+  reduction       chain + absorbed axis=None reduce over the same grid:
+                  each step reduces its block and folds the partial
+                  into the (1, 1) SMEM scalar
   scale_bias_act  the mul -> add -> activation special case of the
                   elementwise emitter (classified so the stats view and
                   the calibration records can tell it apart)
 
-Every generated kernel is verified in interpret mode against its lax
-twin at build time (<= 1e-6, fwd; bwd is the lax twin's vjp by
-construction via custom_vjp) and both paths are timed into the
+Every generated kernel is compiled ALONE at build time, ahead of the
+step that will inline it: on a TPU that is the Mosaic compile, and a
+refusal is a counted `compile_refused` fallback found here, not an
+error inside the whole step's compile. The executable that comes out —
+compiled on a TPU, interpreted only off it or under
+MXNET_FUSION_INTERPRET (utils.pallas_interpret) — is the one verified
+against its jitted lax twin (fwd; bwd is the lax twin's vjp by
+construction via custom_vjp) and both are timed, warm, into the
 profiling `CalibrationStore` under kind="kernel" / "kernel_lax" — the
 autotuner's `choose_fusion_kernel` reads them back, so fuse-vs-fallback
 is a measured decision, never a guess. Groups that do not lower are
@@ -59,6 +67,7 @@ choice and graph codegen share one switch surface.
 from __future__ import annotations
 
 import hashlib
+import logging
 import threading
 import time
 from dataclasses import dataclass
@@ -81,6 +90,25 @@ _ACT_OPS = frozenset({"relu", "sigmoid", "tanh", "Activation"})
 
 PARITY_RTOL = 1e-6
 PARITY_ATOL = 1e-6
+
+
+def _parity_rtol(template, dtype):
+    """Build-time parity tolerance, set from the dtype: PARITY_RTOL for
+    an f32 elementwise chain (the same ops in the same order), a few
+    ulps of a narrower output (kernel and twin may round in different
+    places), and 10x that for a reduction, which adds in another order
+    than XLA's reduce."""
+    import jax.numpy as jnp  # np.finfo does not know bfloat16
+
+    rtol = max(PARITY_RTOL, 4 * float(jnp.finfo(dtype).eps))
+    return 10 * rtol if template == "reduction" else rtol
+
+
+# VMEM one grid step's operand blocks may take together. The pipeline
+# double-buffers every block, so this is half of what the kernel holds;
+# 4 MiB keeps it under the smallest default scoped-VMEM limit (16 MiB,
+# v5e) with room for the chain's temporaries.
+_BLOCK_BYTES = 4 << 20
 
 
 class _Unsupported(Exception):
@@ -392,23 +420,52 @@ def _norm2d(shape):
     return (r, shape[-1])
 
 
-def _tiling(r, c, dtype, interpret):
-    """(block, grid) over the 2-D view: (sublanes, 128) tiles when the
-    view divides the register tile; whole-array single block in
-    interpret mode; unsupported otherwise (real-TPU ragged tails fall
-    back to lax rather than pad inside a generated kernel)."""
+def _largest_divisor(n, unit, cap):
+    """Largest multiple of `unit` that divides `n` and is <= cap
+    (`unit` itself when nothing larger fits; n % unit == 0)."""
+    best = unit
+    for m in range(unit, min(n, cap) + 1, unit):
+        if n % m == 0:
+            best = m
+    return best
+
+
+def _tiling(r, c, dtype, interpret, n_operands):
+    """(block, grid) over the 2-D view. Tile-regular views get blocks
+    of whole (sublanes, 128) register tiles sized so that all
+    `n_operands` blocks of one grid step fit `_BLOCK_BYTES` — lanes
+    first (contiguous HBM rows), then sublanes; one register tile per
+    step would make a ResNet-sized activation a 100k-step grid.
+    Irregular views run as one whole-array block in interpret mode and
+    are unsupported otherwise (real-TPU ragged tails fall back to lax
+    rather than pad inside a generated kernel)."""
     sub = tile_sublanes(dtype)
-    if r % sub == 0 and c % TILE_LANES == 0:
-        return (sub, TILE_LANES), (r // sub, c // TILE_LANES)
-    if interpret:
-        return (r, c), (1, 1)
-    raise _Unsupported("irregular_shapes")
+    if r % sub or c % TILE_LANES:
+        if interpret:
+            return (r, c), (1, 1)
+        raise _Unsupported("irregular_shapes")
+    # budget in 4-byte elements: chains compute in f32 whatever they
+    # load
+    cap = max(sub * TILE_LANES, _BLOCK_BYTES // (4 * n_operands))
+    bc = _largest_divisor(c, TILE_LANES, cap // sub)
+    br = _largest_divisor(r, sub, cap // bc)
+    return (br, bc), (r // br, c // bc)
+
+
+def _load_f32(refs):
+    """Block values widened to f32: the VPU computes in f32 (v5e has
+    no narrower vector ALU) and Mosaic refuses a chain's scalar
+    constants at bf16; the emitters round once, on the store — what
+    XLA's own fusion of the lax twin does."""
+    import jax.numpy as jnp
+
+    return [ref[...].astype(jnp.float32) for ref in refs]
 
 
 def _elementwise_kernel(spec, ext_avals, out_aval, interpret):
     """Tiled elementwise-chain kernel: every external input shares the
     output shape, each grid step evaluates the whole chain on one
-    (sublanes, 128) block."""
+    block."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -418,11 +475,13 @@ def _elementwise_kernel(spec, ext_avals, out_aval, interpret):
         if tuple(s) != out_shape:
             raise _Unsupported("irregular_shapes")
     r, c = _norm2d(out_shape)
-    block, grid = _tiling(r, c, out_aval.dtype, interpret)
+    block, grid = _tiling(r, c, out_aval.dtype, interpret,
+                          len(ext_avals) + 1)
     chain = group_lax_fn(spec)
 
     def kernel(*refs):
-        refs[-1][...] = chain(*[ref[...] for ref in refs[:-1]])
+        out_ref = refs[-1]
+        out_ref[...] = chain(*_load_f32(refs[:-1])).astype(out_ref.dtype)
 
     call = pl.pallas_call(
         kernel,
@@ -453,28 +512,54 @@ def _scale_bias_act_kernel(spec, ext_avals, out_aval, interpret):
 
 
 def _reduction_kernel(spec, ext_avals, out_aval, interpret):
-    """Chain + absorbed axis=None reduction in one kernel: a single
-    whole-array block evaluates the elementwise body and writes the
-    (1, 1) scalar (exact — no padded lanes enter the reduction)."""
+    """Chain + absorbed axis=None reduction in one kernel, on the
+    elementwise grid: each step evaluates the chain (reduce included)
+    on its block and folds that partial into the (1, 1) result, which
+    lives in SMEM across the grid — Mosaic stores vectors, not
+    scalars, to VMEM. No padded lanes enter the reduction."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     shapes = {tuple(s) for s, _ in ext_avals}
     if len(shapes) != 1:
         raise _Unsupported("irregular_shapes")
     r, c = _norm2d(shapes.pop())
-    if not interpret and (r % tile_sublanes(out_aval.dtype)
-                          or c % TILE_LANES):
-        raise _Unsupported("irregular_shapes")
-    chain = group_lax_fn(spec)
+    block, grid = _tiling(r, c, out_aval.dtype, interpret,
+                          len(ext_avals))
+    # the chain WITHOUT its trailing reduce (whose only input is the
+    # chain's last value, _absorb_reductions): the kernel reduces each
+    # block itself so the partial is a true scalar
+    body = group_lax_fn(spec[:-1])
+    reduce_op = spec[-1][0]
+    partial, fold = {
+        "sum": (jnp.sum, jnp.add), "mean": (jnp.sum, jnp.add),
+        "max": (jnp.max, jnp.maximum), "min": (jnp.min, jnp.minimum),
+    }[reduce_op]
 
     def kernel(*refs):
-        val = chain(*[ref[...] for ref in refs[:-1]])
-        refs[-1][0, 0] = jnp.reshape(val, ())
+        out_ref = refs[-1]
+        part = partial(body(*_load_f32(refs[:-1])))
+        if reduce_op == "mean":
+            part = part * (1.0 / (r * c))
+        part = part.astype(out_ref.dtype)
+        first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+
+        @pl.when(first)
+        def _init():
+            out_ref[0, 0] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _fold():
+            out_ref[0, 0] = fold(out_ref[0, 0], part)
 
     call = pl.pallas_call(
         kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec(block, lambda i, j: (i, j))
+                  for _ in ext_avals],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), out_aval.dtype),
         interpret=interpret,
     )
@@ -508,18 +593,28 @@ def _seeded_inputs(ext_avals, digest):
     return out
 
 
-def _parity_and_time(kernel_call, lax_fn, ext_avals, digest):
-    """(ok, kernel_s, lax_s): interpret-mode kernel output vs the lax
-    twin on seeded concrete inputs, both wall-timed."""
-    ins = _seeded_inputs(ext_avals, digest)
-    t0 = time.perf_counter()
-    got = np.asarray(kernel_call(*ins))
-    t_kernel = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    want = np.asarray(lax_fn(*ins))
-    t_lax = time.perf_counter() - t0
+def _parity_and_time(kernel_call, lax_call, ext_avals, digest, rtol):
+    """(ok, kernel_s, lax_s): the built kernel's output vs its lax twin
+    on seeded concrete inputs. Both callables are already jitted; the
+    first call of each is the warm-up (and the parity sample), the
+    second is the one timed, to completion."""
+    import jax
+    import jax.numpy as jnp
+
+    ins = [jnp.asarray(a) for a in _seeded_inputs(ext_avals, digest)]
+
+    def sample(fn):
+        out = np.asarray(fn(*ins))
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*ins))
+        return out, time.perf_counter() - t0
+
+    got, t_kernel = sample(kernel_call)
+    want, t_lax = sample(lax_call)
     ok = (got.shape == want.shape
-          and np.allclose(got, want, rtol=PARITY_RTOL, atol=PARITY_ATOL))
+          and np.allclose(got.astype(np.float32),
+                          want.astype(np.float32), rtol=rtol,
+                          atol=PARITY_ATOL))
     return ok, t_kernel, t_lax
 
 
@@ -551,35 +646,42 @@ def _tuned_choice(digest, platform):
         return "pallas"
 
 
-def _build_and_verify(spec, ext_avals, digest, template, cfg, platform):
-    """Build one group's kernel for one shape signature: emit, verify
-    interpret-mode parity vs the lax twin, time both into calibration,
-    wrap in custom_vjp. Returns ("ok", callable) or ("demoted",
-    reason)."""
+def _build_and_verify(spec, ext_avals, digest, template, platform):
+    """Build one group's kernel for one shape signature: emit, compile
+    it alone, verify that executable against the jitted lax twin, time
+    both into calibration, wrap in custom_vjp. Returns ("ok",
+    callable) or ("demoted", reason)."""
     import jax
 
+    from .. import utils as _utils
+
     lax_fn = group_lax_fn(spec)
+    structs = [jax.ShapeDtypeStruct(s, d) for s, d in ext_avals]
     try:
-        out_aval = jax.eval_shape(
-            lax_fn, *[jax.ShapeDtypeStruct(s, d) for s, d in ext_avals])
+        out_aval = jax.eval_shape(lax_fn, *structs)
     except Exception:
         return ("demoted", "irregular_shapes")
     if (not all(np.issubdtype(d, np.floating) for _, d in ext_avals)
             or not np.issubdtype(np.dtype(out_aval.dtype), np.floating)):
         return ("demoted", "unsupported_dtype")
-    interpret = bool(cfg.interpret) or platform != "tpu"
-    emit = _EMITTERS[template]
     try:
-        kernel = emit(spec, ext_avals, out_aval, interpret)
-        parity_kernel = kernel if interpret else \
-            emit(spec, ext_avals, out_aval, True)
+        kernel = _EMITTERS[template](spec, ext_avals, out_aval,
+                                     _utils.pallas_interpret())
     except _Unsupported as e:
         return ("demoted", str(e))
     except Exception:
         return ("demoted", "irregular_shapes")
     try:
+        compiled = jax.jit(kernel).lower(*structs).compile()
+    except Exception as e:
+        logging.getLogger(__name__).warning(
+            "fusion group %s (%s): generated kernel refused by the "
+            "compiler: %s", digest, template, e)
+        return ("demoted", "compile_refused")
+    try:
         ok, t_kernel, t_lax = _parity_and_time(
-            parity_kernel, lax_fn, ext_avals, digest)
+            compiled, jax.jit(lax_fn), ext_avals, digest,
+            _parity_rtol(template, out_aval.dtype))
     except Exception:
         return ("demoted", "parity")
     with _LOCK:
@@ -637,7 +739,7 @@ def _lower_group(graph, members, digest, cfg, platform, order,
     with _LOCK:
         cached = _KERNELS.get(key)
     if cached is None:
-        cached = _build_and_verify(spec, avals, digest, template, cfg,
+        cached = _build_and_verify(spec, avals, digest, template,
                                    platform)
         with _LOCK:
             _KERNELS[key] = cached

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import jax
 
+from . import utils as _utils
 from .base import MXNetError
 from .context import current_context
 from .ndarray import NDArray
@@ -40,20 +41,19 @@ class PallasKernel(object):
         self.pallas_kwargs = pallas_kwargs
         self._compiled = {}
 
-    def push(self, ins, out_shapes, out_dtypes=None, interpret=None):
-        """Launch on a list of NDArrays; returns list of NDArrays."""
+    def compiled(self, out_shapes, out_dtypes=None):
+        """The jitted pallas_call for these output shapes — compiled
+        on a TPU, interpreted elsewhere (utils.pallas_interpret)."""
         from jax.experimental import pallas as pl
         import numpy as np
 
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
+        interpret = _utils.pallas_interpret()
         if out_dtypes is None:
             out_dtypes = [np.float32] * len(out_shapes)
         key = (
             tuple(tuple(s) for s in out_shapes),
             tuple(str(d) for d in out_dtypes),
             bool(interpret),
-            tuple((a.shape, str(a.dtype)) for a in ins),
         )
         fn = self._compiled.get(key)
         if fn is None:
@@ -71,8 +71,12 @@ class PallasKernel(object):
             )
             fn = jax.jit(call)
             self._compiled[key] = fn
+        return fn
+
+    def push(self, ins, out_shapes, out_dtypes=None):
+        """Launch on a list of NDArrays; returns list of NDArrays."""
         args = [a._data if isinstance(a, NDArray) else a for a in ins]
-        out = fn(*args)
+        out = self.compiled(out_shapes, out_dtypes)(*args)
         if not isinstance(out, (tuple, list)):
             out = (out,)
         ctx = current_context()

@@ -43,6 +43,18 @@ def getenv(name):
     return spec.type(raw)
 
 
+def pallas_interpret():
+    """Whether a Pallas kernel runs in interpret mode — the ONE place
+    that decides. Off the TPU it must (Mosaic compiles for TPUs only).
+    On a TPU a kernel is compiled or the call raises; only
+    MXNET_FUSION_INTERPRET=1 (the parity-debugging hatch) selects the
+    interpreter there."""
+    import jax
+
+    return (jax.default_backend() != "tpu"
+            or bool(getenv("MXNET_FUSION_INTERPRET")))
+
+
 def describe_env():
     """All registered vars with current values (env_var.md analog)."""
     lines = []
@@ -575,7 +587,8 @@ register_env(
     "directory holding per-entry records (optimized canonical graph "
     "JSON, input signatures, sharding digest) plus the AOT-serialized "
     "executables of every captured program, with jax's persistent "
-    "compilation cache configured underneath at <dir>/xla. A process "
+    "compilation cache underneath at <dir>/xla (unless "
+    "JAX_COMPILATION_CACHE_DIR places it elsewhere). A process "
     "restart then rebinds with ZERO jax traces and ZERO XLA compiles "
     "(cache_stats()['disk_hits'] counts the wins). Empty = in-memory "
     "cache only, the pre-disk behavior (docs/perf.md 'Cold starts').",

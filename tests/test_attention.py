@@ -32,7 +32,6 @@ def test_flash_matches_reference(causal):
     ref = attention_reference(q, k, v, causal=causal)
     out = attention(
         q, k, v, causal=causal, impl="flash", block_q=16, block_k=16,
-        interpret=True,
     )
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
 
@@ -43,7 +42,6 @@ def test_flash_gradients_flow():
     def loss(q, k, v):
         return attention(
             q, k, v, impl="flash", block_q=16, block_k=16,
-            interpret=True,
         ).sum()
 
     def loss_ref(q, k, v):

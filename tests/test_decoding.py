@@ -5,6 +5,7 @@ parity against an unbatched reference loop, preempt-then-readmit
 bit-identical continuations, per-step deadlines, streaming, the
 zero-retrace guarantee over the pre-traced decode grid, and the
 `decodingStats` view's pinned key shape."""
+import random
 import time
 
 import numpy as np
@@ -51,9 +52,12 @@ def _model(**kw):
     return dec.DecodedModel("lm", 1, PARAMS, CFG, **kw)
 
 
-def _ref_greedy(prompt, n, cfg=CFG, eos=None):
+def _ref_greedy(prompt, n, cfg=CFG, eos=None, max_context=None):
     """Unbatched single-request reference: one dense forward per
-    token — the parity oracle for every scheduler test."""
+    token — the parity oracle for every scheduler test. With
+    `max_context` it also stops where the engine's capacity stop
+    (finish reason "length") does: once the cache would hold
+    max_context tokens, the token just emitted is the last."""
     eos = cfg.eos_id if eos is None else eos
     toks, out = list(prompt), []
     for _ in range(n):
@@ -63,6 +67,8 @@ def _ref_greedy(prompt, n, cfg=CFG, eos=None):
         if nxt == eos:
             break
         out.append(nxt)
+        if max_context is not None and len(toks) >= max_context:
+            break
         toks.append(nxt)
     return out
 
@@ -238,15 +244,22 @@ def test_continuous_batching_parity_concurrent():
     m = _model(max_batch=4, num_pages=64, page_buckets=(1, 2, 4))
     try:
         floor = m.engine.traces()
-        rng = mx.random.py_rng()
+        # a generator of its own: the jobs must not depend on which
+        # tests drew from the process-wide one before this
+        rng = random.Random(0)
         jobs = [(
             [rng.randrange(2, CFG.vocab) for _ in
              range(rng.randint(1, 12))],
             rng.randint(1, 8),
         ) for _ in range(12)]
+        # 12 prompt tokens + 8 new ones overrun the 16-token context
+        # (4 pages of 4): such a stream ends at capacity, in the
+        # engine and in the reference alike
+        cap = m.engine.max_context
+        assert any(len(p) + n - 1 > cap for p, n in jobs)
         futs = [m.submit(p, max_new_tokens=n) for p, n in jobs]
         for (p, n), f in zip(jobs, futs):
-            assert f.result(120) == _ref_greedy(p, n)
+            assert f.result(120) == _ref_greedy(p, n, max_context=cap)
         assert m.engine.traces() == floor
         snap = m.stats.snapshot()
         assert snap["completed"] == 12

@@ -221,3 +221,24 @@ def test_score_parity_with_padded_last_batch(monkeypatch):
     assert dev_res["accuracy"] == host_res["accuracy"]
     assert dev_res["cross-entropy"] == pytest.approx(
         host_res["cross-entropy"], rel=1e-6)
+
+
+def test_mesh_sharded_preds_take_a_default_device_label():
+    """Module(context=[...], kvstore="tpu").fit — the README's
+    data-parallel path: the fused mesh step's output is sharded over
+    the mesh while the iterator's label sits on the default device;
+    the device metric must co-locate them, not raise "incompatible
+    devices"."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+    rng = np.random.RandomState(0)
+    label = rng.randint(0, 3, size=(8,)).astype("float32")
+    pred = rng.rand(8, 3).astype("float32")
+    sharded = mx.nd.NDArray(jax.device_put(
+        pred, NamedSharding(mesh, P("data"))))
+    host, dev = M.Accuracy(), M.Accuracy()
+    host.update([mx.nd.array(label)], [mx.nd.array(pred)])
+    dev.update_device([mx.nd.array(label)], [sharded])
+    assert dev.get() == host.get()

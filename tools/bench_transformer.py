@@ -62,7 +62,7 @@ def main():
     from mxnet_tpu.models.transformer import get_transformer
 
     dev = jax.devices()[0]
-    on_accel = dev.platform != "cpu"
+    on_accel = dev.platform == "tpu"
     dtype = args.dtype or ("bfloat16" if on_accel else "float32")
 
     mesh_shape = None

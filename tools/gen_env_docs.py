@@ -39,6 +39,11 @@ def render():
         "sharded code without hardware (tests/conftest.py does this).",
         "- `XLA_PYTHON_CLIENT_MEM_FRACTION` / `_PREALLOCATE` — set via "
         "`mx.set_memory_fraction()`; see docs/perf.md.",
+        "- `JAX_COMPILATION_CACHE_DIR` — where jax's persistent compile "
+        "cache lives. When set, no code moves it; unset, `bench.py` and "
+        "`chip_smoke.py` use `<checkout>/.jax_cache` and the exec-cache "
+        "disk tier uses `<MXNET_EXEC_CACHE_DIR>/xla` "
+        "(`exec_cache_disk.place_jax_cache`).",
         "",
     ]
     return "\n".join(lines)

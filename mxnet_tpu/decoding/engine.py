@@ -49,6 +49,7 @@ import numpy as np
 from .. import profiler as _profiler
 from .. import utils as _utils
 from ..serving.batcher import pick_bucket
+from ..telemetry import trace as _trace
 from . import config as _cfg
 from . import attention as _attn
 from . import model as _model
@@ -185,11 +186,19 @@ class DecodeEngine:
              self.kv_dtype)
         ).encode()).hexdigest()[:12]
 
-    def _instrument(self, fn, kind):
-        """Route one grid program through profiling's executable
-        accounting (deviceStats). Transparent: the wrapper dispatches
-        through the SAME compiled executable a raw jit would build, so
-        trace counts (`_note_trace`) are unchanged."""
+    def _jit(self, impl, name, kind, donate):
+        """jit one grid program under a name of its own and route it
+        through profiling's executable accounting (deviceStats).
+
+        The closure is renamed before `jax.jit`, so the compiled module
+        is `jit_<name>` — what a profiler capture's `XLA Modules` line
+        and `profiling.scope_map` key on (instruction names such as
+        `fusion.67` repeat across programs; module names must not).
+        The accounting wrapper is transparent: it dispatches through
+        the SAME compiled executable a raw jit would build, so trace
+        counts (`_note_trace`) are unchanged."""
+        impl.__name__ = impl.__qualname__ = name
+        fn = jax.jit(impl, donate_argnums=donate if self._donate else ())
         try:
             from .. import profiling as _profiling
 
@@ -216,6 +225,13 @@ class DecodeEngine:
         extension of the serving tier's MXNET_SERVING_LENGTH_BUCKETS
         grid, derived instead of hand-configured)."""
         return tuple(b * self.page_size for b in self.page_buckets)
+
+    def step_program(self, bucket):
+        """Module name of the program that takes a step's device time
+        at this pages bucket, as a capture's `XLA Modules` line and
+        `profiling.scope_map` have it (see `_jit`)."""
+        kind = "verify" if self.spec_enabled else "decode"
+        return f"jit_{kind}_p{bucket}"
 
     def traces(self):
         """Total prefill/decode/copy traces so far (see docstring)."""
@@ -298,9 +314,8 @@ class DecodeEngine:
                 lengths, active, seeds, temps, top_ks, top_ps,
                 cfg=cfg, attn=attn, with_stats=guard)
 
-        donate = (2, 3) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                f"decode@{bucket}")
+        return self._jit(impl, f"decode_p{bucket}", f"decode@{bucket}",
+                         (2, 3))
 
     def _build_prefill_fn(self, length_bucket, name="prefill",
                           cfg=None):
@@ -325,9 +340,8 @@ class DecodeEngine:
                 params, tokens, length, k_pages, v_pages, page_ids,
                 seed, temp, top_k, top_p, cfg=cfg, attn_fn=attn_fn)
 
-        donate = (3, 4) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                f"{name}@{length_bucket}")
+        return self._jit(impl, f"{name}_t{length_bucket}",
+                         f"{name}@{length_bucket}", (3, 4))
 
     def _build_tail_fn(self, length_bucket, name="prefill_tail",
                        cfg=None):
@@ -342,9 +356,8 @@ class DecodeEngine:
                 page_ids, seed, temp, top_k, top_p, cfg=cfg,
                 attn_multi=attn_multi)
 
-        donate = (4, 5) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                f"{name}@{length_bucket}")
+        return self._jit(impl, f"{name}_t{length_bucket}",
+                         f"{name}@{length_bucket}", (4, 5))
 
     def _build_propose_fn(self, bucket):
         cfg, attn, k = self.draft_cfg, self._attn, self.spec_k
@@ -357,9 +370,8 @@ class DecodeEngine:
                 active, seeds, temps, top_ks, top_ps, cfg=cfg,
                 attn=attn, k=k)
 
-        donate = (2, 3) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                f"draft@{bucket}")
+        return self._jit(impl, f"draft_p{bucket}", f"draft@{bucket}",
+                         (2, 3))
 
     def _build_verify_fn(self, bucket):
         cfg, attn_multi, k = self.cfg, self._attn_multi, self.spec_k
@@ -373,9 +385,8 @@ class DecodeEngine:
                 page_table, lengths, active, use_draft, seeds, temps,
                 top_ks, top_ps, cfg=cfg, attn_multi=attn_multi, k=k)
 
-        donate = (4, 5) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                f"verify@{bucket}")
+        return self._jit(impl, f"verify_p{bucket}", f"verify@{bucket}",
+                         (4, 5))
 
     def _build_copy_fn(self):
         # the pool argument is a quant.KVPool pytree: ONE traced
@@ -391,9 +402,7 @@ class DecodeEngine:
             scale = pool.scale.at[:, dst].set(pool.scale[:, src])
             return _quant.KVPool(data, scale)
 
-        donate = (0,) if self._donate else ()
-        return self._instrument(jax.jit(impl, donate_argnums=donate),
-                                "copy_page")
+        return self._jit(impl, "copy_page", "copy_page", (0,))
 
     # --------------------------------------------- fixed-dtype packing
     @staticmethod
@@ -609,23 +618,32 @@ class DecodeEngine:
         so every dispatch replays the one warmed shape, and the
         return is sliced back to the caller's width. Per-row sampling
         params default to greedy. Returns next tokens as a host array
-        (the stream/EOS sync — one fetch per step, by design)."""
+        (the stream/EOS sync — one fetch per step, by design).
+
+        Two leaf spans partition the call: `engine.launch` (row
+        padding, host-to-device transfers, the program call returning)
+        and `engine.fetch` (the wait for the device and the copy
+        back)."""
         bucket = page_table.shape[1]
         b_in = len(tokens)
         r = self.step_rows
-        tokens = self._pad_rows(tokens, np.int32, 0)
-        lengths = self._pad_rows(lengths, np.int32, 0)
-        active = self._pad_rows(active, bool, False)
-        if page_table.shape[0] < r:
-            page_table = np.concatenate(
-                [np.asarray(page_table, np.int32),
-                 np.full((r - page_table.shape[0], bucket),
-                         SCRATCH_PAGE, np.int32)])
-        sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
-        out = self._run_decode(
-            self._decode_fns[bucket], self._params, tokens,
-            self._k, self._v, page_table, lengths, active, *sarr)
-        return np.asarray(out)[:b_in]
+        with _trace.span("engine.launch"):
+            tokens = self._pad_rows(tokens, np.int32, 0)
+            lengths = self._pad_rows(lengths, np.int32, 0)
+            active = self._pad_rows(active, bool, False)
+            if page_table.shape[0] < r:
+                page_table = np.concatenate(
+                    [np.asarray(page_table, np.int32),
+                     np.full((r - page_table.shape[0], bucket),
+                             SCRATCH_PAGE, np.int32)])
+            sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
+            out = self._run_decode(
+                self._decode_fns[bucket], self._params, tokens,
+                self._k, self._v, page_table, lengths, active, *sarr)
+        with _trace.span("engine.fetch"):
+            # the wait for the device and the copy back
+            host = np.asarray(out)
+        return host[:b_in]
 
     def _pad_rows(self, arr, dtype, fill):
         arr = np.asarray(arr, dtype)
@@ -644,16 +662,18 @@ class DecodeEngine:
         Returns (tokens_out (B, K+1), n_emit (B,)) as host arrays in
         ONE fetch — row b emits tokens_out[b, :n_emit[b]]."""
         bucket = page_table.shape[1]
-        sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
-        use_draft = np.asarray(use_draft, bool)
-        drafts, q_dists, self._dk, self._dv = self._propose_fns[
-            bucket](self._draft_params, tokens, self._dk, self._dv,
-                    page_table, lengths, active, *sarr)
-        tokens_out, n_emit, self._k, self._v = self._verify_fns[
-            bucket](self._params, tokens, drafts, q_dists, self._k,
-                    self._v, page_table, lengths, active, use_draft,
-                    *sarr)
-        host_toks, host_n = jax.device_get((tokens_out, n_emit))
+        with _trace.span("engine.launch"):
+            sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
+            use_draft = np.asarray(use_draft, bool)
+            drafts, q_dists, self._dk, self._dv = self._propose_fns[
+                bucket](self._draft_params, tokens, self._dk, self._dv,
+                        page_table, lengths, active, *sarr)
+            tokens_out, n_emit, self._k, self._v = self._verify_fns[
+                bucket](self._params, tokens, drafts, q_dists, self._k,
+                        self._v, page_table, lengths, active, use_draft,
+                        *sarr)
+        with _trace.span("engine.fetch"):
+            host_toks, host_n = jax.device_get((tokens_out, n_emit))
         return np.asarray(host_toks), np.asarray(host_n)
 
     def copy_page(self, src, dst):

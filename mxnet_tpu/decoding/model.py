@@ -175,24 +175,34 @@ def prefill_forward(params, tokens, length, k_pages, v_pages,
                           SCRATCH_PAGE)
     slots = pos % page_size
 
-    x = params["embed"][tokens] + params["pos"][:t][None]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens] + params["pos"][:t][None]
     for i in range(cfg.n_layers):
-        h1 = _rms(x, params[f"l{i}.ln1"])
-        q, k, v = _qkv(params, i, h1, cfg)
-        k_pages, _ = _quant.kv_scatter(k_pages, i, tgt_pages, slots,
-                                       k[0])
-        v_pages, _ = _quant.kv_scatter(v_pages, i, tgt_pages, slots,
-                                       v[0])
-        if attn_fn is None:
-            o = _dense_causal_attention(q, k, v, scale)
-        else:
-            o = attn_fn(q, k, v)
-        x = x + o.reshape(1, t, cfg.d_model) @ params[f"l{i}.wo"]
-        x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
-    x = _rms(x, params["ln_f"])
-    last = x[0, length - 1]
-    logits = last @ params["embed"].T
-    tok = _pick_token(logits, seed, length, temperature, top_k, top_p)
+        with jax.named_scope(f"l{i}"):
+            with jax.named_scope("qkv"):
+                h1 = _rms(x, params[f"l{i}.ln1"])
+                q, k, v = _qkv(params, i, h1, cfg)
+            with jax.named_scope("kv_write"):
+                k_pages, _ = _quant.kv_scatter(k_pages, i, tgt_pages,
+                                               slots, k[0])
+                v_pages, _ = _quant.kv_scatter(v_pages, i, tgt_pages,
+                                               slots, v[0])
+            with jax.named_scope("attn"):
+                if attn_fn is None:
+                    o = _dense_causal_attention(q, k, v, scale)
+                else:
+                    o = attn_fn(q, k, v)
+            with jax.named_scope("out"):
+                x = x + o.reshape(1, t, cfg.d_model) @ params[f"l{i}.wo"]
+            with jax.named_scope("mlp"):
+                x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
+    with jax.named_scope("logits"):
+        x = _rms(x, params["ln_f"])
+        last = x[0, length - 1]
+        logits = last @ params["embed"].T
+    with jax.named_scope("sample"):
+        tok = _pick_token(logits, seed, length, temperature, top_k,
+                          top_p)
     return tok, k_pages, v_pages
 
 
@@ -227,22 +237,32 @@ def tail_prefill_forward(params, tokens, start, length, k_pages,
     slots = pos % page_size
     pos_safe = jnp.clip(pos, 0, cfg.max_len - 1)
 
-    x = params["embed"][tokens] + params["pos"][pos_safe][None]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens] + params["pos"][pos_safe][None]
     for i in range(cfg.n_layers):
-        h1 = _rms(x, params[f"l{i}.ln1"])
-        q, k, v = _qkv(params, i, h1, cfg)
-        k_pages, _ = _quant.kv_scatter(k_pages, i, tgt_pages, slots,
-                                       k[0])
-        v_pages, _ = _quant.kv_scatter(v_pages, i, tgt_pages, slots,
-                                       v[0])
-        o = attn_multi(q, k_pages.layer(i), v_pages.layer(i),
-                       page_ids[None], pos_safe[None])
-        x = x + o.reshape(1, t, cfg.d_model) @ params[f"l{i}.wo"]
-        x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
-    x = _rms(x, params["ln_f"])
-    last = x[0, length - 1 - start]
-    logits = last @ params["embed"].T
-    tok = _pick_token(logits, seed, length, temperature, top_k, top_p)
+        with jax.named_scope(f"l{i}"):
+            with jax.named_scope("qkv"):
+                h1 = _rms(x, params[f"l{i}.ln1"])
+                q, k, v = _qkv(params, i, h1, cfg)
+            with jax.named_scope("kv_write"):
+                k_pages, _ = _quant.kv_scatter(k_pages, i, tgt_pages,
+                                               slots, k[0])
+                v_pages, _ = _quant.kv_scatter(v_pages, i, tgt_pages,
+                                               slots, v[0])
+            with jax.named_scope("attn"):
+                o = attn_multi(q, k_pages.layer(i), v_pages.layer(i),
+                               page_ids[None], pos_safe[None])
+            with jax.named_scope("out"):
+                x = x + o.reshape(1, t, cfg.d_model) @ params[f"l{i}.wo"]
+            with jax.named_scope("mlp"):
+                x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
+    with jax.named_scope("logits"):
+        x = _rms(x, params["ln_f"])
+        last = x[0, length - 1 - start]
+        logits = last @ params["embed"].T
+    with jax.named_scope("sample"):
+        tok = _pick_token(logits, seed, length, temperature, top_k,
+                          top_p)
     return tok, k_pages, v_pages
 
 
@@ -271,20 +291,31 @@ def decode_logits(params, tokens, k_pages, v_pages, page_table,
     ctx_len = jnp.where(active, lengths + 1, 1)
 
     clips = jnp.int32(0)
-    x = params["embed"][tokens] + params["pos"][
-        jnp.clip(lengths, 0, cfg.max_len - 1)]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens] + params["pos"][
+            jnp.clip(lengths, 0, cfg.max_len - 1)]
     for i in range(cfg.n_layers):
-        h1 = _rms(x, params[f"l{i}.ln1"])
-        q, k, v = _qkv(params, i, h1, cfg)
-        k_pages, ck = _quant.kv_scatter(k_pages, i, w_pages, slots, k)
-        v_pages, cv = _quant.kv_scatter(v_pages, i, w_pages, slots, v)
-        clips = clips + ck + cv
-        o = attn(q, k_pages.layer(i), v_pages.layer(i), page_table,
-                 ctx_len)
-        x = x + o.reshape(b, cfg.d_model) @ params[f"l{i}.wo"]
-        x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
-    x = _rms(x, params["ln_f"])
-    return x @ params["embed"].T, k_pages, v_pages, clips
+        with jax.named_scope(f"l{i}"):
+            with jax.named_scope("qkv"):
+                h1 = _rms(x, params[f"l{i}.ln1"])
+                q, k, v = _qkv(params, i, h1, cfg)
+            with jax.named_scope("kv_write"):
+                k_pages, ck = _quant.kv_scatter(k_pages, i, w_pages,
+                                                slots, k)
+                v_pages, cv = _quant.kv_scatter(v_pages, i, w_pages,
+                                                slots, v)
+                clips = clips + ck + cv
+            with jax.named_scope("attn"):
+                o = attn(q, k_pages.layer(i), v_pages.layer(i),
+                         page_table, ctx_len)
+            with jax.named_scope("out"):
+                x = x + o.reshape(b, cfg.d_model) @ params[f"l{i}.wo"]
+            with jax.named_scope("mlp"):
+                x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
+    with jax.named_scope("logits"):
+        x = _rms(x, params["ln_f"])
+        logits = x @ params["embed"].T
+    return logits, k_pages, v_pages, clips
 
 
 def decode_forward(params, tokens, k_pages, v_pages, page_table,
@@ -309,13 +340,14 @@ def decode_forward(params, tokens, k_pages, v_pages, page_table,
     logits, k_pages, v_pages, clips = decode_logits(
         params, tokens, k_pages, v_pages, page_table, lengths, active,
         cfg=cfg, attn=attn)
-    if seeds is None:
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        next_tokens = jax.vmap(
-            lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
-                lg, sd, p, tm, tk, tp))(
-            logits, seeds, lengths + 1, temps, top_ks, top_ps)
+    with jax.named_scope("sample"):
+        if seeds is None:
+            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            next_tokens = jax.vmap(
+                lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
+                    lg, sd, p, tm, tk, tp))(
+                logits, seeds, lengths + 1, temps, top_ks, top_ps)
     if with_stats:
         bad_rows = jnp.any(~jnp.isfinite(logits), axis=-1)
         nonfinite = jnp.sum(

@@ -510,17 +510,23 @@ class ContinuousScheduler:
             for row, s in enumerate(self._rows):
                 if s is seq:
                     self._rows[row] = None
+        # the span is the hand-off alone (settling the future), as
+        # serving.reply is; the request's lifetime rides as an
+        # attribute — a span that long would cover, and so name, every
+        # device idle gap of a capture
+        t_r0 = _trace.now()
         if exc is not None:
             seq.future._fail(exc)
         else:
             self.stats.note_completed()
             seq.future._finish(seq.generated, reason)
+        t_r1 = _trace.now()
         _trace.record_span(
-            "decoding.reply", seq.trace_id, seq.t_submit_pc,
-            _trace.now(),
+            "decoding.reply", seq.trace_id, t_r0, t_r1,
             {"model": self.key,
              "outcome": reason or type(exc).__name__,
-             "tokens": len(seq.generated)})
+             "tokens": len(seq.generated),
+             "latency_us": round((t_r1 - seq.t_submit_pc) * 1e6, 1)})
 
     def _preempt(self, seq):
         """Evict for pages: drop the sequence's pages but keep its
@@ -848,15 +854,61 @@ class ContinuousScheduler:
 
     # -------------------------------------------------------------- step
     def _step(self):
+        """One engine step over the live rows, as three spans that
+        partition it: `decoding.pack` (row arrays), `decoding.step`
+        (the engine call: its `engine.launch` and `engine.fetch`),
+        `decoding.emit` (tokens out to the streams, stats). One span
+        each per turn — never per row or per token."""
         engine = self.engine
         live = [(row, s) for row, s in enumerate(self._rows)
                 if s is not None]
         if not live:
             return
-        b = engine.max_batch
-        r = engine.step_rows        # == b + tail_budget when merged
         spec = engine.spec_enabled
         k = engine.spec_k if spec else 0
+        with _trace.span("decoding.pack"):
+            (tokens, table, lengths, active, use_draft, *samp), \
+                tail_rows, bucket = self._pack(live)
+            step_attrs = {
+                "trace_ids": tuple(s.trace_id for _, s in live),
+                "model": self.key, "live": len(live), "bucket": bucket,
+                # context positions this step's attention reads: what
+                # a roofline of the attention kernel is owed
+                "ctx_tokens": int(lengths[active].sum())
+                + int(active.sum()) * (k + 1),
+                "program": engine.step_program(bucket)}
+        with _trace.span("decoding.step", **step_attrs):
+            t0 = _trace.now()
+            if spec:
+                out, n_emit = engine.spec_step(
+                    tokens, table, lengths, active, use_draft, *samp)
+            else:
+                out = engine.step(tokens, table, lengths, active, *samp)
+            dt = _trace.now() - t0
+        with _trace.span("decoding.emit") as emit_span:
+            emitted = self._emit(live, tail_rows, out,
+                                 n_emit if spec else None)
+            emit_span.note(tokens=emitted)
+            self.stats.note_step(emitted, dt)
+            self.stats.note_pool()
+            if engine._guard and self.stats.steps % 16 == 0:
+                # interval drain of the numerics guard (one fetch per
+                # 16 steps); nonfinite rows surface in nonfinite_*,
+                # dequant-overflow clips in quant_clip_*
+                # (decodingStats view)
+                for nf, clips in engine.drain_guard():
+                    if nf:
+                        self.stats.note_nonfinite(nf)
+                    if clips:
+                        self.stats.note_quant_clips(clips)
+
+    def _pack(self, live):
+        """The step's fixed-shape row arrays: returns ((tokens, table,
+        lengths, active, use_draft, seeds, temps, top_ks, top_ps),
+        tail_rows, bucket)."""
+        engine = self.engine
+        b = engine.max_batch
+        r = engine.step_rows        # == b + tail_budget when merged
         # _grow already sized every table for the full write range;
         # span over table lengths keeps the bucket consistent with it
         span = max(len(s.table) for _, s in live)
@@ -908,17 +960,17 @@ class ContinuousScheduler:
                 top_ks[row] = seq.sampling.top_k
                 top_ps[row] = seq.sampling.top_p
             tail_rows.append((seq, next_row - 1, chunk))
-        t0 = _trace.now()
-        if spec:
-            out, n_emit = engine.spec_step(
-                tokens, table, lengths, active, use_draft,
-                seeds, temps, top_ks, top_ps)
-        else:
-            out = engine.step(tokens, table, lengths, active,
-                              seeds, temps, top_ks, top_ps)
-        dt = _trace.now() - t0
+        return (tokens, table, lengths, active, use_draft, seeds, temps,
+                top_ks, top_ps), tail_rows, bucket
+
+    def _emit(self, live, tail_rows, out, n_emit):
+        """Post-step bookkeeping of every live row: lengths advance,
+        tokens go out to the streams, finished requests resolve.
+        `n_emit` is the speculative step's per-row count (None for a
+        plain step). Returns the tokens emitted."""
         emitted = 0
-        if spec:
+        if n_emit is not None:
+            k = self.engine.spec_k
             for row, s in live:
                 n = int(n_emit[row])
                 if s.use_draft:
@@ -945,22 +997,7 @@ class ContinuousScheduler:
                     # tail tokens are prefill work, not emitted tokens:
                     # counted via note_prefill in _finish_tail
                     self._finish_tail(seq, int(out[last_row]))
-        self.stats.note_step(emitted, dt)
-        _trace.record_span(
-            "decoding.step", None, t0, t0 + dt,
-            {"trace_ids": tuple(s.trace_id for _, s in live),
-             "model": self.key, "live": len(live), "bucket": bucket,
-             "tokens": emitted})
-        self.stats.note_pool()
-        if engine._guard and self.stats.steps % 16 == 0:
-            # interval drain of the numerics guard (one fetch per 16
-            # steps); nonfinite rows surface in nonfinite_*, dequant-
-            # overflow clips in quant_clip_* (decodingStats view)
-            for nf, clips in engine.drain_guard():
-                if nf:
-                    self.stats.note_nonfinite(nf)
-                if clips:
-                    self.stats.note_quant_clips(clips)
+        return emitted
 
     # -------------------------------------------------------------- loop
     def _loop(self):
@@ -995,10 +1032,14 @@ class ContinuousScheduler:
                             "decoder stopped"))
                 return
             try:
-                self._check_deadlines(time.monotonic())
-                self._check_cancelled()
-                self._admit()
-                self._grow()
+                # one turn = decoding.admit, then _step's three spans;
+                # prefills launched by _admit record decoding.prefill
+                # inside (parent decoding.admit)
+                with _trace.span("decoding.admit"):
+                    self._check_deadlines(time.monotonic())
+                    self._check_cancelled()
+                    self._admit()
+                    self._grow()
                 self._step()
             except Exception as exc:  # never kill the loop silently
                 for s in self._active():

@@ -60,16 +60,23 @@ def draft_propose_forward(params, last_tokens, k_pages, v_pages,
     tok = last_tokens
     drafts, q_dists = [], []
     for j in range(k):
-        logits, k_pages, v_pages, _ = decode_logits(
-            params, tok, k_pages, v_pages, page_table, lengths + j,
-            active, cfg=cfg, attn=attn)
-        qd = jax.vmap(
-            lambda lg, tm, tk, tp: _sampling.sampling_dist(
-                lg, tm, tk, tp))(logits, temps, top_ks, top_ps)
-        d = jax.vmap(
-            lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
-                lg, sd, p, tm, tk, tp, salt=_sampling.SALT_DRAFT))(
-            logits, seeds, lengths + 1 + j, temps, top_ks, top_ps)
+        # one scope per unrolled draft step; the part names inside are
+        # decode_logits' own (embed, l{i}/qkv ... logits)
+        with jax.named_scope(f"draft{j}"):
+            logits, k_pages, v_pages, _ = decode_logits(
+                params, tok, k_pages, v_pages, page_table, lengths + j,
+                active, cfg=cfg, attn=attn)
+            with jax.named_scope("sample"):
+                qd = jax.vmap(
+                    lambda lg, tm, tk, tp: _sampling.sampling_dist(
+                        lg, tm, tk, tp))(logits, temps, top_ks, top_ps)
+                d = jax.vmap(
+                    lambda lg, sd, p, tm, tk, tp:
+                    _sampling.sample_token(
+                        lg, sd, p, tm, tk, tp,
+                        salt=_sampling.SALT_DRAFT))(
+                    logits, seeds, lengths + 1 + j, temps, top_ks,
+                    top_ps)
         drafts.append(d)
         q_dists.append(qd)
         tok = d
@@ -112,19 +119,44 @@ def verify_forward(params, last_tokens, drafts, q_dists, k_pages,
     slots = pos % page_size
     pos_safe = jnp.clip(pos, 0, cfg.max_len - 1)
 
-    x = params["embed"][tokens_in] + params["pos"][pos_safe]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens_in] + params["pos"][pos_safe]
     for i in range(cfg.n_layers):
-        h1 = _rms(x, params[f"l{i}.ln1"])
-        q, kk, vv = _qkv(params, i, h1, cfg)
-        k_pages, _ = _quant.kv_scatter(k_pages, i, w_pages, slots, kk)
-        v_pages, _ = _quant.kv_scatter(v_pages, i, w_pages, slots, vv)
-        o = attn_multi(q, k_pages.layer(i), v_pages.layer(i),
-                       page_table, pos_safe)
-        x = x + o.reshape(b, s, cfg.d_model) @ params[f"l{i}.wo"]
-        x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
-    x = _rms(x, params["ln_f"])
-    logits = x @ params["embed"].T                     # (B, S, V)
+        with jax.named_scope(f"l{i}"):
+            with jax.named_scope("qkv"):
+                h1 = _rms(x, params[f"l{i}.ln1"])
+                q, kk, vv = _qkv(params, i, h1, cfg)
+            with jax.named_scope("kv_write"):
+                k_pages, _ = _quant.kv_scatter(k_pages, i, w_pages,
+                                               slots, kk)
+                v_pages, _ = _quant.kv_scatter(v_pages, i, w_pages,
+                                               slots, vv)
+            with jax.named_scope("attn"):
+                o = attn_multi(q, k_pages.layer(i), v_pages.layer(i),
+                               page_table, pos_safe)
+            with jax.named_scope("out"):
+                x = x + o.reshape(b, s, cfg.d_model) \
+                    @ params[f"l{i}.wo"]
+            with jax.named_scope("mlp"):
+                x = x + _mlp(params, i, _rms(x, params[f"l{i}.ln2"]))
+    with jax.named_scope("logits"):
+        x = _rms(x, params["ln_f"])
+        logits = x @ params["embed"].T                 # (B, S, V)
 
+    # everything below chooses tokens: the accept rule and its
+    # correction candidates
+    with jax.named_scope("sample"):
+        return _accept(logits, drafts, q_dists, lengths, active,
+                       use_draft, seeds, temps, top_ks, top_ps, k) \
+            + (k_pages, v_pages)
+
+
+def _accept(logits, drafts, q_dists, lengths, active, use_draft, seeds,
+            temps, top_ks, top_ps, k):
+    """The speculative accept rule over the verify pass's logits
+    (B, K+1, V): returns (tokens_out (B, K+1), n_emit (B,))."""
+    b = drafts.shape[0]
+    rows = jnp.arange(b)
     p_dists = jax.vmap(
         lambda lgs, tm, tk, tp: jax.vmap(
             lambda lg: _sampling.sampling_dist(lg, tm, tk, tp))(lgs))(
@@ -182,4 +214,4 @@ def verify_forward(params, last_tokens, drafts, q_dists, k_pages,
         [drafts, jnp.zeros((b, 1), jnp.int32)], axis=1)
     tokens_out = tokens_out.at[rows, n_acc].set(correction)
     n_emit = jnp.where(active, n_acc + 1, 0)
-    return tokens_out, n_emit, k_pages, v_pages
+    return tokens_out, n_emit

@@ -64,14 +64,20 @@ class _DispatchWindow:
         import jax
         import numpy as _np
 
-        t0 = time.perf_counter()
-        jax.block_until_ready(fence)
-        # then a one-scalar value fetch: a value on the host is a
-        # fence whatever block_until_ready acknowledges (same idiom as
-        # Module.sync). Counts as a window stall, not a blocking fetch
-        # — no payload crosses.
-        _np.asarray(jax.device_get(fence.ravel()[0]))
-        _profiler.note_dispatch_stall(time.perf_counter() - t0)
+        with _trace.span("fit.window_wait") as wait:
+            t0 = time.perf_counter()
+            jax.block_until_ready(fence)
+            t_ready = time.perf_counter()
+            # then a one-scalar value fetch: a value on the host is a
+            # fence whatever block_until_ready acknowledges (same idiom
+            # as Module.sync). Counts as a window stall, not a blocking
+            # fetch — no payload crosses. Its own share of the wait is
+            # the span's `fetch_us`: the fetch is two tiny programs
+            # that queue behind every step already dispatched.
+            _np.asarray(jax.device_get(fence.ravel()[0]))
+            t1 = time.perf_counter()
+            wait.note(fetch_us=round((t1 - t_ready) * 1e6, 1))
+        _profiler.note_dispatch_stall(t1 - t0)
 
 
 def _fire(callbacks, **kwargs):
@@ -275,11 +281,20 @@ class BaseModule(object):
                 monitor.tic()
             if num_mon is not None:
                 num_mon.note_batch(batch)
-            with _trace.span("fit.dispatch",
-                             trace_id=f"fit-e{epoch}-b{nbatch}"):
-                self.forward_backward(batch)
-                self.update()
-                self.update_metric(eval_metric, batch.label)
+            # fit.dispatch is partitioned by four leaves: fit.stage
+            # (forward_backward: on the fused path it only turns the
+            # batch into device arrays; the eager executors launch
+            # forward and backward here), fit.launch (update: the step
+            # program's call returning), fit.metric, and
+            # fit.window_wait inside the window's _wait
+            tid = f"fit-e{epoch}-b{nbatch}"
+            with _trace.span("fit.dispatch", trace_id=tid):
+                with _trace.span("fit.stage", trace_id=tid):
+                    self.forward_backward(batch)
+                with _trace.span("fit.launch", trace_id=tid):
+                    self.update()
+                with _trace.span("fit.metric", trace_id=tid):
+                    self.update_metric(eval_metric, batch.label)
                 window.admit(self._step_fence())
             if monitor is not None:
                 monitor.toc_print()
@@ -325,12 +340,14 @@ class BaseModule(object):
             )
             if num_mon is not None:
                 num_mon.note_batch(group[-1])
-            with _trace.span("fit.dispatch",
-                             trace_id=f"fit-e{epoch}-b{nbatch}",
+            tid = f"fit-e{epoch}-b{nbatch}"
+            with _trace.span("fit.dispatch", trace_id=tid,
                              steps=len(group)):
-                self.run_steps(stacked, len(group), stacked=True)
+                with _trace.span("fit.launch", trace_id=tid):
+                    self.run_steps(stacked, len(group), stacked=True)
                 last = group[-1]
-                self.update_metric(eval_metric, last.label)
+                with _trace.span("fit.metric", trace_id=tid):
+                    self.update_metric(eval_metric, last.label)
                 window.admit(self._step_fence())
             if num_mon is not None:
                 num_mon.after_batch(self, epoch, nbatch)
@@ -379,14 +396,13 @@ class BaseModule(object):
                 it = iter(train_data)
                 nfetch = 0
                 while True:
-                    t0 = _trace.now()
                     try:
-                        batch = next(it)
+                        with _trace.span(
+                                "fit.data_wait",
+                                trace_id=f"fit-e{epoch}-b{nfetch}"):
+                            batch = next(it)
                     except StopIteration:
                         return
-                    _trace.record_span(
-                        "fit.data_wait", f"fit-e{epoch}-b{nfetch}",
-                        t0, _trace.now())
                     yield batch
                     nfetch += 1
 

@@ -186,57 +186,19 @@ class scope:
 
 
 def _collect_device_events(trace_dir):
-    """Chrome trace events from the newest jax/XLA capture under
-    trace_dir (jax writes plugins/profile/<run>/<host>.trace.json.gz in
-    chrome trace-event format — one file PER HOST, several in a
-    multi-host/multi-device capture). All files of the newest run
-    directory are merged; device pids map into a per-source-file lane
-    (file i, source pid p -> 1000*(i+1)+p, bumped past collisions) so
-    two devices that both call themselves pid 2 in different files
-    stay separate processes next to the host (pid 0) timeline instead
-    of silently merging. Single-file captures keep the historical
-    pid+1000 mapping exactly."""
-    import glob
-    import gzip
+    """Chrome slices of the device operations in the newest jax
+    capture under trace_dir (this JAX writes one `.xplane.pb` per
+    capture), each attributed to a named scope through the scope map
+    of the module launch that covers it (`profiling.timeline`): one
+    process lane per device (pid 1001, 1002, ...) next to the host
+    (pid 0) timeline. Timestamps are shifted onto the host timeline:
+    the capture's clock starts at its own beginning, which
+    profiler_set_state('run') recorded as trace_t0_us."""
+    from .profiling import timeline as _timeline
 
-    paths = glob.glob(os.path.join(
-        trace_dir, "**", "*.trace.json.gz"), recursive=True)
-    if not paths:
-        return []
-    # the newest RUN, not the newest file: a capture writes sibling
-    # per-host files into one run directory
-    run_dir = os.path.dirname(max(paths, key=os.path.getmtime))
-    run_paths = sorted(p for p in paths
-                       if os.path.dirname(p) == run_dir)
-    # shift device timestamps onto the host timeline: the capture's ts
-    # are relative to its own start, which dump-time recorded as
-    # trace_t0_us on the host clock
-    base = _state.get("trace_t0_us", 0.0)
-    out = []
-    pid_map = {}        # (file_idx, src_pid) -> output pid
-    taken = set()
-    for file_idx, path in enumerate(run_paths):
-        try:
-            with gzip.open(path, "rt") as f:
-                device = json.load(f)
-        except Exception:
-            continue  # a torn/partial file must not drop the others
-        for ev in device.get("traceEvents", []):
-            ev = dict(ev)
-            pid = ev.get("pid")
-            if isinstance(pid, int):
-                lane = pid_map.get((file_idx, pid))
-                if lane is None:
-                    lane = 1000 * (file_idx + 1) + pid
-                    while lane in taken:
-                        lane += 1000
-                    taken.add(lane)
-                    pid_map[(file_idx, pid)] = lane
-                ev["pid"] = lane
-            if isinstance(ev.get("ts"), (int, float)):
-                ev["ts"] = ev["ts"] + base
-            out.append(ev)
-    return out
+    return _timeline.device_slices(
+        _timeline.read_xplane(trace_dir),
+        base_us=_state.get("trace_t0_us", 0.0))
 
 
 def _view(key, import_module):
@@ -302,8 +264,8 @@ def dump_profile(device_trace_dir=None):
     """Write collected events as ONE Chrome trace-event JSON (the
     reference emits a single unified trace, src/engine/profiler.cc:134):
     host-side framework events on pid 0, and — when a jax device
-    capture ran — the XLA device timeline merged in under offset
-    pids. Every subsystem view registered in the telemetry registry is
+    capture ran — the device operations of its `.xplane.pb` merged in
+    under pids 1001.., each with its named scope in `args`. Every subsystem view registered in the telemetry registry is
     embedded top-level under its legacy key (`execCacheStats`,
     `servingStats`, `hostSyncStats`, `inputPipelineStats`,
     `graphPassStats`, in that historical order — chrome://tracing
@@ -322,14 +284,14 @@ def dump_profile(device_trace_dir=None):
     # aggregation lagged one dump behind its own events)
     device_events = []
     if device_trace_dir:
-        device_events = _collect_device_events(device_trace_dir)
-        if device_events:
-            try:
+        try:
+            device_events = _collect_device_events(device_trace_dir)
+            if device_events:
                 from .profiling import ingest_device_events
 
                 ingest_device_events(device_events)
-            except Exception:
-                pass  # aggregation is advisory; the dump must land
+        except Exception:
+            pass  # the device timeline is advisory; the dump must land
     trace = {"traceEvents": [], "displayTimeUnit": "ms"}
     _ensure_silo_views()
     for key, snap in _telemetry.view_items():

@@ -34,8 +34,10 @@ with zero extra wiring:
       PAPERS.md, reduced to its lookup table).
 
 Plus `timeline`: the op-level device-time aggregator that attributes
-XLA trace durations back to graph nodes (the executor wraps every op
-in `jax.named_scope(node_name)`, so HLO metadata carries our names).
+a profiler capture's device operations back to named scopes — graph
+nodes (the executor wraps every op in `jax.named_scope(node_name)`)
+and the decode tier's layers and parts — through the scope map each
+record keeps of its compiled module (`scope_map(module_name)`).
 
 Everything is on by default and CPU-safe; MXNET_PROFILING=0 restores
 raw jit dispatch everywhere.
@@ -45,10 +47,11 @@ from __future__ import annotations
 from .calibration import CalibrationStore, calibration_store
 from .device_stats import (InstrumentedJit, device_stats, instrument,
                            profiling_enabled, records_for,
-                           reset_device_stats)
+                           reset_device_stats, scope_map)
 from .preflight import (HBMPreflightError, HBMPreflightWarning,
                         last_preflight, preflight_bind)
-from .timeline import (aggregate_device_events, ingest_device_events,
+from .timeline import (aggregate_device_events, device_slices,
+                       ingest_device_events, read_xplane,
                        timeline_stats)
 
 __all__ = [
@@ -57,6 +60,7 @@ __all__ = [
     "profiling_enabled", "records_for", "reset_device_stats",
     "HBMPreflightError", "HBMPreflightWarning",
     "last_preflight", "preflight_bind",
-    "aggregate_device_events", "ingest_device_events",
+    "aggregate_device_events", "device_slices",
+    "ingest_device_events", "read_xplane", "scope_map",
     "timeline_stats",
 ]

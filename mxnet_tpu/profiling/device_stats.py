@@ -23,11 +23,19 @@ jit_sharded a caller label), `kind` the program flavor ("fwd",
 merge: compile/trace seconds accumulate, byte/flop fields keep the
 largest signature seen (the footprint that matters for HBM planning).
 
+Each record also names its compiled MODULE (`jit_decode_p8`: what a
+profiler capture's `XLA Modules` line shows), and the module's SCOPE
+MAP — {instruction name: named-scope path}, parsed from the
+`compiled.as_text()` kept at capture — stays beside the table under
+that name (`scope_map`). Both outlive the executable: a capture is reduced
+after the engine that launched the programs is gone.
+
 The `deviceStats` registry view serves /statusz and dump_profile;
 native Prometheus instruments cover the scrape path.
 """
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -42,10 +50,19 @@ _DEFAULT_MAX_SIGS = 64
 _lock = threading.Lock()
 # (digest, kind) -> record dict (see _new_record)
 _records: "dict[tuple, dict]" = {}
+# module name -> the compiled program's text until `scope_map` is first
+# asked for it, its scope map (timeline.parse_scope_map) from then on;
+# oldest first, and a later program of the same module name replaces
+# the earlier one's. On the chip as_text() takes most of the cost and
+# is paid at capture; the parse waits for a reader (PERF.md, PR 25).
+_MAX_SCOPE_MAPS = 64
+_scope_maps: "collections.OrderedDict[str, str | dict]" = \
+    collections.OrderedDict()
 _totals = {"fallbacks": 0, "compile_errors": 0,
            "compiles": 0,      # real XLA compiles this process paid
-           "disk_loads": 0}    # executables restored AOT from the
+           "disk_loads": 0,    # executables restored AOT from the
                                # exec_cache_disk tier (compile_s≈0)
+           "scope_parse_s": 0.0}   # seconds scope_map spent parsing
 
 
 def _disk_tier():
@@ -96,7 +113,52 @@ def _new_record(digest, kind, canonical, label):
         "code_bytes": 0, "alias_bytes": 0, "hbm_bytes": 0,
         "flops": 0.0, "bytes_accessed": 0.0,
         "platform": None,
+        # the compiled module's name, and what as_text() cost at
+        # capture (the parse is paid on request: totals.scope_parse_s)
+        "module": None, "scope_text_s": 0.0,
     }
+
+
+def _keep_scope_source(compiled):
+    """Keep one executable's text under its module's name, for
+    `scope_map` to parse on first request. Returns (module name,
+    seconds spent); (None, seconds) where the backend gives no text."""
+    from . import timeline as _timeline
+
+    t0 = time.perf_counter()
+    try:
+        text = compiled.as_text()
+        module = _timeline.module_name(text)
+    except Exception:
+        module = None
+    if module:
+        with _lock:
+            _scope_maps.pop(module, None)
+            _scope_maps[module] = text
+            while len(_scope_maps) > _MAX_SCOPE_MAPS:
+                _scope_maps.popitem(last=False)
+    return module, time.perf_counter() - t0
+
+
+def scope_map(module):
+    """{instruction name: scope path} of the newest captured program
+    whose compiled module has this name; None if there is none. The
+    first request parses the kept text (`timeline.parse_scope_map`)
+    and keeps the map in its place."""
+    module = str(module)
+    with _lock:
+        src = _scope_maps.get(module)
+    if isinstance(src, str):
+        from . import timeline as _timeline
+
+        t0 = time.perf_counter()
+        smap = _timeline.parse_scope_map(src)
+        with _lock:
+            if _scope_maps.get(module) is src:
+                _scope_maps[module] = smap
+            _totals["scope_parse_s"] += time.perf_counter() - t0
+        src = smap
+    return dict(src) if src is not None else None
 
 
 def record_executable(digest, kind, compiled, trace_s, compile_s,
@@ -135,6 +197,7 @@ def record_executable(digest, kind, compiled, trace_s, compile_s,
         platform = jax.default_backend()
     except Exception:
         platform = None
+    module, text_s = _keep_scope_source(compiled)
 
     key = (str(digest), str(kind))
     with _lock:
@@ -155,6 +218,8 @@ def record_executable(digest, kind, compiled, trace_s, compile_s,
         if canonical and not rec["canonical"]:
             rec["canonical"] = canonical
         rec["platform"] = platform
+        rec["module"] = module or rec["module"]
+        rec["scope_text_s"] += text_s
         if from_disk:
             _totals["disk_loads"] += 1
             rec["disk_loads"] = rec.get("disk_loads", 0) + 1
@@ -218,6 +283,7 @@ def records_for(canonical=None, digest=None, kind=None):
 def reset_device_stats():
     with _lock:
         _records.clear()
+        _scope_maps.clear()
         for k in _totals:
             _totals[k] = 0
 

@@ -1,35 +1,77 @@
-"""Op-level device timelines: XLA trace durations → graph nodes.
+"""Op-level device timelines: a profiler capture's device operations →
+named scopes → per-scope totals.
 
-The profiler's device capture (`profiler._collect_device_events`)
-yields raw Chrome trace events: one `ph=="X"` slice per executed HLO,
-named after the fused computation, with the original op path in the
-event args (`long_name` / `tf_op` / `name` metadata XLA copies from
-HLO op_metadata). The executor wraps every graph op in
-`jax.named_scope(node_name)`, so that path carries OUR node names:
-`jit(run_graph)/convolution0/convolution.3` attributes to
-`convolution0`.
+A `jax.profiler` capture on this JAX is one `.xplane.pb`: per device
+an `XLA Modules` line (one event per program launch, named after the
+compiled module) and an `XLA Ops` line (one event per executed
+instruction, named by the instruction's text). Events carry no
+metadata of their own, so attribution goes through what the program
+kept at compile time: `device_stats.record_executable` parses every
+compiled program's text into a SCOPE MAP, {instruction name: scope
+path}, keyed by the module's name (`device_stats.scope_map`). The scope path is the
+`jax.named_scope` nesting the instruction was traced under — the
+executor wraps every graph op in `jax.named_scope(node_name)`, the
+decode tier names layers and their parts (`l3/attn`) — with jit and
+autodiff wrappers unwrapped (`transpose(jvp(conv0))` → `conv0`).
 
-`aggregate_device_events` folds slices into per-node totals;
-`ingest_device_events` accumulates across captures into the
-process-wide table behind the `deviceTimelineStats` registry view
-(/statusz top-K table, dump_profile embed). Attribution never
-round-trips the device: it is pure JSON crunching at dump time."""
+Instruction names (`fusion.67`) repeat across programs; module names do
+not. So an operation event belongs to the module launch that covers it
+in time, and takes its scope from that module's map.
+
+Two seams, both over plain lists so that tests need no device capture
+(a CPU capture has no device plane):
+
+  read_xplane(trace_dir)       newest `.xplane.pb` → {"devices":
+                               [{"name", "ops": [(instr, label, t0,
+                               t1)], "modules": [(name, name, t0,
+                               t1)]}], "host": [(name, t0, t1)]},
+                               seconds on the capture's clock
+  device_slices(raw, ...)      that form → Chrome slices with
+                               `args.scope` / `args.module`; what
+                               `dump_profile` merges and
+                               `ingest_device_events` folds into the
+                               `deviceTimelineStats` view
+"""
 from __future__ import annotations
 
+import bisect
+import glob
 import os
+import re
 import threading
 
 from ..telemetry import register_view as _register_view
 
 _lock = threading.Lock()
-# node label -> {"count", "total_us", "max_us"}
+# scope label -> {"count", "total_us", "max_us"}
 _ops: "dict[str, dict]" = {}
 _totals = {"events": 0, "captures": 0, "device_pids": set()}
 
 _DEFAULT_TOPK = 20
 
-# metadata keys XLA variously uses for the HLO op path, best first
-_PATH_KEYS = ("long_name", "tf_op", "name", "op_name", "hlo_op")
+UNSCOPED = "unscoped"
+
+# name-stack wrappers jax puts around a scope's name; a segment whose
+# wrappers include a jit is a FUNCTION's name, not a scope
+_WRAPPERS = ("jit", "pjit", "jvp", "vjp", "transpose", "checkpoint",
+             "remat", "custom_jvp", "custom_vjp", "vmap", "while", "cond",
+             "named", "shard_map", "xla_call", "core_call")
+_JITS = ("jit", "pjit", "xla_call", "core_call")
+_WRAPPED = re.compile(r"^([A-Za-z_]+)\((.*)\)$")
+_OP_NAME = re.compile(r'op_name="([^"]+)"')
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_EVENT_INSTR = re.compile(r"^%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+# control flow under an instruction: the computations a device steps
+# into (`body=%b`, `condition=%c`, `branch_computations={%x, %y}`,
+# `true_computation=`/`false_computation=`), and a call's `to_apply=`
+_CONTROL = re.compile(
+    r"(?:body|condition|true_computation|false_computation|"
+    r"branch_computations)=(\{[^}]*\}|%[\w.\-]+)")
+_TO_APPLY = re.compile(r"to_apply=%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*[({]")
+_ANNOTATION = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
+_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 
 
 def _topk():
@@ -40,34 +82,216 @@ def _topk():
         return _DEFAULT_TOPK
 
 
-def attribute_event(ev):
-    """Graph-node label for one trace slice: first path segment of the
-    op metadata that is neither a jit wrapper nor an xla detail —
-    with the executor's named_scope, that IS the node name. Falls back
-    to the slice's own name (the fusion label)."""
-    args = ev.get("args") or {}
-    for key in _PATH_KEYS:
-        path = args.get(key)
-        if not isinstance(path, str) or not path:
+# ------------------------------------------------------- the scope map
+def scope_path(op_name):
+    """'jit(step)/jit(main)/transpose(jvp(conv0))/conv_general_dilated'
+    -> 'conv0'; 'jit(decode_p8)/l3/attn/dot_general' -> 'l3/attn';
+    None where no scope encloses the operation. The last segment is
+    the primitive and never a scope; of the several paths the compiler
+    joins with ';' when it merges instructions, the first counts."""
+    segs = []
+    for seg in op_name.split(";", 1)[0].split("/")[:-1]:
+        seg = seg.strip()
+        is_fn = False
+        while True:
+            m = _WRAPPED.match(seg)
+            if not m or m.group(1) not in _WRAPPERS:
+                break
+            is_fn = is_fn or m.group(1) in _JITS
+            seg = m.group(2)
+        if seg and not is_fn:
+            segs.append(seg)
+    return "/".join(segs) or None
+
+
+def module_name(hlo_text):
+    """The compiled module's name (`jit_decode_p8`), as a capture's
+    `XLA Modules` line has it; None for a text without a header."""
+    m = _MODULE.match(hlo_text)
+    return m.group(1) if m else None
+
+
+def _computations(hlo_text):
+    """(entry name, {computation name: its instruction lines})."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        if " = " in line:
+            if cur is not None:
+                cur.append(line)
             continue
-        for seg in path.split("/"):
-            seg = seg.strip()
-            if not seg or seg.startswith(("jit(", "jvp(", "vjp(",
-                                          "transpose(", "pjit")):
+        head = _COMPUTATION.match(line)
+        if head and line.rstrip().endswith("{"):
+            cur = comps[head.group(1)] = []
+            if line.startswith("ENTRY"):
+                entry = head.group(1)
+    return entry, comps
+
+
+def parse_scope_map(hlo_text):
+    """{instruction name: scope path} for every instruction a device
+    can report as an operation of its own: those of the entry
+    computation and of the control flow under it (while bodies and
+    conditions, branches, calls). Fused bodies and the reducers and
+    comparators handed to `to_apply` are left out: a fusion, a reduce
+    or a sort runs as ONE operation.
+
+    An instruction's own `op_name` metadata decides where it has one.
+    The compiler's own instructions have none (layout copies, the
+    `fusion.N.remat_uncompressed` it clones): such an instruction takes
+    the scope of the first instruction that uses it (through further
+    unnamed users, if need be), else of the one that produces its
+    first operand (likewise), else stays `unscoped` — a copy made FOR
+    a scope's kernel is that scope's cost, and a copy of a scope's
+    result on its way out of the program (its only user the unnamed
+    root tuple) is the producer's."""
+    entry, comps = _computations(hlo_text)
+    todo, reached = [entry], []
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps:
+            continue
+        reached.append(name)
+        for line in comps[name]:
+            if "_computation" in line or "body=" in line:
+                for group in _CONTROL.findall(line):
+                    todo.extend(_OPERAND.findall(group))
+            if " call(" in line:
+                todo.extend(_TO_APPLY.findall(line))
+    order, scope, operands = [], {}, {}
+    for comp in reached:
+        for line in comps[comp]:
+            m = _INSTR.match(line)
+            if not m:
                 continue
-            # first segment under the jit wrappers: the named_scope
-            # node name when present, else the raw HLO id — both are
-            # the most framework-meaningful label available
-            return seg
-    name = ev.get("name")
-    return str(name) if name else None
+            name = m.group(1)
+            body = line[m.end():]
+            meta = body.find("metadata={")
+            path = None
+            if meta >= 0:
+                p = _OP_NAME.search(body, meta)
+                if p:
+                    path = scope_path(p.group(1))
+                body = body[:meta]
+            order.append(name)
+            scope[name] = path
+            operands[name] = _OPERAND.findall(body)
+    first_user = {}
+    for name in order:
+        for op in operands[name]:
+            first_user.setdefault(op, name)
+
+    def follow(name, step):
+        """The first scope along a chain of unnamed instructions."""
+        seen = set()
+        while name in scope and name not in seen:
+            if scope[name] is not None:
+                return scope[name]
+            seen.add(name)
+            name = step(name)
+        return None
+
+    def producer(name):
+        return operands[name][0] if operands[name] else None
+
+    return {name: scope[name] or follow(name, first_user.get)
+            or follow(name, producer) or UNSCOPED for name in order}
 
 
+# -------------------------------------------------- reading a capture
+def read_xplane(trace_dir):
+    """The newest `.xplane.pb` under `trace_dir` as plain lists (see
+    the module docstring); None where there is no capture. Host events
+    are the process's own annotations only (telemetry spans and the
+    like: names with a dot), not the runtime's thousands."""
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "**", "*.xplane.pb"), recursive=True),
+        key=os.path.getmtime)
+    if not paths:
+        return None
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(paths[-1])
+    devices, host = [], []
+    for plane in pd.planes:
+        pname = plane.name
+        if pname.startswith(("/device:TPU:", "/device:GPU:")):
+            dev = {"name": pname, "ops": [], "modules": []}
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    for ev in line.events:
+                        # an event's name is the instruction's text
+                        m = _EVENT_INSTR.match(ev.name)
+                        instr = m.group(1) if m else ev.name
+                        t0 = ev.start_ns * 1e-9
+                        dev["ops"].append(
+                            (instr, instr, t0,
+                             t0 + ev.duration_ns * 1e-9))
+                elif line.name == "XLA Modules":
+                    for ev in line.events:
+                        t0 = ev.start_ns * 1e-9
+                        dev["modules"].append(
+                            (ev.name, ev.name, t0,
+                             t0 + ev.duration_ns * 1e-9))
+            if dev["ops"] or dev["modules"]:
+                devices.append(dev)
+        elif pname.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if _ANNOTATION.match(ev.name):
+                        t0 = ev.start_ns * 1e-9
+                        host.append((ev.name, t0,
+                                     t0 + ev.duration_ns * 1e-9))
+    devices.sort(key=lambda d: d["name"])
+    return {"devices": devices, "host": host, "path": paths[-1]}
+
+
+def module_of_launch(name):
+    """'jit_decode_p8(1234567)' -> 'jit_decode_p8': a launch event's
+    name is the module's, with the program's id in brackets."""
+    cut = name.find("(")
+    return name[:cut] if cut > 0 else name
+
+
+def device_slices(raw, scope_maps=None, base_us=0.0):
+    """Chrome trace slices (ph 'X', microseconds) of every device
+    operation of a capture in the plain-list form, one process lane
+    per device (pid 1001, 1002, ...; the host timeline is pid 0).
+    Each carries `args.module` — the launch that covers it in time —
+    and `args.scope`, from that module's scope map (`scope_maps`:
+    module name -> map or None, by default the record table's
+    `device_stats.scope_map`); an operation no map places is
+    `unscoped`, outside every launch it keeps its instruction's name
+    alone. `base_us` shifts the capture's clock onto the caller's."""
+    if scope_maps is None:
+        from .device_stats import scope_map as scope_maps
+    out = []
+    for idx, dev in enumerate((raw or {}).get("devices", ())):
+        pid = 1001 + idx
+        launches = sorted((t0, t1, module_of_launch(n))
+                          for n, _l, t0, t1 in dev["modules"])
+        starts = [la[0] for la in launches]
+        maps = {}
+        for instr, _label, t0, t1 in dev["ops"]:
+            args = {}
+            i = bisect.bisect_right(starts, t0) - 1
+            if i >= 0 and t0 < launches[i][1]:
+                module = launches[i][2]
+                if module not in maps:
+                    maps[module] = scope_maps(module) or {}
+                args["module"] = module
+                args["scope"] = maps[module].get(instr, UNSCOPED)
+            out.append({"name": instr, "ph": "X", "pid": pid, "tid": 0,
+                        "ts": t0 * 1e6 + base_us,
+                        "dur": (t1 - t0) * 1e6, "args": args})
+    return out
+
+
+# ------------------------------------------------------ the aggregator
 def aggregate_device_events(events):
-    """Fold Chrome trace slices into {label: {count, total_us,
-    max_us}}. Only complete slices (ph=='X' with a dur) carry device
-    time; everything else (metadata, counters, B/E host pairs) is
-    ignored."""
+    """Fold device slices (`device_slices`) into {label: {count,
+    total_us, max_us}}; the label is the slice's scope, or its own
+    name where it has none. Only complete slices (ph=='X' with a dur)
+    carry device time."""
     out = {}
     for ev in events:
         if ev.get("ph") != "X":
@@ -75,7 +299,7 @@ def aggregate_device_events(events):
         dur = ev.get("dur")
         if not isinstance(dur, (int, float)) or dur < 0:
             continue
-        label = attribute_event(ev)
+        label = (ev.get("args") or {}).get("scope") or ev.get("name")
         if not label:
             continue
         rec = out.get(label)
@@ -113,7 +337,7 @@ def ingest_device_events(events):
 
 
 def timeline_stats():
-    """`deviceTimelineStats` view: top-K ops by total device time.
+    """`deviceTimelineStats` view: top-K scopes by total device time.
     {"ops": {label: {count, total_us, max_us, mean_us}}, "totals":
     {...}}; empty until a capture was ingested."""
     with _lock:

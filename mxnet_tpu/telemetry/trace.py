@@ -16,6 +16,20 @@ device interaction. `ci/check_telemetry.sh` gates the end-to-end cost
 at <= 3% of step time; `MXNET_TELEMETRY_SPANS=0` disables recording
 entirely (the A/B arm of that gate).
 
+Causation: a `with span(...)` block pushes its name on a thread-local
+stack, so every span recorded inside it — by a nested block or by
+`record_span` after the fact — carries `parent`, the enclosing span on
+the same thread. A layer's self time is its span less its children.
+
+One clock with the device: the same block opens a
+`jax.profiler.TraceAnnotation` of the span's name. With no profiler
+session live that is a TraceMe that records nothing (well under a
+microsecond); inside ANY `jax.profiler` capture the program's spans sit
+in the host plane on the profiler's own clock, next to the device's
+operations, with no alignment by the reader. `record_span` cannot do
+that (its region is already over), which is why the hot loops use
+`with` blocks.
+
 Correlation: `new_trace_id()` mints a process-unique id; serving
 threads it `submit -> enqueue -> batch_flush -> execute -> reply`
 (the request's Future carries it as `.trace_id`), and `fit` stamps
@@ -47,6 +61,8 @@ def _env_capacity():
 
 
 _lock = threading.Lock()
+_tls = threading.local()        # .stack: names of the open spans
+_annotation = None              # jax.profiler.TraceAnnotation, or False
 _capacity = _env_capacity()
 _ring = collections.deque(maxlen=_capacity or 1)
 _recorded = 0
@@ -54,18 +70,20 @@ _id_counter = itertools.count(1)
 
 
 class Span:
-    """One recorded region: (name, trace_id, begin, end, attrs).
-    Times are `time.perf_counter()` seconds (same clock family as the
-    profiler's host events)."""
+    """One recorded region: (name, trace_id, begin, end, attrs,
+    parent). Times are `time.perf_counter()` seconds (same clock
+    family as the profiler's host events); `parent` is the name of the
+    span that was open on the recording thread, or None."""
 
-    __slots__ = ("name", "trace_id", "t0", "t1", "attrs")
+    __slots__ = ("name", "trace_id", "t0", "t1", "attrs", "parent")
 
-    def __init__(self, name, trace_id, t0, t1, attrs):
+    def __init__(self, name, trace_id, t0, t1, attrs, parent=None):
         self.name = name
         self.trace_id = trace_id
         self.t0 = t0
         self.t1 = t1
         self.attrs = attrs
+        self.parent = parent
 
     @property
     def duration_us(self):
@@ -84,6 +102,8 @@ class Span:
             "t0_us": round(self.t0 * 1e6, 1),
             "dur_us": round(self.duration_us, 1),
         }
+        if self.parent:
+            out["parent"] = self.parent
         if self.attrs:
             out["attrs"] = {
                 k: (list(v) if isinstance(v, tuple) else v)
@@ -98,13 +118,40 @@ def new_trace_id(prefix="req"):
     return f"{prefix}-{os.getpid():x}-{next(_id_counter):x}"
 
 
-def record_span(name, trace_id, t0, t1, attrs=None):
+def _open_spans():
+    try:
+        return _tls.stack
+    except AttributeError:
+        stack = _tls.stack = []
+        return stack
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported on first use (the ring
+    exists before, and without, jax); False where it cannot be."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        except Exception:
+            _annotation = False
+    return _annotation
+
+
+def record_span(name, trace_id, t0, t1, attrs=None, parent=None):
     """Append one finished span to the ring (the single hot-path
-    recording chokepoint — listed in mxlint's HOT_PATH_MANIFEST)."""
+    recording chokepoint — listed in mxlint's HOT_PATH_MANIFEST).
+    `parent` defaults to the span open on this thread, if any."""
     global _recorded
     if _capacity <= 0:
         return
-    span_obj = Span(name, trace_id, t0, t1, attrs)
+    if parent is None:
+        stack = _open_spans()
+        if stack:
+            parent = stack[-1]
+    span_obj = Span(name, trace_id, t0, t1, attrs, parent)
     with _lock:
         _ring.append(span_obj)
         _recorded += 1
@@ -117,26 +164,46 @@ class span:
             ...
 
     The record decision is latched nowhere — the ring is always on —
-    but a zero capacity (MXNET_TELEMETRY_SPANS=0) makes __exit__ a
-    no-op."""
+    but a zero capacity (MXNET_TELEMETRY_SPANS=0) makes the block a
+    no-op: no record, no profiler annotation. Attributes known only
+    inside the block are added with `note(**attrs)`."""
 
-    __slots__ = ("name", "trace_id", "attrs", "_t0")
+    __slots__ = ("name", "trace_id", "attrs", "_t0", "_ann", "_pushed")
 
     def __init__(self, name, trace_id=None, **attrs):
         self.name = name
         self.trace_id = trace_id
         self.attrs = attrs or None
 
+    def note(self, **attrs):
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
     def __enter__(self):
+        self._ann = None
+        self._pushed = _capacity > 0
+        if self._pushed:
+            _open_spans().append(self.name)
+            ann_cls = _annotation or _trace_annotation()
+            if ann_cls:
+                self._ann = ann_cls(self.name)
+                self._ann.__enter__()
         self._t0 = now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = now()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._pushed:
+            _open_spans().pop()
         if exc_type is not None:
             attrs = dict(self.attrs or ())
             attrs["error"] = exc_type.__name__
             self.attrs = attrs
-        record_span(self.name, self.trace_id, self._t0, now(),
+        record_span(self.name, self.trace_id, self._t0, t1,
                     self.attrs)
         return False
 

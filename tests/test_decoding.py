@@ -533,3 +533,167 @@ def test_batcher_pop_expired():
     assert popped == [dead]
     assert b.depth() == 1            # the live request stays queued
     assert b.pop_expired() == []
+
+
+# ------------------------------------------ names inside a decode step
+PARTS = ("embed", "qkv", "kv_write", "attn", "out", "mlp", "logits",
+         "sample")
+
+
+def test_decode_program_holds_every_part_name():
+    """The compiled decode and prefill programs carry the named scopes
+    (metadata only): `embed`, `logits`, `sample` outside the layers,
+    `l{i}/<part>` inside, and the profiling layer's scope map places
+    the programs' instructions in them after the engine is gone."""
+    from mxnet_tpu import profiling
+
+    m = _model()
+    eng = m.engine
+    text = eng.decode_program_text(2)
+    want = [f"/{p}/" for p in ("embed", "logits", "sample")] + [
+        f"/l{i}/{p}/" for i in range(CFG.n_layers)
+        for p in ("qkv", "kv_write", "attn", "out", "mlp")]
+    for seg in want:
+        assert f"jit(decode_p2){seg}" in text, seg
+    program = eng.step_program(2)
+    assert program == "jit_decode_p2"
+    m.close(drain=False)
+    del m, eng
+    smap = profiling.scope_map(program)
+    scopes = set(smap.values())
+    for i in range(CFG.n_layers):
+        for p in ("qkv", "kv_write", "attn", "out", "mlp"):
+            assert any(s == f"l{i}/{p}" or s.startswith(f"l{i}/{p}/")
+                       for s in scopes), (i, p)
+    assert {"embed", "logits"} <= scopes
+    assert any(s.split("/")[0] == "sample" for s in scopes)
+    pre = set(profiling.scope_map("jit_prefill_t4").values())
+    assert {"l0/attn", "l1/mlp", "logits"} <= {
+        "/".join(s.split("/")[:2]) if s.startswith("l") else s
+        for s in pre}
+
+
+def test_engine_programs_have_names_of_their_own():
+    """Every program of the grid compiles to a module named after what
+    it is: none is `jit_impl`, no two share a name, and the record of
+    each carries it."""
+    from mxnet_tpu import profiling
+
+    draft_cfg = dec.DecoderConfig(vocab=32, d_model=8, n_layers=1,
+                                  n_heads=1, d_ff=16, max_len=64)
+    eng = dec.DecodeEngine(
+        PARAMS, CFG, max_batch=2, page_size=4, num_pages=32,
+        page_buckets=(1, 2), prefix_cache=True, merged_step=False,
+        draft_params=dec.init_decoder_params(draft_cfg, seed=1),
+        draft_cfg=draft_cfg, spec_k=2).warmup()
+    recs = profiling.records_for(digest=eng._digest)
+    modules = {r["kind"]: r["module"] for r in recs}
+    assert len(set(modules.values())) == len(modules) == len(recs)
+    assert "jit_impl" not in modules.values()
+    assert modules["decode@2"] == "jit_decode_p2"
+    assert modules["prefill@4"] == "jit_prefill_t4"
+    assert modules["prefill_tail@8"] == "jit_prefill_tail_t8"
+    assert modules["draft_prefill@4"] == "jit_draft_prefill_t4"
+    assert modules["draft@1"] == "jit_draft_p1"
+    assert modules["verify@2"] == "jit_verify_p2"
+    assert modules["copy_page"] == "jit_copy_page"
+    assert eng.step_program(2) == "jit_verify_p2"
+    # the speculative bodies carry the same part names
+    verify = set(profiling.scope_map("jit_verify_p2").values())
+    assert any(s.startswith("l0/kv_write") for s in verify)
+    draft = set(profiling.scope_map("jit_draft_p1").values())
+    assert any(s.startswith("draft1/l0/attn") for s in draft)
+
+
+def _spans_between(t0, t1):
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    return [s for s in ttrace.recent_spans()
+            if s.t0 >= t0 and s.t1 <= t1]
+
+
+def test_scheduler_turn_is_partitioned_by_leaf_spans():
+    """One turn of the loop = decoding.admit, decoding.pack,
+    decoding.step (children engine.launch + engine.fetch),
+    decoding.emit: right parents, no overlap, and the leaves sum to
+    the turn."""
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    ttrace.set_capacity(4096)
+    try:
+        m = _model(max_tokens=24, page_buckets=(1, 2, 4, 8))
+        fut = m.submit([3, 4, 5, 6, 7], max_new_tokens=24)
+        assert len(fut.result(timeout=120)) == 24
+        m.close()
+        spans = ttrace.recent_spans()
+    finally:
+        ttrace.set_capacity(ttrace._env_capacity())
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    assert len(by["decoding.step"]) >= 20
+    assert {s.parent for s in by["decoding.step"]} == {None}
+    assert {s.parent for s in by["engine.launch"]} == {"decoding.step"}
+    assert {s.parent for s in by["engine.fetch"]} == {"decoding.step"}
+    assert {s.parent for s in by["decoding.prefill"]} \
+        == {"decoding.admit"}
+    assert {s.parent for s in by["decoding.reply"]} == {"decoding.emit"}
+    step = by["decoding.step"][10]
+    assert step.attrs["program"] == m.engine.step_program(
+        step.attrs["bucket"])
+    # one live row whose context holds prompt + 10 generated tokens:
+    # the step reads lengths + 1 positions
+    assert step.attrs["ctx_tokens"] == 5 + 10 + 1
+    assert "tokens" in by["decoding.emit"][10].attrs
+    # the children of a step partition it
+    kids = sorted((s for s in spans if s.parent == "decoding.step"
+                   and s.t0 >= step.t0 and s.t1 <= step.t1),
+                  key=lambda s: s.t0)
+    assert [k.name for k in kids] == ["engine.launch", "engine.fetch"]
+    assert kids[0].t1 <= kids[1].t0
+    assert sum(k.t1 - k.t0 for k in kids) >= 0.95 * (step.t1 - step.t0)
+    # turns 5..15: admit, pack, step, emit follow each other without
+    # overlap and fill the period between two admits
+    admits = by["decoding.admit"]
+    first = next(i for i, a in enumerate(admits)
+                 if a.t0 > by["decoding.step"][5].t1)
+    lo, hi = admits[first].t0, admits[first + 10].t0
+    leaves = sorted((s for s in spans
+                     if s.name in ("decoding.admit", "decoding.pack",
+                                   "decoding.step", "decoding.emit")
+                     and s.t0 >= lo and s.t1 <= hi), key=lambda s: s.t0)
+    assert [s.name for s in leaves] == [
+        "decoding.admit", "decoding.pack", "decoding.step",
+        "decoding.emit"] * 10
+    for a, b in zip(leaves, leaves[1:]):
+        assert a.t1 <= b.t0
+    # what the leaves leave out of a turn is the loop's own lock check
+    # and the spans' bookkeeping: some tens of microseconds, a share
+    # that only a toy step of a millisecond makes visible
+    assert sum(s.t1 - s.t0 for s in leaves) >= 0.85 * (hi - lo)
+
+
+def test_reply_span_of_a_cancelled_request_is_the_handoff_alone():
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    m = _model(max_tokens=48, page_buckets=(1, 2, 4, 8, 16))
+    t_submit = ttrace.now()
+    fut = m.submit([3, 4, 5], max_new_tokens=48)
+    stream = fut.stream(timeout=60)
+    for _ in range(6):
+        next(stream)
+    fut.cancel()
+    fut.result(timeout=60)
+    m.close()
+    t_done = ttrace.now()
+    reply = [s for s in ttrace.spans_for_trace(fut.trace_id)
+             if s.name == "decoding.reply"]
+    assert len(reply) == 1
+    reply = reply[0]
+    assert reply.attrs["outcome"] == "cancelled"
+    lifetime = reply.attrs["latency_us"] * 1e-6
+    # six steps at least lie between submit and cancel; the span holds
+    # none of them
+    assert 0 < lifetime <= t_done - t_submit
+    assert reply.t1 - reply.t0 < lifetime / 5
+    assert reply.t0 > t_submit + lifetime / 2

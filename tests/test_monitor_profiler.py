@@ -59,107 +59,75 @@ def test_profiler_chrome_trace(tmp_path):
     assert any("executor_forward" in n for n in names)
 
 
-def test_collect_device_events_rebase(tmp_path):
-    """_collect_device_events on a synthetic jax-style capture: every
-    device pid is offset by 1000 (separate process lanes next to the
-    host's pid 0) and every ts is re-based by trace_t0_us onto the
-    host timeline — proven here without a real XLA capture."""
-    import gzip
+def _capture(name, ops, modules):
+    """One device of a capture in the plain-list form
+    profiling.timeline.read_xplane returns (seconds)."""
+    return {"name": name,
+            "ops": [(n, n, t0, t1) for n, t0, t1 in ops],
+            "modules": [(n, n, t0, t1) for n, t0, t1 in modules]}
 
+
+def test_collect_device_events_rebase(tmp_path, monkeypatch):
+    """_collect_device_events on a synthetic capture: every device
+    gets a process lane of its own (pid 1001.. next to the host's
+    pid 0) and every ts is re-based by trace_t0_us onto the host
+    timeline — proven here without a real device capture, at the
+    plain-list seam (a CPU capture has no device plane)."""
     from mxnet_tpu import profiler
+    from mxnet_tpu.profiling import timeline
 
-    run_dir = tmp_path / "plugins" / "profile" / "run1"
-    run_dir.mkdir(parents=True)
-    device = {"traceEvents": [
-        {"name": "fusion", "pid": 2, "tid": 1, "ph": "X",
-         "ts": 10.0, "dur": 5.0},
-        {"name": "copy", "pid": 3, "tid": 0, "ph": "X",
-         "ts": 20.5, "dur": 1.0},
-        # metadata event without ts/pid-int must pass through intact
-        {"name": "process_name", "ph": "M", "pid": "meta"},
-    ]}
-    with gzip.open(str(run_dir / "host.trace.json.gz"), "wt") as f:
-        json.dump(device, f)
-
-    old_base = profiler._state.get("trace_t0_us")
-    profiler._state["trace_t0_us"] = 1000.0
-    try:
-        out = profiler._collect_device_events(str(tmp_path))
-    finally:
-        if old_base is None:
-            profiler._state.pop("trace_t0_us", None)
-        else:
-            profiler._state["trace_t0_us"] = old_base
+    raw = {"devices": [
+        _capture("/device:TPU:0", [("fusion", 10e-6, 15e-6)],
+                 [("jit_a(1)", 0.0, 1.0)]),
+        _capture("/device:TPU:1", [("copy", 20.5e-6, 21.5e-6)],
+                 [("jit_a(1)", 0.0, 1.0)])], "host": []}
+    monkeypatch.setattr(timeline, "read_xplane", lambda d: raw)
+    monkeypatch.setitem(profiler._state, "trace_t0_us", 1000.0)
+    out = profiler._collect_device_events(str(tmp_path))
 
     by_name = {e["name"]: e for e in out}
-    assert by_name["fusion"]["pid"] == 1002   # 2 + 1000
-    assert by_name["copy"]["pid"] == 1003
-    assert by_name["fusion"]["ts"] == 1010.0  # 10 + trace_t0_us
-    assert by_name["copy"]["ts"] == 1020.5
-    # non-numeric pid / missing ts untouched
-    assert by_name["process_name"]["pid"] == "meta"
-    assert "ts" not in by_name["process_name"]
+    assert by_name["fusion"]["pid"] == 1001
+    assert by_name["copy"]["pid"] == 1002
+    assert abs(by_name["fusion"]["ts"] - 1010.0) < 1e-6  # 10 + t0
+    assert abs(by_name["copy"]["ts"] - 1020.5) < 1e-6
+    assert abs(by_name["fusion"]["dur"] - 5.0) < 1e-6
+    # the launch that covers an operation is named; without a scope
+    # map for that module the operation is unscoped
+    assert by_name["fusion"]["args"] == {"module": "jit_a",
+                                         "scope": "unscoped"}
 
 
-def test_collect_device_events_multi_file(tmp_path):
-    """A multi-host/multi-device capture writes SIBLING per-host files
-    into one run directory, and each file numbers its own devices from
-    scratch — two devices that both call themselves pid 2 must land in
-    distinct lanes (previously only the newest file was read and
-    colliding pids would have merged). A torn file is skipped without
-    dropping the others, and files of an OLDER run are ignored."""
-    import gzip
+def test_collect_device_events_newest_capture(tmp_path):
+    """Of several captures under one directory only the NEWEST is
+    read (real `.xplane.pb` files of two CPU captures; their host
+    plane holds the telemetry spans open during each)."""
     import os as _os
+    import time as _time
 
-    from mxnet_tpu import profiler
+    import jax
 
-    run_dir = tmp_path / "plugins" / "profile" / "run2"
-    run_dir.mkdir(parents=True)
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.profiling import timeline
 
-    def write(name, events):
-        with gzip.open(str(run_dir / name), "wt") as f:
-            json.dump({"traceEvents": events}, f)
-
-    write("a.trace.json.gz",
-          [{"name": "fusion_a", "pid": 2, "ph": "X",
-            "ts": 1.0, "dur": 2.0}])
-    write("b.trace.json.gz",
-          [{"name": "fusion_b", "pid": 2, "ph": "X",
-            "ts": 3.0, "dur": 4.0},
-           {"name": "copy_b", "pid": 3, "ph": "X",
-            "ts": 5.0, "dur": 1.0}])
-    with open(str(run_dir / "c.trace.json.gz"), "wb") as f:
-        f.write(b"not gzip at all")  # torn capture file
-
-    # an older sibling run: must not contribute events
-    old_run = tmp_path / "plugins" / "profile" / "run1"
-    old_run.mkdir()
-    with gzip.open(str(old_run / "stale.trace.json.gz"), "wt") as f:
-        json.dump({"traceEvents": [
-            {"name": "stale", "pid": 2, "ph": "X",
-             "ts": 0.0, "dur": 9.0}]}, f)
-    _os.utime(str(old_run / "stale.trace.json.gz"), (1, 1))
-
-    old_base = profiler._state.get("trace_t0_us")
-    profiler._state["trace_t0_us"] = 100.0
-    try:
-        out = profiler._collect_device_events(str(tmp_path))
-    finally:
-        if old_base is None:
-            profiler._state.pop("trace_t0_us", None)
-        else:
-            profiler._state["trace_t0_us"] = old_base
-
-    by_name = {e["name"]: e for e in out}
-    assert "stale" not in by_name
-    # file 0 keeps the historical +1000 lane; file 1's identically
-    # numbered device gets its own +2000 lane
-    assert by_name["fusion_a"]["pid"] == 1002
-    assert by_name["fusion_b"]["pid"] == 2002
-    assert by_name["copy_b"]["pid"] == 2003
-    pids = {e["pid"] for e in out}
-    assert len(pids) == 3
-    assert by_name["fusion_a"]["ts"] == 101.0  # rebased onto host
+    for name in ("monitor.first_capture", "monitor.second_capture"):
+        jax.profiler.start_trace(str(tmp_path))
+        with telemetry.span(name):
+            pass
+        jax.profiler.stop_trace()
+        _time.sleep(1.1)   # run directories are named by the second
+    paths = sorted(
+        _os.path.join(r, f) for r, _, fs in _os.walk(str(tmp_path))
+        for f in fs if f.endswith(".xplane.pb"))
+    assert len(paths) == 2
+    _os.utime(paths[0], (1, 1))
+    raw = timeline.read_xplane(str(tmp_path))
+    assert raw["path"] == paths[1]
+    names = {n for n, _, _ in raw["host"]}
+    assert "monitor.second_capture" in names
+    assert "monitor.first_capture" not in names
+    # a CPU capture has no device plane: nothing to merge
+    assert raw["devices"] == []
+    assert timeline.device_slices(raw) == []
 
 
 def test_collect_device_events_empty_dir(tmp_path):
@@ -248,12 +216,29 @@ def test_stop_without_run_is_noop(tmp_path, monkeypatch):
 
 def test_profiler_merges_device_trace(tmp_path, monkeypatch):
     """With a device capture enabled, the dumped Chrome trace must be
-    ONE file holding both host events (pid 0) and the XLA device
-    timeline (offset pids) — reference emits a single unified trace
-    (src/engine/profiler.cc:134); round-2 flagged the split artifact."""
+    ONE file holding both host events (pid 0) and the capture's
+    device timeline (pids 1001..) — reference emits a single unified
+    trace (src/engine/profiler.cc:134); round-2 flagged the split
+    artifact."""
+    from mxnet_tpu.profiling import timeline
+
     fn = str(tmp_path / "merged.json")
     trace_dir = str(tmp_path / "xla")
     monkeypatch.setenv("MXNET_TPU_XLA_TRACE_DIR", trace_dir)
+    real_read = timeline.read_xplane
+
+    def read_with_a_device(d):
+        # the real capture is read for real; a CPU capture has no
+        # device plane, so one operation 1 ms into the capture stands
+        # in for it at the plain-list seam
+        raw = real_read(d)
+        assert raw is not None and raw["devices"] == []
+        raw["devices"] = [_capture("/device:TPU:0",
+                                   [("fusion.1", 1e-3, 2e-3)],
+                                   [("jit_fwd(1)", 0.0, 1.0)])]
+        return raw
+
+    monkeypatch.setattr(timeline, "read_xplane", read_with_a_device)
     mx.profiler.profiler_set_config(mode="symbolic", filename=fn)
     mx.profiler.profiler_set_state("run")
     net = _net()

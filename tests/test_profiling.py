@@ -14,7 +14,6 @@ The contracts under test:
     restart (fresh store, same path); calibrated_cost prefers measured
     evidence over the analytic byte model.
 """
-import gzip
 import json
 import os
 
@@ -358,31 +357,137 @@ def test_serving_warmup_harvests_calibration():
 # ---------------------------------------------------------------------
 # op-level timelines
 # ---------------------------------------------------------------------
-def test_attribute_event_strips_jit_wrappers():
-    ev = {"name": "fusion.1", "args": {
-        "long_name": "jit(run_graph)/fc1_fwd/dot_general.3"}}
-    assert _timeline.attribute_event(ev) == "fc1_fwd"
-    assert _timeline.attribute_event(
-        {"name": "copy.2", "args": {}}) == "copy.2"
-    assert _timeline.attribute_event({"ph": "X"}) is None
+_HLO = """HloModule jit_toy, is_scheduled=true, entry_computation_layout={()->f32[4]}
+
+%fused_computation.1 (param_0.1: f32[4]) -> f32[4] {
+  %param_0.1 = f32[4]{0} parameter(0)
+  ROOT %inner.9 = f32[4]{0} negate(%param_0.1), metadata={op_name="jit(toy)/hidden/neg"}
+}
+
+%region_0.1 (reduce.1: f32[], reduce.2: f32[]) -> f32[] {
+  %reduce.1 = f32[] parameter(0)
+  %reduce.2 = f32[] parameter(1)
+  ROOT %add.5 = f32[] add(%reduce.1, %reduce.2)
+}
+
+%body.3 (arg.1: f32[4]) -> f32[4] {
+  %arg.1 = f32[4]{0} parameter(0)
+  ROOT %fusion.4 = f32[4]{0} fusion(%arg.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(toy)/l1/mlp/while/body/mul"}
+}
+
+%cond.3 (arg.2: f32[4]) -> pred[] {
+  %arg.2 = f32[4]{0} parameter(0)
+  ROOT %lt.1 = pred[] constant(false)
+}
+
+ENTRY %main.9 (x.1: f32[4]) -> f32[4] {
+  %x.1 = f32[4]{0} parameter(0), metadata={op_name="x"}
+  %copy.1 = f32[4]{0} copy(%x.1)
+  %fusion.1 = f32[4]{0} fusion(%copy.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(toy)/jit(main)/l0/kv_write/scatter" source_file="m.py"}
+  %fusion.2 = f32[4]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(toy)/transpose(jvp(conv0))/conv_general_dilated;jit(toy)/bn0/add"}
+  %fusion.2.remat_uncompressed = f32[4]{0} copy(%fusion.2)
+  %while.1 = f32[4]{0} while(%fusion.2.remat_uncompressed), condition=%cond.3, body=%body.3, metadata={op_name="jit(toy)/l1/mlp/while"}
+  %reduce.9 = f32[] reduce(%while.1, %fusion.2), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(toy)/vmap(jit(_helper))/reduce_sum"}
+  %constant.7 = f32[] constant(0)
+  %copy.2 = f32[4]{0} copy(%while.1)
+  ROOT %tuple.3 = (f32[], f32[4]{0}) tuple(%reduce.9, %copy.2)
+}
+"""
+
+
+def test_scope_path_strips_jit_and_autodiff_wrappers():
+    path = _timeline.scope_path
+    assert path("jit(run_graph)/fc1_fwd/dot_general") == "fc1_fwd"
+    assert path("jit(step)/jit(main)/transpose(jvp(conv0))/"
+                "conv_general_dilated") == "conv0"
+    assert path("jit(decode_p8)/l3/attn/bhd,bthd->bht/dot_general") \
+        == "l3/attn/bhd,bthd->bht"
+    # a jit under a vmap is a function's name, not a scope; `vmap()`
+    # wraps nothing
+    assert path("jit(f)/sample/vmap()/vmap(jit(_fold_in))/mul") \
+        == "sample"
+    assert path("jit(f)/add") is None      # no scope encloses it
+    assert path("params['embed']") is None
+    assert _timeline.module_name(_HLO) == "jit_toy"
+    assert _timeline.module_name("ENTRY %main () -> f32[] {") is None
+
+
+def test_scope_map_of_hand_written_hlo():
+    """Every instruction a device can report gets its scope: its own
+    metadata (wrappers unwrapped, the first of ';'-joined paths), else
+    the first user's, else the producer's, else `unscoped`."""
+    m = _timeline.parse_scope_map(_HLO)
+    assert m["fusion.1"] == "l0/kv_write"
+    assert m["fusion.2"] == "conv0"
+    # a layout copy without metadata: the scope of its first user
+    assert m["copy.1"] == "l0/kv_write"
+    assert m["fusion.2.remat_uncompressed"] == "l1/mlp"
+    # ... a parameter too (its own op_name holds no scope)
+    assert m["x.1"] == "l0/kv_write"
+    # its only user is the unnamed root tuple: the scope of what
+    # produces its operand, not of the tuple's other operands
+    assert m["copy.2"] == "l1/mlp"
+    # a helper's name under vmap(jit()) is no scope: the producer's
+    assert m["reduce.9"] == "l1/mlp"
+    assert m["tuple.3"] == "l1/mlp"
+    # neither user nor operand: unscoped
+    assert m["constant.7"] == "unscoped"
+    # control flow under the entry is reported by the device, fused
+    # bodies and reducers are not
+    assert m["fusion.4"] == "l1/mlp/while/body"
+    assert "lt.1" in m
+    assert "inner.9" not in m and "add.5" not in m
+
+
+def test_scope_map_outlives_the_executable():
+    def toy(x):
+        with jax.named_scope("l0"):
+            with jax.named_scope("attn"):
+                return jnp.tanh(x) * 2.0
+
+    wrapped = profiling.instrument(jax.jit(toy), digest="t-scope",
+                                   kind="unit")
+    wrapped(jnp.arange(8.0))
+    rec = profiling.records_for(digest="t-scope")[0]
+    assert rec["module"] == "jit_toy" and rec["scope_text_s"] > 0
+    del wrapped
+    jax.clear_caches()
+    m = profiling.scope_map("jit_toy")
+    assert m and set(m.values()) <= {"l0/attn", "unscoped"}
+    assert "l0/attn" in m.values()
+    assert profiling.device_stats()["totals"]["scope_parse_s"] > 0
+    assert profiling.scope_map("jit_never_built") is None
+
+
+def _capture(ops, modules=None, name="/device:TPU:0"):
+    """One device of a capture in the plain-list form read_xplane
+    returns; `ops` [(instr, t0_us, dur_us)], one launch of `jit_g`
+    covering them all unless `modules` says otherwise."""
+    ops = [(n, n, t0 * 1e-6, (t0 + d) * 1e-6) for n, t0, d in ops]
+    if modules is None:
+        modules = [("jit_g(7)", "jit_g(7)", 0.0, 1.0)]
+    return {"name": name, "ops": ops, "modules": modules}
+
+
+_MAPS = {"jit_g": {"f1": "conv0", "f2": "conv0", "f3": "relu0"}}
 
 
 def test_aggregate_and_ingest_device_events():
-    events = [
-        {"ph": "X", "dur": 5.0, "name": "f1",
-         "pid": 1002, "args": {"long_name": "jit(g)/conv0/conv.1"}},
-        {"ph": "X", "dur": 3.0, "name": "f2",
-         "pid": 1002, "args": {"long_name": "jit(g)/conv0/conv.2"}},
-        {"ph": "X", "dur": 2.0, "name": "f3",
-         "pid": 2002, "args": {"long_name": "jit(g)/relu0/max.1"}},
-        {"ph": "M", "name": "process_name", "pid": 1002},  # metadata
-        {"ph": "X", "name": "no_dur", "pid": 1002},         # no dur
+    raw = {"devices": [
+        _capture([("f1", 10, 5.0), ("f2", 20, 3.0)]),
+        _capture([("f3", 10, 2.0)], name="/device:TPU:1")], "host": []}
+    events = _timeline.device_slices(raw, _MAPS.get)
+    assert [e["pid"] for e in events] == [1001, 1001, 1002]
+    assert events[0]["args"] == {"module": "jit_g", "scope": "conv0"}
+    events += [
+        {"ph": "M", "name": "process_name", "pid": 1001},   # metadata
+        {"ph": "X", "name": "no_dur", "pid": 1001},         # no dur
     ]
     _timeline.ingest_device_events(events)
     stats = _timeline.timeline_stats()
-    assert stats["ops"]["conv0"] == {
-        "count": 2, "total_us": 8.0, "max_us": 5.0, "mean_us": 4.0}
-    assert stats["ops"]["relu0"]["total_us"] == 2.0
+    assert stats["ops"]["conv0"] == pytest.approx({
+        "count": 2, "total_us": 8.0, "max_us": 5.0, "mean_us": 4.0})
+    assert stats["ops"]["relu0"]["total_us"] == pytest.approx(2.0)
     assert stats["totals"]["events"] == 3
     assert stats["totals"]["captures"] == 1
     assert stats["totals"]["devices"] == 2
@@ -391,32 +496,60 @@ def test_aggregate_and_ingest_device_events():
     assert _timeline.timeline_stats()["ops"]["conv0"]["count"] == 3
 
 
+def test_device_slices_attribute_by_the_covering_launch():
+    """Instruction names repeat across programs: the launch that
+    covers an operation in time decides whose scope map reads it; an
+    operation the map does not place is unscoped, one outside every
+    launch keeps its own name."""
+    raw = {"devices": [_capture(
+        [("f1", 10, 5.0), ("f1", 110, 5.0), ("f9", 120, 1.0),
+         ("f1", 900, 1.0)],
+        modules=[("jit_g(7)", "jit_g(7)", 0.0, 100e-6),
+                 ("jit_h(8)", "jit_h(8)", 100e-6, 200e-6)])],
+        "host": []}
+    maps = {"jit_g": {"f1": "conv0"}, "jit_h": {"f1": "l0/attn"}}
+    ev = _timeline.device_slices(raw, maps.get, base_us=1000.0)
+    assert [e["args"].get("scope") for e in ev] == [
+        "conv0", "l0/attn", "unscoped", None]
+    assert ev[0]["ts"] == pytest.approx(1010.0)   # onto the host clock
+    agg = _timeline.aggregate_device_events(ev)
+    assert set(agg) == {"conv0", "l0/attn", "unscoped", "f1"}
+
+
 def test_timeline_topk(monkeypatch):
     monkeypatch.setenv("MXNET_PROFILING_TOPK", "2")
-    _timeline.ingest_device_events([
-        {"ph": "X", "dur": float(d), "name": f"op{d}",
-         "args": {"long_name": f"jit(g)/node{d}/x"}}
-        for d in (1, 2, 3, 4)])
+    raw = {"devices": [_capture([(f"op{d}", 10 * d, float(d))
+                                 for d in (1, 2, 3, 4)])], "host": []}
+    maps = {"jit_g": {f"op{d}": f"node{d}" for d in (1, 2, 3, 4)}}
+    _timeline.ingest_device_events(
+        _timeline.device_slices(raw, maps.get))
     stats = _timeline.timeline_stats()
     assert list(stats["ops"]) == ["node4", "node3"]  # by total_us
     assert stats["totals"]["distinct_ops"] == 4
     assert stats["totals"]["shown"] == 2
 
 
-def test_dump_profile_embeds_timeline_of_same_capture(tmp_path):
+def test_dump_profile_embeds_timeline_of_same_capture(tmp_path,
+                                                      monkeypatch):
     """The deviceTimelineStats view embedded in a dump must reflect
     the device capture written in the SAME file (events are ingested
-    before the view snapshot)."""
+    before the view snapshot), through the scope maps the record table
+    keeps. A CPU capture has no device plane, so the capture comes in
+    at the plain-list seam."""
     from mxnet_tpu import profiler
 
-    run_dir = tmp_path / "plugins" / "profile" / "run1"
-    run_dir.mkdir(parents=True)
-    with gzip.open(str(run_dir / "host.trace.json.gz"), "wt") as f:
-        json.dump({"traceEvents": [
-            {"ph": "X", "dur": 7.0, "ts": 1.0, "pid": 2,
-             "name": "fusion",
-             "args": {"long_name": "jit(run)/fc_fwd/dot.1"}},
-        ]}, f)
+    def fc(x):
+        with jax.named_scope("fc_fwd"):
+            return jnp.tanh(x)
+
+    profiling.instrument(jax.jit(fc), digest="t-dump", kind="unit")(
+        jnp.arange(4.0))
+    instr = next(k for k, v in profiling.scope_map("jit_fc").items()
+                 if v == "fc_fwd")
+    raw = {"devices": [_capture(
+        [(instr, 1.0, 7.0)],
+        modules=[("jit_fc(3)", "jit_fc(3)", 0.0, 1.0)])], "host": []}
+    monkeypatch.setattr(_timeline, "read_xplane", lambda d: raw)
 
     old = dict(profiler._state)
     profiler.profiler_set_config(filename=str(tmp_path / "prof.json"))
@@ -428,9 +561,10 @@ def test_dump_profile_embeds_timeline_of_same_capture(tmp_path):
     with open(fn) as f:
         dump = json.load(f)
     assert dump["deviceTimelineStats"]["ops"]["fc_fwd"]["total_us"] \
-        == 7.0
-    # the raw device slice itself rides along under its offset pid
-    assert any(e.get("pid") == 1002 for e in dump["traceEvents"])
+        == pytest.approx(7.0)
+    # the raw device slice itself rides along in its device's lane
+    assert any(e.get("pid") == 1001 and e["args"]["scope"] == "fc_fwd"
+               for e in dump["traceEvents"])
 
 
 # ---------------------------------------------------------------------

@@ -254,6 +254,117 @@ def test_fit_records_step_spans():
         {s.name for s in step0}
 
 
+def test_fit_dispatch_is_partitioned_by_leaf_spans():
+    """fit.dispatch = fit.stage + fit.launch + fit.metric +
+    fit.window_wait: children of the dispatch, in order, without
+    overlap, summing to it; the wait carries its scalar fetch's own
+    time."""
+    d = mx.sym.Variable("data")
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(d, num_hidden=64, name="fc"),
+        name="softmax")
+    rs = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(
+        rs.rand(256, 512).astype("float32"),
+        rs.randint(0, 2, (256,)).astype("float32"), batch_size=32)
+    mod = mx.mod.Module(net, context=[mx.cpu()])
+    mod.fit(it, num_epoch=2, optimizer_params=(("learning_rate", 0.1),))
+    spans = telemetry.recent_spans()
+    leaves = ("fit.stage", "fit.launch", "fit.metric", "fit.window_wait")
+    dispatches = [s for s in spans if s.name == "fit.dispatch"]
+    assert len(dispatches) == 16 and all(s.parent is None
+                                         for s in dispatches)
+    waited = 0
+    for disp in dispatches[2:]:          # the first two compile
+        kids = sorted((s for s in spans if s.name in leaves
+                       and s.t0 >= disp.t0 and s.t1 <= disp.t1),
+                      key=lambda s: s.t0)
+        assert {k.parent for k in kids} == {"fit.dispatch"}
+        names = [k.name for k in kids]
+        assert names[:3] == ["fit.stage", "fit.launch", "fit.metric"]
+        assert names[3:] in ([], ["fit.window_wait"])
+        waited += len(names) == 4
+        for a, b in zip(kids, kids[1:]):
+            assert a.t1 <= b.t0
+        covered = sum(k.t1 - k.t0 for k in kids)
+        assert covered >= 0.95 * (disp.t1 - disp.t0) \
+            or (disp.t1 - disp.t0) - covered < 100e-6
+    assert waited, "no dispatch had to wait for the window"
+    for w in (s for s in spans if s.name == "fit.window_wait"):
+        assert 0 <= w.attrs["fetch_us"] <= w.duration_us
+    # the epoch-end drain waits under its own parent
+    assert any(s.name == "fit.window_wait"
+               and s.parent == "fit.metric_drain" for s in spans)
+    # the iterator's end is a data wait too, marked as such
+    ends = [s for s in spans if s.name == "fit.data_wait"
+            and s.attrs and s.attrs.get("error") == "StopIteration"]
+    assert len(ends) == 2
+
+
+def test_span_parent_is_the_enclosing_span_on_the_same_thread():
+    import threading
+
+    with telemetry.span("outer.a", trace_id="t"):
+        with telemetry.span("inner.b") as sp:
+            sp.note(k=1)
+            sp.note(j=2)
+        ttrace.record_span("after.fact", "t", 0.0, 1.0)
+        other = threading.Thread(
+            target=lambda: ttrace.record_span("other.thread", "t", 0.0,
+                                              1.0))
+        other.start()
+        other.join(10)
+    ttrace.record_span("top.level", "t", 0.0, 1.0)
+    ttrace.record_span("explicit.parent", "t", 0.0, 1.0,
+                       parent="outer.a")
+    got = {s.name: s for s in telemetry.recent_spans()}
+    assert got["inner.b"].parent == "outer.a"
+    assert got["inner.b"].attrs == {"k": 1, "j": 2}
+    assert got["after.fact"].parent == "outer.a"
+    assert got["other.thread"].parent is None
+    assert got["outer.a"].parent is None
+    assert got["top.level"].parent is None
+    assert got["explicit.parent"].parent == "outer.a"
+    assert got["inner.b"].to_dict()["parent"] == "outer.a"
+    assert "parent" not in got["outer.a"].to_dict()
+    # an exception unwinds the stack
+    with pytest.raises(ValueError):
+        with telemetry.span("outer.raises"):
+            raise ValueError("x")
+    ttrace.record_span("after.raise", "t", 0.0, 1.0)
+    assert telemetry.recent_spans()[-1].parent is None
+
+
+def test_span_shows_up_in_a_profiler_capture_under_its_own_name(
+        tmp_path):
+    """A span opened while a jax.profiler capture runs sits in the
+    capture's host plane, on the profiler's clock, under its own name:
+    its length there agrees with the ring's."""
+    import jax
+    from mxnet_tpu.profiling import timeline
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("decoding.step", live=1):
+            with telemetry.span("engine.fetch"):
+                sum(range(20000))
+    finally:
+        jax.profiler.stop_trace()
+    raw = timeline.read_xplane(str(tmp_path))
+    host = {n: (t0, t1) for n, t0, t1 in raw["host"]}
+    assert {"decoding.step", "engine.fetch"} <= set(host)
+    ring = {s.name: s for s in telemetry.recent_spans()}
+    for name in ("decoding.step", "engine.fetch"):
+        in_capture = host[name][1] - host[name][0]
+        in_ring = ring[name].t1 - ring[name].t0
+        assert abs(in_capture - in_ring) < 200e-6
+    # the child lies inside its parent on the capture's clock too
+    assert host["decoding.step"][0] <= host["engine.fetch"][0]
+    assert host["engine.fetch"][1] <= host["decoding.step"][1]
+    # the runtime's own annotations are not the program's spans
+    assert not any("::" in n or n.startswith("$") for n in host)
+
+
 # ------------------------------------------------------ HTTP exporter
 def test_exporter_endpoints_agree_with_process_state():
     net = _fixed_net()

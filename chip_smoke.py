@@ -464,19 +464,26 @@ def _kernel_paged(dcfg, spec, kv_dtype, seed):
     b, p = spec["max_batch"], spec["page_size"]
     bp, n = spec["page_buckets"][0], spec["num_pages"]
     rs = np.random.RandomState(seed)
+    # two layers, the second one read: the kernels take the whole pool
+    # and the layer's index (decoding/quant.py), never a slice of it
     pools = []
     for _ in range(2):
-        pool = quant.make_pool((1, n, p, h, d), kv_dtype)
+        pool = quant.make_pool((2, n, p, h, d), kv_dtype)
         vals = jnp.asarray(rs.standard_normal((n * p, h, d)), jnp.float32)
         pool, _ = quant.kv_scatter(
-            pool, 0, jnp.repeat(jnp.arange(n), p),
+            pool, 1, jnp.repeat(jnp.arange(n), p),
             jnp.tile(jnp.arange(p), n), vals)
-        pools.append(pool.layer(0))
+        pools.append(pool)
     q = jnp.asarray(rs.standard_normal((b, h, d)), jnp.float32)
     table = jnp.asarray(rs.randint(1, n, (b, bp)), jnp.int32)
     lengths = jnp.asarray(rs.randint(1, bp * p + 1, (b,)), jnp.int32)
-    pallas = jax.jit(attn.paged_attention_pallas)
-    lax = jax.jit(attn.paged_attention_lax)
+
+    def on_layer(kernel):
+        return jax.jit(lambda q, k, v, table, lengths: kernel(
+            q, k.layer(1), v.layer(1), table, lengths))
+
+    pallas = on_layer(attn.paged_attention_pallas)
+    lax = on_layer(attn.paged_attention_lax)
     args = (q, pools[0], pools[1], table, lengths)
     with jax.default_matmul_precision("highest"):  # the lax twin's dots
         err = _max_err(pallas(*args), lax(*args))

@@ -4,10 +4,19 @@ KV pool (the device half of Ragged Paged Attention, PAPERS.md).
 Contract shared by both kernels:
 
   q           (B, H, D)        one query token per batch row
-  k_pages     (N, P, H, D)     the pool (one layer's K pages) — a raw
-                               float array or a quant.KVPool, whose
-                               int8 pages dequantize INSIDE the kernel
-  v_pages     (N, P, H, D)     the pool (one layer's V pages)
+  k_pages     quant.KVLayer    one layer of the K pool as a (pool,
+                               layer index) pair — `pool.layer(i)`.
+                               The pool's data is (L, N, P, H*D): a
+                               token's whole row, all heads side by
+                               side, in the minor dimension (quant.py
+                               says why). The kernels read pages
+                               `data[layer, page]` straight from the
+                               pool — no layer is sliced out first —
+                               and split heads only on what they have
+                               read; int8 pages dequantize INSIDE the
+                               kernel. A bare float array (N, P, H*D)
+                               is taken as a one-layer pool
+  v_pages     quant.KVLayer    the same layer of the V pool
   page_table  (B, Bp) int32    per-row page ids, seq-ordered; padding
                                entries point at the scratch page 0
   lengths     (B,) int32       valid context tokens per row (masking;
@@ -22,8 +31,9 @@ pages bucket and steady-state decode provably adds zero traces.
 Two implementations behind `MXNET_DECODE_KERNEL`:
 
   lax     (default) gather the Bp pages per row into a contiguous
-          (B, Bp*P, H, D) context and run masked softmax attention —
-          pure lax, runs anywhere, XLA fuses the gather.
+          (B, Bp*P, H*D) context, split its rows into heads and run
+          masked softmax attention — pure lax, runs anywhere, XLA
+          fuses the gather.
   pallas  flash-style online-softmax kernel on a (B, Bp) grid whose
           K/V block index maps read the page table via scalar
           prefetch (PrefetchScalarGridSpec) — pages stream HBM->VMEM
@@ -48,25 +58,32 @@ from . import quant as _quant
 NEG_INF = -1e30
 
 
-def _check_shapes(q, k_pages, v_pages, page_table, lengths):
-    b, h, d = q.shape
-    n, p, hh, dd = k_pages.shape
+def _check_pool(k_pages, v_pages, h, d):
+    """The page size, once the two layers agree with each other and
+    with the query's heads."""
+    _, p, hd = k_pages.shape
     if k_pages.shape != v_pages.shape:
         raise ValueError("k_pages/v_pages shape mismatch")
-    if (hh, dd) != (h, d):
+    if hd != h * d:
         raise ValueError(
-            f"pool heads/dim {(hh, dd)} != query {(h, d)}")
+            f"pool row width {hd} != query heads*dim {h}*{d}")
+    return p
+
+
+def _check_shapes(q, k_pages, v_pages, page_table, lengths):
+    b, h, d = q.shape
+    p = _check_pool(k_pages, v_pages, h, d)
     if page_table.shape[0] != b or lengths.shape != (b,):
         raise ValueError("page_table/lengths batch mismatch")
-    return b, h, d, n, p, page_table.shape[1]
+    return b, h, d, p, page_table.shape[1]
 
 
 def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
                         scale=None):
     """Gather-based reference kernel (see module docstring)."""
-    k_pages = _quant.as_pool(k_pages)
-    v_pages = _quant.as_pool(v_pages)
-    b, h, d, _, p, bp = _check_shapes(
+    k_pages = _quant.as_layer(k_pages)
+    v_pages = _quant.as_layer(v_pages)
+    b, h, d, p, bp = _check_shapes(
         q, k_pages, v_pages, page_table, lengths)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -74,8 +91,8 @@ def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
     # (B, Bp, P, H, D) -> (B, T, H, D): pages are seq-ordered, so the
     # flattened axis IS the token axis (positions >= length masked);
     # gather_ctx dequantizes only the gathered pages, never the pool
-    k_ctx = _quant.gather_ctx(k_pages, page_table).reshape(b, t, h, d)
-    v_ctx = _quant.gather_ctx(v_pages, page_table).reshape(b, t, h, d)
+    k_ctx = _quant.gather_ctx(k_pages, page_table, h).reshape(b, t, h, d)
+    v_ctx = _quant.gather_ctx(v_pages, page_table, h).reshape(b, t, h, d)
     s = jnp.einsum("bhd,bthd->bht", q, k_ctx,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(t)[None, :] < lengths[:, None]
@@ -104,20 +121,17 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
     speculative verify step (queries = last_token + K drafts). Shapes
     are a function of (B, S, pages bucket) only.
     """
-    k_pages = _quant.as_pool(k_pages)
-    v_pages = _quant.as_pool(v_pages)
+    k_pages = _quant.as_layer(k_pages)
+    v_pages = _quant.as_layer(v_pages)
     b, s, h, d = q.shape
-    n, p, hh, dd = k_pages.shape
-    if (hh, dd) != (h, d):
-        raise ValueError(
-            f"pool heads/dim {(hh, dd)} != query {(h, d)}")
+    p = _check_pool(k_pages, v_pages, h, d)
     if page_table.shape[0] != b or q_positions.shape != (b, s):
         raise ValueError("page_table/q_positions batch mismatch")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     t = page_table.shape[1] * p
-    k_ctx = _quant.gather_ctx(k_pages, page_table).reshape(b, t, h, d)
-    v_ctx = _quant.gather_ctx(v_pages, page_table).reshape(b, t, h, d)
+    k_ctx = _quant.gather_ctx(k_pages, page_table, h).reshape(b, t, h, d)
+    v_ctx = _quant.gather_ctx(v_pages, page_table, h).reshape(b, t, h, d)
     sc = jnp.einsum("bshd,bthd->bhst", q, k_ctx,
                     preferred_element_type=jnp.float32) * scale
     mask = (jnp.arange(t)[None, None, :]
@@ -132,23 +146,38 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
 
 
 # ---------------------------------------------------------------- pallas
-def _paged_attn_kernel(page_size, quantized):
+def _paged_attn_kernel(page_size, heads, quantized):
     """Kernel body on a (B, Bp) grid: one (page, row) tile per step,
     online-softmax accumulated in VMEM scratch across the Bp axis.
 
-    One query per row makes the step memory-bound (a page of K and V
-    in, H*D out), so the two contractions run on the VPU as
-    multiply + reduce with the page in its stored (P, H, D)
-    orientation: scores stay (P, H, 1) — head on sublanes, keepdims —
-    and broadcast back over D without a relayout. (An MXU dot would
-    need the head batch dimension leading on both operands; Mosaic
-    refuses "hd,phd->hp".) Quantized pools carry two extra scale refs
-    (one per K/V page, gathered by the SAME page-table index maps)
-    that dequantize each int8 page as it lands in VMEM — the pool is
-    never upcast in HBM, which is the whole point of int8 pages."""
+    A page lands in VMEM as the pool stores it, (P, H*D): every head
+    of a token side by side on the lanes. Mosaic will not split the
+    lane dimension into (H, D) for D under 128, so the heads are never
+    split: the elementwise product q*K is summed per head by a matmul
+    with the 0/1 segment matrix `seg` (H, H*D) (seg[h, j] = 1 where
+    lane j belongs to head h), giving scores (P, H); the same matrix
+    spreads the softmax weights (P, H) back over their head's lanes
+    for the product with V. Running max and sum stay (1, H), the
+    accumulator (1, H*D). Quantized pools carry two extra scale refs
+    (P, H), one per K/V page, applied to the scores and to the weights
+    — per (slot, head), the same arithmetic as dequantizing the page —
+    so the pool is never upcast in HBM, which is the whole point of
+    int8 pages."""
     from jax.experimental import pallas as pl
 
-    def kernel(pt_ref, len_ref, q_ref, *refs):
+    def per_head(x, seg):
+        # (R, H*D) -> (R, H): sum each head's lanes
+        return jax.lax.dot_general(
+            x, seg, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    def over_lanes(x, seg):
+        # (R, H) -> (R, H*D): repeat each head's value over its lanes
+        return jnp.dot(x, seg, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def kernel(pt_ref, len_ref, layer_ref, q_ref, *refs):
         if quantized:
             k_ref, ks_ref, v_ref, vs_ref = refs[:4]
         else:
@@ -164,85 +193,104 @@ def _paged_attn_kernel(page_size, quantized):
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        qb = q_ref[0].astype(jnp.float32)          # (H, D)
-        kb = k_ref[0].astype(jnp.float32)          # (P, H, D)
-        vb = v_ref[0].astype(jnp.float32)
+        qb = q_ref[0].astype(jnp.float32)          # (1, H*D)
+        kb = k_ref[0, 0].astype(jnp.float32)       # (P, H*D)
+        vb = v_ref[0, 0].astype(jnp.float32)
+        hd = qb.shape[-1]
+        d = hd // heads
+        seg = (jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1) // d
+               == jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0)
+               ).astype(jnp.float32)               # (H, H*D)
+        s = per_head(qb * kb, seg) * (1.0 / math.sqrt(d))   # (P, H)
         if quantized:
-            # per-(slot, head) dequant: (P, H, D) int8 * (P, H, 1) f32
-            kb = kb * ks_ref[0][..., None]
-            vb = vb * vs_ref[0][..., None]
-        scale = 1.0 / math.sqrt(qb.shape[-1])
-        s = jnp.sum(qb[None] * kb, axis=-1, keepdims=True) * scale
+            s = s * ks_ref[0, 0]
         pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)                      # (P, H, 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(pos < len_ref[b], s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]         # (H, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=0))
+        m_prev, l_prev = m_ref[...], l_ref[...]         # (1, H)
+        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        e = jnp.exp(s - m_new[None])                    # (P, H, 1)
-        l_ref[...] = l_prev * corr + e.sum(axis=0)
-        acc_ref[...] = acc_ref[...] * corr + jnp.sum(e * vb, axis=0)
+        e = jnp.exp(s - m_new)                          # (P, H)
+        l_ref[...] = l_prev * corr + e.sum(axis=0, keepdims=True)
+        w = e * vs_ref[0, 0] if quantized else e
+        # one spread for the weights and the correction: (P + 1, H)
+        spread = over_lanes(jnp.concatenate([w, corr], axis=0), seg)
+        acc_ref[...] = acc_ref[...] * spread[page_size:] + jnp.sum(
+            spread[:page_size] * vb, axis=0, keepdims=True)
         m_ref[...] = m_new
 
         @pl.when(i == nbp - 1)
         def _flush():
-            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+            norm = over_lanes(1.0 / l_ref[...], seg)
+            o_ref[0] = (acc_ref[...] * norm).astype(o_ref.dtype)
 
     return kernel
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                            scale=None):
-    """Flash-style paged kernel; page ids drive the K/V block index
-    maps through scalar prefetch, so only the pages a row actually
-    owns ever move HBM->VMEM. Quantized pools' scale planes ride the
-    same index maps. Compiled on a TPU, interpreted elsewhere
-    (utils.pallas_interpret)."""
+    """Flash-style paged kernel; the layer index and the page ids
+    drive the K/V block index maps through scalar prefetch, so only
+    the pages a row actually owns ever move HBM->VMEM, straight from
+    the pool as it is stored. A quantized pool stores a page's scales
+    as one row of page_size*heads lanes, which Mosaic cannot turn into
+    the (P, H) the scores want; they come as a per-call view of the
+    GATHERED scale rows (B, Bp, P, H) — 1/head_dim of the context's
+    bytes — never of the pool. Compiled on a TPU, interpreted
+    elsewhere (utils.pallas_interpret)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    k_pages = _quant.as_pool(k_pages)
-    v_pages = _quant.as_pool(v_pages)
-    b, h, d, _, p, bp = _check_shapes(
+    k_pages = _quant.as_layer(k_pages)
+    v_pages = _quant.as_layer(v_pages)
+    b, h, d, p, bp = _check_shapes(
         q, k_pages, v_pages, page_table, lengths)
     if scale is not None and not math.isclose(
             scale, 1.0 / math.sqrt(d)):
         raise ValueError(
             "pallas kernel hard-codes scale=1/sqrt(head_dim)")
-    quantized = k_pages.scale is not None
+    quantized = k_pages.pool.scale is not None
 
-    def page_spec(bs):
+    def page_spec(width):
         return pl.BlockSpec(
-            bs, lambda bb, i, pt, ln: (pt[bb, i],) + (0,) * (len(bs) - 1))
+            (1, 1, p, width),
+            lambda bb, i, pt, ln, ly: (ly[0], pt[bb, i], 0, 0))
 
-    in_specs = [pl.BlockSpec((1, h, d), lambda bb, i, pt, ln: (bb, 0, 0))]
-    operands = [q]
-    for pool in (k_pages, v_pages):
-        in_specs.append(page_spec((1, p, h, d)))
-        operands.append(pool.data)
+    row_spec = pl.BlockSpec((1, 1, h * d),
+                            lambda bb, i, pt, ln, ly: (bb, 0, 0))
+    # one prefetched index serves both pools: every caller reads the
+    # same layer of K and V
+    layer = jnp.asarray(k_pages.index, jnp.int32).reshape(1)
+    in_specs = [row_spec]
+    operands = [q.reshape(b, 1, h * d)]
+    for layer_ in (k_pages, v_pages):
+        in_specs.append(page_spec(h * d))
+        operands.append(layer_.pool.data)
         if quantized:
-            in_specs.append(page_spec((1, p, h)))
-            operands.append(pool.scale)
+            in_specs.append(pl.BlockSpec(
+                (1, 1, p, h), lambda bb, i, pt, ln, ly: (bb, i, 0, 0)))
+            operands.append(
+                layer_.pool.scale[layer_.index, page_table].reshape(
+                    b, bp, p, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,   # page_table, lengths
+        num_scalar_prefetch=3,   # page_table, lengths, layer
         grid=(b, bp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, h, d), lambda bb, i, pt, ln: (bb, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((1, h * d), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
         ],
     )
     fn = pl.pallas_call(
-        _paged_attn_kernel(p, quantized),
+        _paged_attn_kernel(p, h, quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
         interpret=_utils.pallas_interpret(),
         name="paged_attention",
     )
-    return fn(page_table, lengths, *operands)
+    return fn(page_table, lengths, layer, *operands).reshape(b, h, d)
 
 
 # ---------------------------------------------------------------- ragged

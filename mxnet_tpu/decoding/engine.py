@@ -1,8 +1,8 @@
 """DecodeEngine: the device half of continuous batching.
 
 Owns the paged KV pool (one pre-allocated (layers, pages, page_size,
-heads, head_dim) buffer per K and V), the block allocator over it, and
-a FIXED grid of jitted programs:
+heads*head_dim) buffer per K and V — quant.py says why that order),
+the block allocator over it, and a FIXED grid of jitted programs:
 
   prefill  one program per prompt length bucket (batch 1, dense causal
            attention — optionally ring attention for long buckets —
@@ -175,7 +175,10 @@ class DecodeEngine:
         self._guard_pending = []
         # executable-accounting key: the decode grid is a function of
         # (model config, batch, paging layout, kernel) — deterministic
-        # within a process, which is all deviceStats needs
+        # within a process, which is all deviceStats needs. The pool's
+        # storage order is part of it: an AOT bundle's programs take
+        # the pools as arguments, so one compiled around another order
+        # must not match
         import hashlib as _hashlib
 
         self._digest = _hashlib.sha1(repr(
@@ -183,7 +186,7 @@ class DecodeEngine:
              self.kernel_name, self.draft_cfg,
              self.spec_k if self.spec_enabled else 0,
              self.step_rows if self.merged_step_enabled else 0,
-             self.kv_dtype)
+             self.kv_dtype, _quant.POOL_LAYOUT)
         ).encode()).hexdigest()[:12]
 
     def _jit(self, impl, name, kind, donate):
@@ -396,11 +399,9 @@ class DecodeEngine:
         # version this replaces.
         def impl(pool, src, dst):
             self._note_trace("copy_page")
-            data = pool.data.at[:, dst].set(pool.data[:, src])
-            if pool.scale is None:
-                return _quant.KVPool(data, None)
-            scale = pool.scale.at[:, dst].set(pool.scale[:, src])
-            return _quant.KVPool(data, scale)
+            return _quant.KVPool(*(
+                None if a is None else a.at[:, dst].set(a[:, src])
+                for a in pool))
 
         return self._jit(impl, "copy_page", "copy_page", (0,))
 

@@ -15,9 +15,30 @@ replaces: the fixed-shape program grid is UNCHANGED IN COUNT and the
 scale plane rides along wherever its pages go (COW copies, prefix
 shares, fleet handoffs).
 
+Storage layout (one for float32, bf16 and int8; no knob):
+
+  data   [layers, pages, page_size, heads*head_dim]
+  scale  [layers, pages, page_size*heads]    float32, int8 pools only
+
+The minor dimension of `data` is a whole K (or V) row of one token,
+all heads side by side; that of `scale` is a page's scales, slot by
+slot. Both are multiples of 128 at any served width, so the chip's
+(8, 128) tile holds them without padding whatever the storage type.
+(A minor dimension under 128 — head_dim 64, or 32 heads — is padded
+to 128; the chip then keeps the array with ANOTHER dimension minor,
+the scatter wants it otherwise, and XLA copies the WHOLE pool around
+every layer's write.) With this order a token's write is an in-place
+scatter on the donated buffer — one row of `data`, one window of
+`heads` scales at lane `slot*heads` — a read gathers
+`data[layer, page_table]` straight from the pool, the layer an index
+of the gather and never a slice taken first, and heads are split only
+on the gathered context. No decode-tier program holds a pool-sized or
+layer-sized temporary (tests/test_chip_compile.py reads the compiled
+text).
+
 Quantization scheme (symmetric, zero-point-free):
 
-  scale[l, page, slot, head] = max|K/V[l, page, slot, head, :]| / 127
+  scale[l, page, slot*H + head] = max|K/V row[head*D:(head+1)*D]| / 127
   data = round(value / scale) in [-127, 127] int8
 
 Per-(slot, head) granularity — "a per-page scale plane" in the
@@ -47,12 +68,18 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from .blocks import PageError
 
 # the knob's full surface; "fp8" is reserved (see module docstring)
 KV_DTYPES = ("float32", "bf16", "int8", "fp8")
+
+# the storage order above, by name: part of the engine's digest, so an
+# AOT bundle compiled around another order is refused, not loaded
+POOL_LAYOUT = ("data[layers,pages,page_size,heads*head_dim] "
+               "scale[layers,pages,page_size*heads]")
 
 # scale floor: keeps an all-zero (or denormal) K/V row from dividing
 # by zero; 1e-8/127 quantizes everything below float32 noise to 0
@@ -82,8 +109,10 @@ def storage_dtype(kv_dtype):
 
 class KVPool(NamedTuple):
     """One K (or V) page pool: `data` is (layers, pages, page_size,
-    heads, head_dim) in the storage dtype; `scale` is the per-(page,
-    slot, head) float32 plane for int8 pools, None otherwise.
+    heads*head_dim) in the storage dtype (see the module docstring for
+    why the heads are folded into the minor dimension); `scale` is the
+    (layers, pages, page_size*heads) float32 plane for int8 pools —
+    one scale per (slot, head) — None otherwise.
 
     NamedTuple => pytree: jit, donation and device copies treat the
     pair as one value, which is what keeps the trace grid count
@@ -107,27 +136,52 @@ class KVPool(NamedTuple):
         return "bf16" if self.data.dtype == jnp.bfloat16 else "float32"
 
     def layer(self, i):
-        """The (pages, page_size, heads, head_dim) view of one layer
-        — what the attention kernels consume."""
-        return KVPool(self.data[i],
-                      None if self.scale is None else self.scale[i])
+        """Layer `i` as the attention kernels take it: the whole pool
+        and the index, nothing sliced or copied."""
+        return KVLayer(self, i)
+
+
+class KVLayer(NamedTuple):
+    """One layer of a pool, as a (pool, layer index) pair: the reads
+    index `pool.data[index, pages]` in one gather."""
+
+    pool: KVPool
+    index: int
+
+    @property
+    def shape(self):
+        """(pages, page_size, heads*head_dim)."""
+        return self.pool.data.shape[1:]
 
 
 def as_pool(x):
     """Adopt a bare (quantization-naive) pool array as a float KVPool
-    so the attention kernels keep accepting raw arrays (tests and the
-    parity harness build those directly)."""
+    so the model functions keep accepting raw arrays."""
     return x if isinstance(x, KVPool) else KVPool(x, None)
 
 
+def as_layer(x):
+    """What the attention kernels take for `k_pages`/`v_pages`: a
+    `KVLayer`, or a bare float array (pages, page_size, heads*head_dim)
+    (tests and the parity harness build those directly), adopted as a
+    one-layer pool (a leading unit axis, no copy)."""
+    if isinstance(x, KVLayer):
+        return x
+    return KVLayer(KVPool(x[None], None), 0)
+
+
 def make_pool(shape, kv_dtype):
-    """A zeroed pool of `shape` (layers, pages, page_size, heads,
-    head_dim) at `kv_dtype`; int8 pools get their scale plane."""
+    """A zeroed pool for `shape` = (layers, pages, page_size, heads,
+    head_dim) at `kv_dtype`, stored in `POOL_LAYOUT`; int8 pools get
+    their scale plane."""
     name = canonical(kv_dtype)
-    data = jnp.zeros(shape, storage_dtype(name))
+    layers, pages, page_size, heads, head_dim = shape
+    data = jnp.zeros((layers, pages, page_size, heads * head_dim),
+                     storage_dtype(name))
     if name != "int8":
         return KVPool(data, None)
-    return KVPool(data, jnp.zeros(shape[:-1], jnp.float32))
+    return KVPool(data, jnp.zeros((layers, pages, page_size * heads),
+                                  jnp.float32))
 
 
 def quantize_values(values):
@@ -157,41 +211,64 @@ def dequantize_values(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
 
 
+def _fold_heads(x):
+    """(..., H, D) -> (..., H*D): a K/V row as the pool stores it."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
 def kv_scatter(pool, layer, pages, slots, values):
-    """Quantize-at-scatter: write `values` (..., H, D) float at
-    [layer, pages, slots] (index arrays shaped like values minus the
-    trailing (H, D)), quantizing INTO the pool's storage dtype so a
-    full-precision K/V tensor never exists outside the current
-    activations. Returns (pool', clips () i32); clips is 0 for
-    non-int8 pools."""
+    """Quantize-at-scatter: write `values` (..., H, D) float as rows
+    (..., H*D) at [layer, pages, slots] (index arrays shaped like
+    values minus the trailing (H, D)), quantizing INTO the pool's
+    storage dtype so a full-precision K/V tensor never exists outside
+    the current activations. On a donated pool the scatter is in
+    place. Returns (pool', clips () i32); clips is 0 for non-int8
+    pools."""
     if pool.scale is None:
         data = pool.data.at[layer, pages, slots].set(
-            values.astype(pool.data.dtype))
+            _fold_heads(values).astype(pool.data.dtype))
         return KVPool(data, None), jnp.int32(0)
     q, scale, clips = quantize_values(values)
-    data = pool.data.at[layer, pages, slots].set(q)
-    sc = pool.scale.at[layer, pages, slots].set(scale)
+    data = pool.data.at[layer, pages, slots].set(_fold_heads(q))
+    # the token's `heads` scales are one window of its page's row, at
+    # lane slot*heads: a windowed scatter (`.at[]` could only write
+    # them one element at a time)
+    heads = scale.shape[-1]
+    at = jnp.stack(jnp.broadcast_arrays(
+        jnp.asarray(layer, jnp.int32), pages, slots * heads), axis=-1)
+    sc = jax.lax.scatter(
+        pool.scale, at, scale,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(scale.ndim - 1,),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2)))
     return KVPool(data, sc), clips
 
 
-def gather_ctx(layer_pool, page_table):
-    """The lax attention paths' read: gather page_table's pages from
-    one layer's pool and dequantize them in-flight — (B, Bp) int32 ->
-    (B, Bp, P, H, D) float32. Only the gathered pages are ever
-    upcast, never the pool."""
-    pool = as_pool(layer_pool)
-    d = pool.data[page_table]
+def gather_ctx(layer, page_table, heads):
+    """The lax attention paths' read: gather page_table's pages of one
+    layer straight from the pool (the layer is an index of the SAME
+    gather) and dequantize them in-flight — (B, Bp) int32 ->
+    (B, Bp, P, H, D) float32. Heads are split here, on the gathered
+    context; only the gathered pages are ever upcast, never the
+    pool."""
+    pool, i = as_layer(layer)
+    d = pool.data[i, page_table].astype(jnp.float32)
+    d = d.reshape(d.shape[:-1] + (heads, d.shape[-1] // heads))
     if pool.scale is None:
-        return d.astype(jnp.float32)
-    return d.astype(jnp.float32) * pool.scale[page_table][..., None]
+        return d
+    s = pool.scale[i, page_table]
+    return d * s.reshape(s.shape[:-1] + (-1, heads, 1))
 
 
 def dequant_page(pool, layer, page):
-    """One page, dequantized to float32 (test/debug reads)."""
-    d = pool.data[layer, page]
+    """One page, dequantized to float32 (page_size, heads*head_dim)
+    (test/debug reads)."""
+    d = pool.data[layer, page].astype(jnp.float32)
     if pool.scale is None:
-        return d.astype(jnp.float32)
-    return dequantize_values(d, pool.scale[layer, page])
+        return d
+    s = pool.scale[layer, page].reshape(d.shape[0], -1, 1)   # (P, H, 1)
+    return (d.reshape(s.shape[:2] + (-1,)) * s).reshape(d.shape)
 
 
 def pool_nbytes(pool):

@@ -14,6 +14,7 @@ Two halves:
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -93,27 +94,62 @@ def test_flash_forward_compiles(compile_for_chip):
     assert "tpu_custom_call" in text
 
 
+def _pool_struct(shape, kv_dtype):
+    """quant.make_pool's result as shapes only: nothing is allocated."""
+    return jax.eval_shape(lambda: quant.make_pool(shape, kv_dtype))
+
+
 def _paged_args(kv_dtype, rows, bucket):
     dcfg, spec = FULL["decoder"], FULL["serve"]
     h = dcfg["n_heads"]
     d = dcfg["d_model"] // h
-    n, p = spec["num_pages"], spec["page_size"]
-    if kv_dtype == "int8":
-        pool = quant.KVPool(_s((n, p, h, d), jnp.int8),
-                            _s((n, p, h), jnp.float32))
-    else:
-        pool = quant.KVPool(_s((n, p, h, d), quant.storage_dtype(kv_dtype)),
-                            None)
+    pool = _pool_struct((2, spec["num_pages"], spec["page_size"], h, d),
+                        kv_dtype)
     return (_s((rows, h, d), jnp.float32), pool, pool,
             _s((rows, bucket), jnp.int32), _s((rows,), jnp.int32))
+
+
+def _on_layer(kernel, layer=1):
+    """The kernel as a decode program calls it: on one layer of whole
+    pools, read by index."""
+    return lambda q, k, v, table, lengths: kernel(
+        q, k.layer(layer), v.layer(layer), table, lengths)
+
+
+def _pool_sized_ops(text, pool_dims):
+    """Instructions of the compiled ENTRY computation whose result has
+    the pool's or one layer's shape and is not an in-place scatter (a
+    fusion of a `scatter` whose result aliases its operand): `copy`,
+    `fusion` (a relayout or a slice made whole) and the like.
+    Parameters, tuples and views of the donated buffer do not count."""
+    entry = text[text.index("ENTRY "):]
+    shapes = [",".join(map(str, pool_dims)), ",".join(map(str, pool_dims[1:]))]
+    found = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
+                     line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        if op in ("parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        if not any(f"[{dims}]" in result for dims in shapes):
+            continue
+        if op == "fusion" and "/scatter" in line \
+                and '"aliasing_operands"' in line:
+            continue    # written in place: the result IS operand 0
+        found.append(f"{name} = {result} {op}")
+    return found
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "bf16", "int8"])
 @pytest.mark.parametrize("bucket", FULL["serve"]["page_buckets"])
 def test_paged_attention_compiles(compile_for_chip, kv_dtype, bucket):
     args = _paged_args(kv_dtype, FULL["serve"]["max_batch"], bucket)
-    text = compile_for_chip(paged.paged_attention_pallas, *args)
+    text = compile_for_chip(_on_layer(paged.paged_attention_pallas), *args)
     assert "tpu_custom_call" in text
+    # the kernel reads pages from the pool as stored: no relayout of it
+    assert not _pool_sized_ops(text, args[1].data.shape)
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
@@ -123,8 +159,118 @@ def test_ragged_paged_attention_compiles(compile_for_chip, kv_dtype):
     spec = FULL["serve"]
     args = _paged_args(kv_dtype, spec["max_batch"] + spec["page_size"],
                        spec["page_buckets"][-1])
-    text = compile_for_chip(paged.get_ragged_kernel("pallas"), *args)
+    text = compile_for_chip(_on_layer(paged.get_ragged_kernel("pallas")),
+                            *args)
     assert "tpu_custom_call" in text
+
+
+# the chat cell's pool and step (perfbench/traffic/chat_closed.json) at
+# OPT-1.3B's head widths; two layers and a small vocabulary, which the
+# pool's handling does not depend on
+CELL = dict(pages=1152, page_size=16, heads=32, head_dim=64, rows=48,
+            bucket=48, layers=4)
+SPEC_K = 4
+
+
+@pytest.fixture(scope="module")
+def cell_engine():
+    """A DecodeEngine whose program builders the tests below lower over
+    DESCRIBED pools: its own pool is small and its weights are absent
+    (a builder closes over neither). Donation is on, as on the chip."""
+    from mxnet_tpu import decoding as dec
+
+    c = CELL
+    cfg = dec.DecoderConfig(
+        vocab=512, d_model=c["heads"] * c["head_dim"],
+        n_layers=c["layers"], n_heads=c["heads"], d_ff=512, max_len=2048)
+    eng = dec.DecodeEngine(
+        {}, cfg, max_batch=c["rows"], page_size=c["page_size"],
+        num_pages=c["bucket"] + 1, page_buckets=(c["bucket"],),
+        kernel="lax", prefix_cache=True, merged_step=False,
+        draft_params={}, draft_cfg=cfg, spec_k=SPEC_K)
+    eng._donate = True
+    return eng
+
+
+def _decoder_param_structs(cfg):
+    dm, ff = cfg.d_model, cfg.d_ff
+    shapes = {"embed": (cfg.vocab, dm), "pos": (cfg.max_len, dm),
+              "ln_f": (dm,)}
+    for i in range(cfg.n_layers):
+        shapes.update({f"l{i}.ln1": (dm,), f"l{i}.ln2": (dm,),
+                       f"l{i}.w1": (dm, ff), f"l{i}.w2": (ff, dm)})
+        shapes.update({f"l{i}.{nm}": (dm, dm)
+                       for nm in ("wq", "wk", "wv", "wo")})
+    return {k: _s(v, jnp.bfloat16) for k, v in shapes.items()}
+
+
+def _cell_program(eng, program, kv_dtype, pages):
+    """(jitted program, argument structs, one pool's struct) of one
+    engine program at the cell's shapes over pools of `pages` pages."""
+    c = CELL
+    pool = _pool_struct((c["layers"], pages, c["page_size"], c["heads"],
+                         c["head_dim"]), kv_dtype)
+    params = _decoder_param_structs(eng.cfg)
+    scalar = [_s((), jnp.uint32), _s((), jnp.float32), _s((), jnp.int32),
+              _s((), jnp.float32)]
+    r = c["rows"]
+    row = [_s((r,), jnp.uint32), _s((r,), jnp.float32), _s((r,), jnp.int32),
+           _s((r,), jnp.float32)]
+    rows_in = (_s((r, c["bucket"]), jnp.int32), _s((r,), jnp.int32),
+               _s((r,), jnp.bool_))        # page table, lengths, active
+    tokens = c["bucket"] * c["page_size"]
+    prompt = _s((1, tokens), jnp.int32)
+    ids = _s((c["bucket"],), jnp.int32)
+    i32 = _s((), jnp.int32)
+    if program == "decode":
+        fn = eng._build_decode_fn(c["bucket"])
+        args = (params, _s((r,), jnp.int32), pool, pool, *rows_in, *row)
+    elif program == "draft":
+        fn = eng._build_propose_fn(c["bucket"])
+        args = (params, _s((r,), jnp.int32), pool, pool, *rows_in, *row)
+    elif program == "verify":
+        fn = eng._build_verify_fn(c["bucket"])
+        args = (params, _s((r,), jnp.int32), _s((r, SPEC_K), jnp.int32),
+                _s((r, SPEC_K, eng.cfg.vocab), jnp.float32), pool, pool,
+                *rows_in, _s((r,), jnp.bool_), *row)
+    elif program == "prefill":
+        fn = eng._build_prefill_fn(tokens)
+        args = (params, prompt, i32, pool, pool, ids, *scalar)
+    elif program == "prefill_tail":
+        fn = eng._build_tail_fn(tokens)
+        args = (params, prompt, i32, i32, pool, pool, ids, *scalar)
+    else:
+        fn = eng._build_copy_fn()
+        args = (pool, i32, i32)
+    return getattr(fn, "fn", fn), args, pool
+
+
+@pytest.mark.parametrize("program,kv_dtype", [
+    (program, kv_dtype)
+    for program in ("decode", "prefill", "prefill_tail")
+    for kv_dtype in ("bf16", "float32", "int8")
+] + [("draft", "bf16"), ("verify", "bf16"), ("copy_page", "int8")])
+def test_engine_program_holds_no_pool_sized_copy(v5e, cell_engine, program,
+                                                 kv_dtype):
+    """A token's write is in place and a read gathers from the pool:
+    the compiled program holds nothing of the pool's or a layer's size
+    but the scatters on the donated buffers, and its temporaries do not
+    grow with the pool."""
+    temps = []
+    for pages in (CELL["pages"], 2 * CELL["pages"]):
+        fn, args, pool = _cell_program(cell_engine, program, kv_dtype, pages)
+        placed = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            args)
+        compiled = fn.lower(*placed).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        if pages == CELL["pages"]:
+            text = compiled.as_text()
+            assert f"jit_{program}" in text.split("\n", 1)[0]
+            assert not _pool_sized_ops(text, pool.data.shape)
+    # twice the pool: what is left (the gathered contexts) depends on
+    # rows and bucket only
+    assert temps[1] - temps[0] <= 0.05 * temps[0], temps
 
 
 def _group_spec(net):

@@ -187,10 +187,13 @@ def test_paged_attention_kernels_match_dense():
                       replace=False).astype(np.int32)
     lengths = np.asarray([5, 12, 1], np.int32)
 
+    # the pool's layout: a token's heads side by side in one row
+    k_rows = k_pages.reshape(n, p, h * d)
+    v_rows = v_pages.reshape(n, p, h * d)
     out_lax = np.asarray(dec.paged_attention_lax(
-        q, k_pages, v_pages, table, lengths))
+        q, k_rows, v_rows, table, lengths))
     out_pls = np.asarray(dec.paged_attention_pallas(
-        q, k_pages, v_pages, table, lengths))
+        q, k_rows, v_rows, table, lengths))
 
     # dense oracle: gather each row's true context and softmax it
     scale = 1.0 / np.sqrt(d)
@@ -204,6 +207,67 @@ def test_paged_attention_kernels_match_dense():
         ref = np.einsum("ht,thd->hd", w, ctx_v[:ln])
         np.testing.assert_allclose(out_lax[row], ref, atol=1e-5)
         np.testing.assert_allclose(out_pls[row], ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+def test_engine_tokens_match_dense_reference(kernel, kv_dtype):
+    """The pool's layout end to end: a prefill, a copy-on-write fork of
+    the prompt's pages (the half-filled last page is COPIED on the
+    device), then 20 decode steps of the original and the fork side by
+    side. Both rows must emit the dense `reference_logits` greedy
+    stream, through either kernel, at every page precision."""
+    prompt, steps = [3, 7, 11, 2, 9, 14, 5, 21, 8, 30], 20
+    ref = _ref_greedy(prompt, steps + 1, eos=-1)
+    eng = dec.DecodeEngine(
+        PARAMS, CFG, max_batch=2, page_size=4, num_pages=32,
+        page_buckets=(8,), kernel=kernel, prefix_cache=True,
+        merged_step=False, kv_dtype=kv_dtype).warmup()
+    alloc = eng.allocator
+    need = pages_needed(len(prompt) + steps, eng.page_size)
+    n_prompt = pages_needed(len(prompt), eng.page_size)
+    t1 = alloc.alloc(need)
+    first = eng.prefill(prompt, t1[:n_prompt])
+    # the fork shares the prompt's pages and owns the rest; its first
+    # write lands in the shared, half-filled page: copy, then write
+    t2 = alloc.fork(t1[:n_prompt]) + alloc.alloc(need - n_prompt)
+    page, copy_from = alloc.make_writable(t2, n_prompt - 1)
+    assert copy_from == t1[n_prompt - 1] and page != copy_from
+    eng.copy_page(copy_from, page)
+    np.testing.assert_array_equal(eng.read_page(1, copy_from)[0],
+                                  eng.read_page(1, page)[0])
+    floor = eng.traces()
+
+    table = np.asarray([t1, t2], np.int32)
+    out = [[first], [first]]
+    for t in range(steps):
+        toks = eng.step([row[-1] for row in out], table,
+                        [len(prompt) + t] * 2, [True, True])
+        for row, tok in zip(out, toks):
+            row.append(int(tok))
+    assert out[0] == ref and out[1] == ref
+    assert eng.traces() == floor
+    # both rows wrote the same stream: the original's page and the
+    # fork's copy of it hold the same rows, each a token's H*D values
+    k1, _ = eng.read_page(0, t1[n_prompt - 1])
+    k2, _ = eng.read_page(0, t2[n_prompt - 1])
+    assert k1.shape == (eng.page_size, CFG.d_model)
+    np.testing.assert_array_equal(k1, k2)
+
+
+def test_pool_layout_is_part_of_the_engine_digest(monkeypatch):
+    """An AOT bundle's programs take the pools as arguments: one
+    compiled around another storage order must not be found."""
+    from mxnet_tpu.decoding import quant as kvq
+
+    def digest():
+        return dec.DecodeEngine(PARAMS, CFG, max_batch=2, page_size=4,
+                                num_pages=8, page_buckets=(1,))._digest
+
+    was = digest()
+    assert digest() == was
+    monkeypatch.setattr(kvq, "POOL_LAYOUT", "layers,pages,slots,heads,dim")
+    assert digest() != was
 
 
 def test_get_kernel():

@@ -274,7 +274,8 @@ def test_ragged_kernel_mixed_prefill_decode_matches_dense():
 
     for name in ("lax", "pallas"):
         out = np.asarray(attn.get_ragged_kernel(name)(
-            q, k_pages, v_pages, table, lengths))
+            q, k_pages.reshape(n, p, h * d),
+            v_pages.reshape(n, p, h * d), table, lengths))
         for row in range(b):
             np.testing.assert_allclose(out[row], oracle(row),
                                        atol=1e-5,

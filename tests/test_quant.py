@@ -117,8 +117,13 @@ def test_canonical_pool_and_capacity():
 
     pool = kvq.make_pool((2, 6, 4, 2, 8), "int8")
     assert pool.data.dtype == jnp.int8
-    assert pool.scale.shape == (2, 6, 4, 2)
+    # heads folded into the minor dimension; one scale per (slot, head)
+    assert pool.data.shape == (2, 6, 4, 16)
+    assert pool.scale.shape == (2, 6, 4 * 2)
     assert kvq.as_pool(pool) is pool
+    layer = pool.layer(1)
+    assert layer.pool is pool and layer.index == 1
+    assert layer.shape == (6, 4, 16) and kvq.as_layer(layer) is layer
     f = kvq.make_pool((2, 6, 4, 2, 8), "float32")
     assert f.scale is None and f.kv_dtype == "float32"
     # int8 pools really are ~capacity_ratio smaller per token

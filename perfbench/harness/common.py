@@ -190,6 +190,20 @@ def peak_bytes(devices):
     return max(peaks) if peaks else None
 
 
+def start_trace(ctx):
+    """Begin the capture of the traced sub-window, without the
+    profiler's Python tracer: its hook on every Python call slowed the
+    serving loop's host turn threefold (PERF.md, PR 25), and no reader
+    reads a Python frame. The two sync marks and the program's spans are
+    `TraceAnnotation`s, which the host tracer keeps."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(os.path.join(ctx.out_dir, "trace"),
+                             profiler_options=opts)
+
+
 def free_device_memory():
     gc.collect()
     import jax

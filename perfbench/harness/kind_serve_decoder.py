@@ -138,7 +138,7 @@ def serve_window(ctx, server, model, plan):
     t_open = t_close = None
     if ctx.trace:
         time.sleep(max(0.0, ctx.seconds - trace_s - 2.0))
-        jax.profiler.start_trace(os.path.join(ctx.out_dir, "trace"))
+        common.start_trace(ctx)
         time.sleep(1.0)
         with jax.profiler.TraceAnnotation(trace_reduce.SYNC_A):
             t_open = now()
@@ -187,6 +187,47 @@ def window_numbers(clients, t0, t1):
                     and r.stamps[0] <= t1:
                 ttft.append(r.stamps[0] - r.t_submit)
     return tokens, gaps, ttft, touched, failed
+
+
+def window_account(spans, t0, t1):
+    """What the window's turns were made of, for the counts line: the
+    share of steps and the median step per decode program, the prefills,
+    and the turns (one `decoding.step` start to the next) that ran long.
+    A turn's excess is its length less its prefills less the median of
+    that: a host stall, whatever its cause."""
+    steps = sorted((s[1], s[2], (s[3] or {}).get("program", "?"))
+                   for s in spans
+                   if s[0] == "decoding.step" and s[1] >= t0 and s[2] <= t1)
+    fills = sorted((s[1], s[2]) for s in spans
+                   if s[0] == "decoding.prefill" and s[1] >= t0
+                   and s[2] <= t1)
+    by_prog = {}
+    for a, b, prog in steps:
+        by_prog.setdefault(prog, []).append((b - a) * 1e3)
+    programs = {
+        prog: {"steps": len(ms),
+               "share_pct": round(100.0 * len(ms) / len(steps), 2),
+               "ms_median": round(common.quantile(ms, 0.5), 3)}
+        for prog, ms in sorted(by_prog.items())}
+    bare, i = [], 0
+    for (a, _, _), (b, _, _) in zip(steps, steps[1:]):
+        inside = 0.0
+        while i < len(fills) and fills[i][0] < b:
+            if fills[i][0] >= a:
+                inside += fills[i][1] - fills[i][0]
+            i += 1
+        bare.append((b - a - inside) * 1e3)
+    out = {"programs": programs, "prefills_in_window": len(fills),
+           "prefill_ms_total": round(sum(b - a for a, b in fills) * 1e3, 1)}
+    if bare:
+        med = common.quantile(bare, 0.5)
+        excess = sorted((x - med for x in bare), reverse=True)
+        out.update(
+            turn_ms_median=round(med, 3),
+            turn_excess_ms_longest=[round(x, 1) for x in excess[:5]],
+            turn_excess_ms_total=round(
+                sum(x for x in excess if x > 0.1 * med), 1))
+    return out
 
 
 def pick_sample(ctx, touched, t1):
@@ -263,7 +304,11 @@ def run(ctx, hooks=None):
         "setup_s": t0 - common.T_PROCESS_START,
     }
     counts.update(w["gc"])
+    counts.update(window_account(w["spans"], t0, t1))
     ctx.log("window: " + str(counts))
+    ctx.log("share of steps per decode program: " + ", ".join(
+        f"{p} {v['share_pct']}% ({v['steps']} steps, median "
+        f"{v['ms_median']} ms)" for p, v in counts["programs"].items()))
     sample = pick_sample(ctx, touched, t1)
     # free the program's state before the reference runs
     del model, server
@@ -287,6 +332,7 @@ def run(ctx, hooks=None):
            "control_gap": low, "served_tokens_checked": n_tok,
            "device": dict(ctx.device, memory_peak_bytes=peak)}
     if ctx.trace:
+        t_red = now()
         raw = trace_reduce.load_xplane(os.path.join(ctx.out_dir, "trace"))
         red = trace_reduce.Reduced(raw, w["spans"], w["t_open"],
                                    w["t_close"])
@@ -298,11 +344,15 @@ def run(ctx, hooks=None):
                  "trace": red, "spans": w["spans"], "window_host": (lo, hi),
                  "counters": d, "ttft": ttft, "all_gaps": gaps,
                  "tokens": window_numbers(clients, lo, hi)[0]}
+        t_read = now()
         res["per_layer"] = common.read_per_layer(ctx, facts)
         for k, v in facts.get("notes", {}).items():
             ctx.log(f"{k}: {v}")
+        t_brk = now()
         res["breakdown"] = red.breakdown()
         res["device"].update(busy_s=red.busy_s, window_s=red.window_s)
         ctx.log(f"trace: window {red.window_s:.3f}s busy {red.busy_s:.3f}s "
-                f"clock drift {red.drift * 1e3:.3f}ms")
+                f"clock drift {red.drift * 1e3:.3f}ms; reading it took "
+                f"{t_read - t_red:.1f}s, the readers {t_brk - t_read:.1f}s, "
+                f"the breakdown {now() - t_brk:.1f}s")
     return res

@@ -111,8 +111,7 @@ class WindowIter:
             start_at = self.t0 + max(
                 0.0, ctx.seconds - self.trace_seconds - 1.0)
             if not self.tracing and t >= start_at:
-                jax.profiler.start_trace(os.path.join(ctx.out_dir,
-                                                      "trace"))
+                common.start_trace(ctx)
                 self.tracing = True
             elif self.tracing and self.t_open is None:
                 self._since_start += 1

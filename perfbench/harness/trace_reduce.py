@@ -3,6 +3,7 @@ the host span that covers them, per-node kernel time, exposed
 collective time. Pure functions over plain lists so that the self-check
 can feed them a small recorded trace; `load_xplane` is the only part
 that touches jax."""
+import bisect
 import glob
 import os
 import re
@@ -228,6 +229,7 @@ class Reduced:
         that clock, each stamped inside a TraceAnnotation so that the
         trace holds the same instants on its own clock."""
         self.ok = False
+        self._merged = {}
         self.devices = raw["devices"] if raw else []
         marks = {n: t0 for n, t0, _t1 in (raw["host"] if raw else [])}
         if not self.devices or SYNC_A not in marks or SYNC_B not in marks:
@@ -250,12 +252,24 @@ class Reduced:
         d = self.devices[dev]
         return [(t0, t1) for _n, _l, t0, t1 in (d["ops"] or d["modules"])]
 
+    def _busy(self, dev):
+        """The chip's busy intervals inside the window, merged, and
+        their ends (to bisect)."""
+        if dev not in self._merged:
+            iv = merge(clip(self.intervals(dev), self.lo, self.hi))
+            self._merged[dev] = (iv, [b for _a, b in iv])
+        return self._merged[dev]
+
     def gaps_named(self, dev=0, top=10):
+        """(the `top` longest idle gaps, each named by the host span that
+        covers most of it; the idle seconds; the count of gaps). Only
+        the gaps returned are named: naming looks at every span, and
+        gaps and spans both grow with the steps in the window."""
         spans3 = [(n, a, b) for n, a, b, _ in self.spans]
-        gaps = idle_gaps(self.intervals(dev), self.lo, self.hi)
-        named = [(name_gap(g, spans3), g[1] - g[0]) for g in gaps]
-        named.sort(key=lambda x: -x[1])
-        return named[:top], sum(b - a for a, b in gaps), len(gaps)
+        gaps = idle_gaps(self._busy(dev)[0], self.lo, self.hi)
+        longest = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
+        named = [(name_gap(g, spans3), g[1] - g[0]) for g in longest]
+        return named, sum(b - a for a, b in gaps), len(gaps)
 
     def top_ops(self, top=10):
         return sorted(self.labels().items(), key=lambda x: -x[1])[:top]
@@ -270,12 +284,15 @@ class Reduced:
     def busy_inside(self, span_name, dev=0):
         """(device busy seconds inside host spans of that name, the
         spans that lie whole inside the window)."""
-        iv = merge(clip(self.intervals(dev), self.lo, self.hi))
+        iv, ends = self._busy(dev)
         total, spans = 0.0, []
         for n, a, b, attrs in self.spans:
             if n != span_name or a < self.lo or b > self.hi:
                 continue
-            total += busy_seconds(iv, a, b)
+            i = bisect.bisect_right(ends, a)
+            while i < len(iv) and iv[i][0] < b:
+                total += min(iv[i][1], b) - max(iv[i][0], a)
+                i += 1
             spans.append((a, b, attrs))
         return total, spans
 

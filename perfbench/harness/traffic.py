@@ -3,9 +3,12 @@ parameters under traffic/, read here.
 
 Serving mixes (`serve_closed`): a fixed multiset of (prompt length,
 answer length) pairs, one per client, is drawn from the FILE's
-`sizes_seed`; the run's --seed only deals those pairs to the clients in
-another order each round and draws the token ids. So every seed offers
-the same work in another order, and runs differ by the system, not by
+`sizes_seed`; the run's --seed only chooses which client holds which
+pair and draws the token ids. A client keeps its pair for the whole
+run, so at every instant the live rows are the mix's own pairs, each
+somewhere in its own cycle: the pages in use, the decode program a step
+runs and the prefills a second are properties of the mix and the
+system, the same on every seed, and runs differ by the system, not by
 the draw.
 """
 import math
@@ -34,32 +37,25 @@ def size_pairs(mix):
 
 
 class ClosedLoopPlan:
-    """Which request a client sends in which round: round r deals the
-    same pairs to the clients by a permutation drawn from the seed, and
-    the token ids come from the seed, the round and the client."""
+    """Which request a client sends in which round: the seed deals the
+    mix's pairs to the clients once, and the token ids come from the
+    seed, the round and the client."""
 
     def __init__(self, mix, seed, vocab):
         self.mix = mix
         self.seed = int(seed)
         self.vocab = int(vocab)
-        self.pairs = size_pairs(mix)
         self.clients = int(mix["clients"])
         shared = int(mix.get("shared_prefix_tokens", 0))
         rs = np.random.RandomState(self.seed % (2 ** 32))
         self.prefix = rs.randint(2, self.vocab, shared).tolist()
-        self._perms = {}
-
-    def _perm(self, rnd):
-        if rnd not in self._perms:
-            rs = np.random.RandomState(
-                (self.seed * 1000003 + rnd * 7919 + 1) % (2 ** 32))
-            self._perms[rnd] = rs.permutation(self.clients)
-        return self._perms[rnd]
+        pairs = size_pairs(mix)
+        self.pairs = [pairs[i] for i in rs.permutation(self.clients)]
 
     def request(self, client, rnd):
         """(prompt token ids, answer length) of a client's `rnd`-th
         request."""
-        n_prompt, n_out = self.pairs[int(self._perm(rnd)[client])]
+        n_prompt, n_out = self.pairs[client]
         rs = np.random.RandomState(
             (self.seed * 69069 + rnd * 104729 + client * 31 + 5) % (2 ** 32))
         body = rs.randint(2, self.vocab,

@@ -33,6 +33,10 @@ def test_rehearsal_ends_well_formed(cell):
     counts = json.loads(text.strip().splitlines()[-2])["counts"]
     assert counts["compilations_in_window"] == 0
     assert counts["fenced_seconds"] > 0
+    if "programs" in counts:    # serving: the share of steps per program
+        assert abs(sum(v["share_pct"] for v in counts["programs"].values())
+                   - 100.0) < 0.1
+        assert counts["prefills_in_window"] <= counts["prefills"]
     if "epoch_ends_in_window" in counts:
         assert counts["epoch_ends_in_window"] == 0
         assert counts["steps"] == line["attempted"]

@@ -22,7 +22,7 @@ def _reader(name):
         "selfcheck_metric_" + name.replace(".", "_"))
 
 
-def _facts(scope_maps="planted", spans=True):
+def _facts(scope_maps="planted", spans=True, more_spans=()):
     with open(os.path.join(ROOT, "perfbench/selfcheck/data",
                            "small_decode_trace.json")) as f:
         rec = json.load(f)
@@ -32,6 +32,7 @@ def _facts(scope_maps="planted", spans=True):
                        for d in rec["devices"]],
            "host": [tuple(h) for h in rec["host"]]}
     host_spans = [tuple(s) for s in rec["host_spans"]] if spans else []
+    host_spans += list(more_spans)
     red = tr.Reduced(raw, host_spans, rec["t_open_host"],
                      rec["t_close_host"])
     assert red.ok
@@ -143,3 +144,22 @@ def test_a_scope_the_steps_never_name_reads_nothing():
     facts["scope_maps"] = lambda module: {"fusion.1": "l0/qkv"}
     assert _reader("kv_write_device_ms_per_step").read(facts) is None
     assert _reader("paged_attn_roofline").read(facts) is None
+
+
+def test_prefill_device_time_per_thousand_prompt_tokens():
+    # the trace's one prefill launch (10 ms of device time) under a
+    # `decoding.prefill` span of 13 ms that prefilled 12 tokens, 2 of
+    # them from cached pages; a span the window's close cuts is left out
+    fill = [("decoding.prefill", 10.094, 10.107,
+             {"tokens": 12, "cached_tokens": 2}),
+            ("decoding.prefill", 10.296, 10.31, {"tokens": 500})]
+    facts = _facts(more_spans=fill)
+    assert _reader("prefill_device_ms_per_ktok").read(facts) \
+        == pytest.approx(10.0 / 10 * 1000)
+    assert _reader("prefill_device_ms_per_ktok").read(_facts()) is None
+
+
+def test_first_token_time_is_the_clients_median():
+    facts = dict(_facts(), ttft=[0.1, 0.3, 0.2])
+    assert _reader("ttft_p50_ms.serve").read(facts) == pytest.approx(200.0)
+    assert _reader("ttft_p50_ms.serve").read(dict(facts, ttft=[])) is None

@@ -103,10 +103,16 @@ HOT_PATH_MANIFEST = {
     "mxnet_tpu/decoding/sampling.py": "*",
     "mxnet_tpu/decoding/speculative.py": "*",
     "mxnet_tpu/decoding/engine.py": (
-        "DecodeEngine.prefill", "DecodeEngine.step",
-        "DecodeEngine.spec_step", "DecodeEngine.copy_page",
-        "DecodeEngine.pool_stats",
+        "DecodeEngine.prefill", "DecodeEngine.launch_prefill",
+        "DecodeEngine.fetch_prefill", "DecodeEngine._launch_chunks",
+        "DecodeEngine.step", "DecodeEngine.launch_step",
+        "DecodeEngine.fetch_step", "DecodeEngine.next_tokens",
+        "DecodeEngine.spec_step",
+        "DecodeEngine.copy_page", "DecodeEngine.pool_stats",
     ),
+    # the second block's forwards run inside the jitted chunk-prefill
+    # and decode programs: pure jax on traced values
+    "mxnet_tpu/decoding/sparse_latent.py": "*",
     "mxnet_tpu/decoding/scheduler.py": (
         "ContinuousScheduler._admit", "ContinuousScheduler._grow",
         "ContinuousScheduler._step", "ContinuousScheduler._preempt",
@@ -116,12 +122,16 @@ HOT_PATH_MANIFEST = {
         "ContinuousScheduler._check_cancelled",
         "ContinuousScheduler._handle_token",
         "ContinuousScheduler._resolve",
+        "ContinuousScheduler._turn_ahead",
+        "ContinuousScheduler._launch_ahead",
+        "ContinuousScheduler._retire", "ContinuousScheduler._settle",
+        "ContinuousScheduler._pending",
     ),
     "mxnet_tpu/decoding/stats.py": (
         "DecodeStats.note_step", "DecodeStats.note_prefill",
         "DecodeStats.note_preempted", "DecodeStats.note_pool",
         "DecodeStats.note_spec", "DecodeStats.note_prefix_reuse",
-        "DecodeStats.note_quant_clips",
+        "DecodeStats.note_quant_clips", "DecodeStats.note_counters",
     ),
     # KV quantization (quant PR): quantize-at-scatter / dequantize-at-
     # gather run INSIDE the jitted prefill/decode/attention programs —

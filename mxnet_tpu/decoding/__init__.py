@@ -10,21 +10,42 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
              refcounts (prefix sharing, copy-on-write fork)
   attention  page-table attention kernels: gather-based lax reference
              and a scalar-prefetch Pallas flash kernel
-             (MXNET_DECODE_KERNEL=lax|pallas)
-  model      the decoder contract: reference / prefill / decode-step
-             forwards over one flat params dict
-  engine     DecodeEngine — owns the device page pool and a pre-traced
-             fixed-shape program grid (zero steady-state retraces)
+             (MXNET_DECODE_KERNEL=lax|pallas); sparse selection inside
+             paged attention (an index score over every cached token,
+             an exact top-k, attention over the selected rows only)
+  model      the MODEL CONTRACT and its first instance: a
+             configuration object (`DecoderConfig`) gives the engine
+             the planes of its page pool (`planes`: name, width a
+             token, scale groups), its step functions (`decode_step`,
+             `prefill_step` or `chunk_step`, `probe_step`), the prefix
+             of its programs' names and the counters a step returns
+             beside its tokens; the dense block (learned positions,
+             per-head K and V pages, ReLU MLP, tied head) is written
+             as reference / prefill / decode-step forwards over one
+             flat params dict
+  sparse_latent  the second instance (`SparseLatentConfig`): a latent
+             row and an index key a token, learned sparse attention,
+             rotary positions with YaRN, routed experts that are told
+             which they hold (`experts_held`), a shared expert, an
+             untied head over the vocabulary slice held here; prompts
+             go through the pages in chunks
+  engine     DecodeEngine — owns the device page pool (one buffer per
+             plane, one page table for all) and a pre-traced
+             fixed-shape program grid (zero steady-state retraces);
+             dispatches on the configuration object alone
   scheduler  ContinuousScheduler + DecodedModel — per-step admission,
              eviction, priority preemption, streaming DecodeFuture
-             (whose TokenStream owns/cancels the request)
+             (whose TokenStream owns/cancels the request); with
+             `run_ahead` it keeps steps in flight so that the device
+             does not wait for the host between them
   prefix     PrefixCache — radix index over cached prompt KV pages;
              admission maps shared prefixes via the fork path and
              prefills only the tail
-  quant      precision-polymorphic page pools (KVPool pytree):
-             int8 pages with per-page scale planes, quantized at
-             scatter and dequantized in-kernel
-             (MXNET_DECODE_KV_DTYPE=float32|bf16|int8)
+  quant      precision-polymorphic page pools (KVPool pytree), one
+             per `Plane` of width w: int8 pages with per-page scale
+             planes, quantized at scatter and dequantized in-kernel
+             (MXNET_DECODE_KV_DTYPE=float32|bf16|int8); whole-context
+             and token-granular reads through the page table
   sampling   SamplingParams + the (seed, position, salt) counter
              streams: temperature/top-k/top-p inside the jitted step,
              bit-reproducible across preemption
@@ -45,14 +66,16 @@ Knobs: MXNET_DECODE_* (docs/env_vars.md). Guide: docs/serving.md
 ("Continuous decoding").
 """
 from . import attention, blocks, config, engine, model, prefix, \
-    quant, sampling, scheduler, speculative, stats
+    quant, sampling, scheduler, sparse_latent, speculative, stats
 from .blocks import (SCRATCH_PAGE, BlockAllocator, PageError,
                      PagePoolExhausted, pages_needed)
 from .attention import (get_kernel, get_multi_kernel,
                         paged_attention_lax, paged_attention_pallas)
 from .engine import DecodeEngine, quant_parity_probe
-from .quant import KVPool
+from .quant import KVPool, Plane
 from .model import DecoderConfig, init_decoder_params, reference_logits
+from .sparse_latent import (SparseLatentConfig,
+                            init_sparse_latent_params)
 from .prefix import PrefixCache, page_digests
 from .sampling import SamplingParams
 from .scheduler import (ContinuousScheduler, DecodeFuture,
@@ -62,13 +85,14 @@ from .stats import DecodeStats, decoding_stats, reset_decoding_stats
 __all__ = [
     "BlockAllocator", "ContinuousScheduler", "DecodeEngine",
     "DecodeFuture", "DecodeStats", "DecodedModel", "DecoderConfig",
-    "KVPool", "PageError", "PagePoolExhausted", "PrefixCache",
+    "KVPool", "PageError", "PagePoolExhausted", "Plane", "PrefixCache",
     "RequestHandedOff", "SCRATCH_PAGE", "SamplingParams",
-    "TokenStream", "attention", "blocks", "config",
+    "SparseLatentConfig", "TokenStream", "attention", "blocks", "config",
     "decoding_stats", "engine", "get_kernel", "get_multi_kernel",
-    "init_decoder_params", "model", "page_digests",
+    "init_decoder_params", "init_sparse_latent_params", "model",
+    "page_digests",
     "paged_attention_lax", "paged_attention_pallas", "pages_needed",
     "prefix", "quant", "quant_parity_probe", "reference_logits",
-    "reset_decoding_stats", "sampling", "scheduler", "speculative",
-    "stats",
+    "reset_decoding_stats", "sampling", "scheduler", "sparse_latent",
+    "speculative", "stats",
 ]

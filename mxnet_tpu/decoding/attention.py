@@ -145,6 +145,66 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
     return out.astype(q.dtype)
 
 
+# ------------------------------------------- sparse selection over pages
+def sparse_index_select(q_idx, w_idx, k_ctx, q_pos, topk):
+    """Learned sparse selection: which cached tokens each query
+    attends.
+
+      q_idx  (B, T, J, D)   index queries, J index heads
+      w_idx  (B, T, J) f32  the query's weight of each index head
+      k_ctx  (B, S, D)      the row's index keys, position-ordered
+                            (`quant.gather_plane` of the index plane)
+      q_pos  (B, T) int32   absolute position of each query
+
+    I[t, s] = sum_j w[t, j] * ReLU(q[t, j] . k[s]) for s <= q_pos[t];
+    returns the positions of the `topk` largest, (B, T, topk) int32 —
+    EXACT (`lax.top_k`: ties to the lower position; an approximate
+    top-k at recall under 1 would be another model). A query with
+    fewer than topk tokens in reach gets them all, and past them
+    positions beyond its own, which `sparse_latent_attention` masks.
+    The (B, T, J, S) float32 scores are this function's temporary: the
+    caller bounds T."""
+    s = jnp.einsum("btjd,bsd->btjs", q_idx.astype(k_ctx.dtype), k_ctx,
+                   preferred_element_type=jnp.float32)
+    score = jnp.sum(jax.nn.relu(s) * w_idx[..., None], axis=2)
+    reach = jnp.arange(k_ctx.shape[1])[None, None, :] <= q_pos[..., None]
+    score = jnp.where(reach, score, -jnp.inf)
+    # queries as rows of ONE matrix: with a decode step's single query
+    # a row (B, 1, S) the sort would run one sublane of eight
+    b, t, n = score.shape
+    picked = jax.lax.top_k(score.reshape(b * t, n), topk)[1]
+    return picked.reshape(b, t, topk).astype(jnp.int32)
+
+
+def sparse_latent_attention(q, latent, page_table, selected, q_pos,
+                            value_width, scale):
+    """Attention over the selected rows only, in latent space.
+
+      q          (B, T, H, W)   queries already taken into the latent
+                                row's space (W = the plane's width)
+      latent     quant.KVLayer  one layer of the latent plane
+      page_table (B, Bp) int32
+      selected   (B, T, K)      positions from `sparse_index_select`
+      q_pos      (B, T) int32
+
+    Gathers the K rows of each query through the page table (token
+    granular, straight from the pool), scores q . row over the whole
+    row, softmax in float32 over the rows in reach, and returns
+    sum_k p[k] * row[k, :value_width] as (B, T, H, value_width)
+    float32: all heads share the one gathered row."""
+    rows = _quant.gather_rows(latent, page_table,
+                              selected)[..., :q.shape[-1]]
+    s = jnp.einsum("bthw,btkw->bthk", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    reach = selected <= q_pos[..., None]
+    s = jnp.where(reach[:, :, None, :], s, NEG_INF)
+    e = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    w = e / e.sum(axis=-1, keepdims=True)
+    return jnp.einsum("bthk,btkc->bthc", w.astype(rows.dtype),
+                      rows[..., :value_width],
+                      preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------- pallas
 def _paged_attn_kernel(page_size, heads, quantized):
     """Kernel body on a (B, Bp) grid: one (page, row) tile per step,

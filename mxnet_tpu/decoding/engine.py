@@ -1,8 +1,13 @@
 """DecodeEngine: the device half of continuous batching.
 
-Owns the paged KV pool (one pre-allocated (layers, pages, page_size,
-heads*head_dim) buffer per K and V — quant.py says why that order),
-the block allocator over it, and a FIXED grid of jitted programs:
+Owns the paged pool — one pre-allocated (layers, pages, page_size,
+width) buffer per PLANE the model's configuration states (`cfg.planes`:
+`k` and `v` of heads*head_dim for the dense block, a latent row and an
+index key for the sparse latent block; quant.py says why that order) —
+the block allocator over it (one page table for every plane), and a
+FIXED grid of jitted programs. The engine names no block: it takes the
+planes, the step functions and the program names from the
+configuration object (the model contract, `model.DecoderConfig`):
 
   prefill  one program per prompt length bucket (batch 1, dense causal
            attention — optionally ring attention for long buckets —
@@ -28,6 +33,14 @@ the block allocator over it, and a FIXED grid of jitted programs:
            the speculative pair joins the same pinned trace grid, and
            the draft keeps parallel K/V pools indexed by the SAME
            page ids (see speculative.py)
+  chunk    where the configuration prefills through the pages
+           (`cfg.prefill_chunk`): INSTEAD of the prefill and tail
+           families, one program per (chunk tokens bucket, context
+           pages bucket) that writes a chunk's rows and attends its
+           queries over the pages — a long prompt is that program
+           taken repeatedly, a prefix-cache tail taken once or twice.
+           No whole-prompt program is built (its score matrix grows
+           with the square of the prompt)
   copy     one page-copy program (copy-on-write fork support; traced
            once more for the draft pool shape when it differs)
 
@@ -42,6 +55,8 @@ The engine is NOT thread-safe: exactly one scheduler thread drives it
 """
 from __future__ import annotations
 
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,7 +67,6 @@ from ..serving.batcher import pick_bucket
 from ..telemetry import trace as _trace
 from . import config as _cfg
 from . import attention as _attn
-from . import model as _model
 from . import quant as _quant
 from . import speculative as _spec
 
@@ -68,7 +82,8 @@ class DecodeEngine:
                  num_pages=None, page_buckets=None, kernel=None,
                  ring_prefill=None, draft_params=None, draft_cfg=None,
                  spec_k=None, prefix_cache=None, merged_step=None,
-                 kv_dtype=None):
+                 kv_dtype=None, chunk_buckets=None,
+                 context_buckets=None):
         self.cfg = cfg
         # KV storage precision (MXNET_DECODE_KV_DTYPE): the page pools
         # — target AND draft — store at this dtype; int8 pools carry
@@ -105,14 +120,40 @@ class DecodeEngine:
                 f"page bucket {self.page_buckets[-1]} exceeds pool "
                 f"capacity {self.num_pages - 1}")
 
+        # chunked prefill (cfg.prefill_chunk): the chunk programs'
+        # token buckets, and the page buckets they gather over (a
+        # document's early chunks need not score and sort the largest
+        # context)
+        self.chunk_buckets = self.context_buckets = ()
+        if cfg.prefill_chunk:
+            self.chunk_buckets = tuple(sorted(set(
+                int(b) for b in chunk_buckets or (cfg.prefill_chunk,))))
+            self.context_buckets = tuple(sorted(set(
+                int(b) for b in context_buckets
+                or self.page_buckets[-1:])))
+            if self.chunk_buckets[-1] != cfg.prefill_chunk:
+                raise PageError(
+                    f"largest chunk bucket {self.chunk_buckets[-1]} != "
+                    f"the configuration's prefill_chunk "
+                    f"{cfg.prefill_chunk}")
+            if self.context_buckets[-1] != self.page_buckets[-1]:
+                raise PageError(
+                    f"largest context bucket {self.context_buckets[-1]} "
+                    f"!= largest page bucket {self.page_buckets[-1]}")
+            if draft_params is not None:
+                raise PageError(
+                    "speculation verifies with the dense block's "
+                    "multi-query kernel: not for a chunk-prefilled "
+                    "configuration")
+
         self.allocator = BlockAllocator(self.num_pages, self.page_size)
         self._attn = _attn.get_kernel(self.kernel_name)
         self._attn_multi = _attn.get_multi_kernel(self.kernel_name)
         self._params = jax.tree_util.tree_map(jnp.asarray, dict(params))
-        shape = (cfg.n_layers, self.num_pages, self.page_size,
-                 cfg.n_heads, cfg.head_dim)
-        self._k = _quant.make_pool(shape, self.kv_dtype)
-        self._v = _quant.make_pool(shape, self.kv_dtype)
+        self._pools = tuple(
+            _quant.make_plane(cfg.n_layers, self.num_pages,
+                              self.page_size, plane, self.kv_dtype)
+            for plane in cfg.planes)
         self.prefix_cache_enabled = prefix_cache if prefix_cache \
             is not None else _cfg.prefix_cache()
         self.spec_k = int(spec_k) if spec_k is not None \
@@ -148,7 +189,12 @@ class DecodeEngine:
             else _cfg.merged_step()
         self.merged_step_enabled = bool(
             want_merged and self.prefix_cache_enabled
-            and not self.spec_enabled)
+            and not self.spec_enabled and not cfg.prefill_chunk)
+        # the block's choice of paged attention, handed to its steps
+        self._kernels = SimpleNamespace(
+            attn=(_attn.get_ragged_kernel(self.kernel_name)
+                  if self.merged_step_enabled else self._attn),
+            attn_multi=self._attn_multi)
         # extra step rows available for tail tokens each merged step;
         # one page's worth keeps the row overhead bounded while a tail
         # still advances a full page per step
@@ -161,11 +207,20 @@ class DecodeEngine:
         self._decode_fns = {}
         self._prefill_fns = {}
         self._tail_fns = {}
+        self._chunk_fns = {}
+        self._probe_fns = {}
+        # what the newest step and the newest prefill counted
+        # (cfg.step_counters), for the scheduler's spans and stats
+        self.last_step_counters = {}
+        self.last_prefill = {"chunks": 1}
         self._draft_prefill_fns = {}
         self._draft_tail_fns = {}
         self._propose_fns = {}
         self._verify_fns = {}
         self._copy_fn = None
+        # a step's tokens cut from its output, for the step after it
+        rows = self.step_rows
+        self._next_tokens_fn = jax.jit(lambda out: out[:rows])
         self._trace_counts = {}
         self._warm = False
         # MXNET_NUMERICS_DECODE_GUARD: each decode step also returns a
@@ -187,7 +242,18 @@ class DecodeEngine:
              self.spec_k if self.spec_enabled else 0,
              self.step_rows if self.merged_step_enabled else 0,
              self.kv_dtype, _quant.POOL_LAYOUT)
+            + ((self.chunk_buckets, self.context_buckets)
+               if cfg.prefill_chunk else ())
         ).encode()).hexdigest()[:12]
+
+    # the dense block's two planes by name (speculation, page reads)
+    @property
+    def _k(self):
+        return self._pools[0]
+
+    @property
+    def _v(self):
+        return self._pools[1]
 
     def _jit(self, impl, name, kind, donate):
         """jit one grid program under a name of its own and route it
@@ -234,7 +300,7 @@ class DecodeEngine:
         at this pages bucket, as a capture's `XLA Modules` line and
         `profiling.scope_map` have it (see `_jit`)."""
         kind = "verify" if self.spec_enabled else "decode"
-        return f"jit_{kind}_p{bucket}"
+        return f"jit_{self.cfg.program_family}{kind}_p{bucket}"
 
     def traces(self):
         """Total prefill/decode/copy traces so far (see docstring)."""
@@ -245,11 +311,11 @@ class DecodeEngine:
 
     def pool_stats(self):
         st = self.allocator.stats()
-        # measured K+V bytes per pooled token position (scale planes
-        # included): the float32/int8 ratio of this number is the
-        # capacity multiplier BENCH_MODE=decode and quant-check report
-        per_tok = (_quant.kv_bytes_per_token(self._k)
-                   + _quant.kv_bytes_per_token(self._v))
+        # measured bytes per pooled token position over every plane
+        # (scale planes included): the float32/int8 ratio of this
+        # number is the capacity multiplier BENCH_MODE=decode and
+        # quant-check report
+        per_tok = sum(_quant.kv_bytes_per_token(p) for p in self._pools)
         return {
             "pages_total": st["pages_total"],
             "pages_free": st["pages_free"],
@@ -276,9 +342,9 @@ class DecodeEngine:
         on device — zero sync) when the guard is enabled."""
         res = fn(*args)
         if not self._guard:
-            out, self._k, self._v = res
+            out, self._pools = res
             return out
-        out, self._k, self._v, bad = res
+        out, self._pools, bad = res
         self._guard_pending.append(bad)
         if len(self._guard_pending) > self._GUARD_CAP:
             del self._guard_pending[:-self._GUARD_CAP]
@@ -304,21 +370,18 @@ class DecodeEngine:
     def _build_decode_fn(self, bucket):
         # merged mode routes through the ragged entry: same per-row
         # contract, named for what the mixed batch actually is
-        cfg = self.cfg
-        attn = (_attn.get_ragged_kernel(self.kernel_name)
-                if self.merged_step_enabled else self._attn)
-        guard = self._guard
+        cfg, kernels, guard = self.cfg, self._kernels, self._guard
 
-        def impl(params, tokens, k_pages, v_pages, page_table,
-                 lengths, active, seeds, temps, top_ks, top_ps):
+        def impl(params, tokens, pools, page_table, lengths, active,
+                 seeds, temps, top_ks, top_ps):
             self._note_trace(f"decode@{bucket}")
-            return _model.decode_forward(
-                params, tokens, k_pages, v_pages, page_table,
-                lengths, active, seeds, temps, top_ks, top_ps,
-                cfg=cfg, attn=attn, with_stats=guard)
+            return cfg.decode_step(
+                params, tokens, pools, page_table, lengths, active,
+                seeds, temps, top_ks, top_ps, kernels=kernels,
+                with_stats=guard)
 
-        return self._jit(impl, f"decode_p{bucket}", f"decode@{bucket}",
-                         (2, 3))
+        return self._jit(impl, f"{cfg.program_family}decode_p{bucket}",
+                         f"decode@{bucket}", (2,))
 
     def _build_prefill_fn(self, length_bucket, name="prefill",
                           cfg=None):
@@ -336,31 +399,40 @@ class DecodeEngine:
             def attn_fn(q, k, v):
                 return ring_attention(q, k, v, mesh=mesh, causal=True)
 
-        def impl(params, tokens, length, k_pages, v_pages, page_ids,
-                 seed, temp, top_k, top_p):
+        kernels = self._kernels
+
+        def impl(params, tokens, length, pools, page_ids, seed, temp,
+                 top_k, top_p):
             self._note_trace(f"{name}@{length_bucket}")
-            return _model.prefill_forward(
-                params, tokens, length, k_pages, v_pages, page_ids,
-                seed, temp, top_k, top_p, cfg=cfg, attn_fn=attn_fn)
+            return cfg.prefill_step(
+                params, tokens, length, pools, page_ids, seed, temp,
+                top_k, top_p, kernels=kernels, attn_fn=attn_fn)
 
         return self._jit(impl, f"{name}_t{length_bucket}",
-                         f"{name}@{length_bucket}", (3, 4))
+                         f"{name}@{length_bucket}", (3,))
 
     def _build_tail_fn(self, length_bucket, name="prefill_tail",
                        cfg=None):
         cfg = cfg if cfg is not None else self.cfg
-        attn_multi = self._attn_multi
+        kernels = self._kernels
 
-        def impl(params, tokens, start, length, k_pages, v_pages,
-                 page_ids, seed, temp, top_k, top_p):
+        def impl(params, tokens, start, length, pools, page_ids, seed,
+                 temp, top_k, top_p):
             self._note_trace(f"{name}@{length_bucket}")
-            return _model.tail_prefill_forward(
-                params, tokens, start, length, k_pages, v_pages,
-                page_ids, seed, temp, top_k, top_p, cfg=cfg,
-                attn_multi=attn_multi)
+            return cfg.chunk_step(
+                params, tokens, start, length, pools, page_ids, seed,
+                temp, top_k, top_p, kernels=kernels)
 
         return self._jit(impl, f"{name}_t{length_bucket}",
-                         f"{name}@{length_bucket}", (4, 5))
+                         f"{name}@{length_bucket}", (4,))
+
+    def _build_chunk_fn(self, tokens_bucket, pages_bucket):
+        """One chunk-prefill program: `tokens_bucket` prompt positions
+        written and attended over a table of `pages_bucket` pages (the
+        tail program's contract, `cfg.chunk_step`)."""
+        return self._build_tail_fn(
+            f"{tokens_bucket}_p{pages_bucket}",
+            name=f"{self.cfg.program_family}prefill_chunk")
 
     def _build_propose_fn(self, bucket):
         cfg, attn, k = self.draft_cfg, self._attn, self.spec_k
@@ -440,8 +512,8 @@ class DecodeEngine:
         0, inactive, all-scratch table): what warmup, the calibration
         harvest and `decode_program_text` dispatch or lower."""
         r = self.step_rows
-        return (self._params, np.zeros((r,), np.int32), self._k,
-                self._v, np.zeros((r, bucket), np.int32),
+        return (self._params, np.zeros((r,), np.int32), self._pools,
+                np.zeros((r, bucket), np.int32),
                 np.zeros((r,), np.int32), np.zeros((r,), bool),
                 *self._samp_arrays(None, None, None, None))
 
@@ -459,14 +531,23 @@ class DecodeEngine:
         self.copy_page(SCRATCH_PAGE, SCRATCH_PAGE)
         sargs = self._samp_scalars()
         max_pages = pages_needed(self.max_context, self.page_size)
-        for lb in self.prefill_buckets:
+        for tb in self.chunk_buckets:
+            # a chunk-prefilled configuration: this family alone
+            for cb in self.context_buckets:
+                self._chunk_fns[tb, cb] = self._build_chunk_fn(tb, cb)
+                tok, self._pools = self._chunk_fns[tb, cb](
+                    self._params, np.zeros((1, tb), np.int32),
+                    jnp.int32(0), jnp.int32(0), self._pools,
+                    np.zeros((cb,), np.int32), *sargs)
+                tok.block_until_ready()
+        for lb in () if self.chunk_buckets else self.prefill_buckets:
             tokens = np.zeros((1, lb), np.int32)
             page_ids = np.zeros((pages_needed(lb, self.page_size),),
                                 np.int32)
             full_ids = np.zeros((max_pages,), np.int32)
             self._prefill_fns[lb] = self._build_prefill_fn(lb)
-            tok, self._k, self._v = self._prefill_fns[lb](
-                self._params, tokens, jnp.int32(0), self._k, self._v,
+            tok, self._pools = self._prefill_fns[lb](
+                self._params, tokens, jnp.int32(0), self._pools,
                 page_ids, *sargs)
             tok.block_until_ready()
             if self.prefix_cache_enabled \
@@ -475,23 +556,23 @@ class DecodeEngine:
                 # programs: tail tokens ride the decode step below —
                 # this is the warmup-grid shrink the merged step buys
                 self._tail_fns[lb] = self._build_tail_fn(lb)
-                tok, self._k, self._v = self._tail_fns[lb](
+                tok, self._pools = self._tail_fns[lb](
                     self._params, tokens, jnp.int32(0), jnp.int32(0),
-                    self._k, self._v, full_ids, *sargs)
+                    self._pools, full_ids, *sargs)
                 tok.block_until_ready()
             if self.spec_enabled:
                 self._draft_prefill_fns[lb] = self._build_prefill_fn(
                     lb, name="draft_prefill", cfg=self.draft_cfg)
-                tok, self._dk, self._dv = self._draft_prefill_fns[lb](
+                tok, (self._dk, self._dv) = self._draft_prefill_fns[lb](
                     self._draft_params, tokens, jnp.int32(0),
-                    self._dk, self._dv, page_ids, *sargs)
+                    (self._dk, self._dv), page_ids, *sargs)
                 tok.block_until_ready()
                 if self.prefix_cache_enabled:
                     self._draft_tail_fns[lb] = self._build_tail_fn(
                         lb, name="draft_tail", cfg=self.draft_cfg)
-                    tok, self._dk, self._dv = self._draft_tail_fns[lb](
+                    tok, (self._dk, self._dv) = self._draft_tail_fns[lb](
                         self._draft_params, tokens, jnp.int32(0),
-                        jnp.int32(0), self._dk, self._dv, full_ids,
+                        jnp.int32(0), (self._dk, self._dv), full_ids,
                         *sargs)
                     tok.block_until_ready()
         b = self.max_batch
@@ -499,7 +580,7 @@ class DecodeEngine:
             self._decode_fns[bucket] = self._build_decode_fn(bucket)
             out = self._run_decode(self._decode_fns[bucket],
                                    *self._masked_step_args(bucket))
-            out.block_until_ready()
+            self.next_tokens(out).block_until_ready()
             if self.spec_enabled:
                 self._propose_fns[bucket] = self._build_propose_fn(
                     bucket)
@@ -566,10 +647,22 @@ class DecodeEngine:
         through the tail program family, whose page table is padded to
         the largest bucket for a static shape. With a draft model
         loaded, the same prompt also prefills the draft pools (same
-        pages, draft-shaped K/V)."""
+        pages, draft-shaped K/V). `launch_prefill` and `fetch_prefill`
+        are the two halves: a scheduler with steps in flight takes
+        their tokens out between them."""
+        return self.fetch_prefill(self.launch_prefill(
+            token_ids, table, start=start, seed=seed,
+            temperature=temperature, top_k=top_k, top_p=top_p))
+
+    def launch_prefill(self, token_ids, table, *, start=0, seed=0,
+                       temperature=0.0, top_k=0, top_p=1.0):
+        """The prefill's programs dispatched, nothing fetched: returns
+        their outputs still on the device, for `fetch_prefill`."""
         n = len(token_ids)
         sargs = self._samp_scalars(seed, temperature, top_k, top_p)
         zargs = self._samp_scalars()  # draft prefill output is unused
+        if self.chunk_buckets:
+            return self._launch_chunks(token_ids, table, start, sargs)
         if start and self.merged_step_enabled:
             raise PageError(
                 "tail prefill has no dedicated program in merged-step "
@@ -584,13 +677,13 @@ class DecodeEngine:
             max_pages = pages_needed(self.max_context, self.page_size)
             page_ids = np.full((max_pages,), SCRATCH_PAGE, np.int32)
             page_ids[:len(table)] = table
-            tok, self._k, self._v = self._tail_fns[lb](
+            tok, self._pools = self._tail_fns[lb](
                 self._params, tokens, jnp.int32(start), jnp.int32(n),
-                self._k, self._v, page_ids, *sargs)
+                self._pools, page_ids, *sargs)
             if self.spec_enabled:
-                _, self._dk, self._dv = self._draft_tail_fns[lb](
+                _, (self._dk, self._dv) = self._draft_tail_fns[lb](
                     self._draft_params, tokens, jnp.int32(start),
-                    jnp.int32(n), self._dk, self._dv, page_ids, *zargs)
+                    jnp.int32(n), (self._dk, self._dv), page_ids, *zargs)
         else:
             lb = pick_bucket(n, self.prefill_buckets)
             tokens = np.zeros((1, lb), np.int32)
@@ -598,16 +691,59 @@ class DecodeEngine:
             page_ids = np.full((pages_needed(lb, self.page_size),),
                                SCRATCH_PAGE, np.int32)
             page_ids[:len(table)] = table
-            tok, self._k, self._v = self._prefill_fns[lb](
-                self._params, tokens, jnp.int32(n), self._k, self._v,
+            tok, self._pools = self._prefill_fns[lb](
+                self._params, tokens, jnp.int32(n), self._pools,
                 page_ids, *sargs)
             if self.spec_enabled:
-                _, self._dk, self._dv = self._draft_prefill_fns[lb](
+                _, (self._dk, self._dv) = self._draft_prefill_fns[lb](
                     self._draft_params, tokens, jnp.int32(n),
-                    self._dk, self._dv, page_ids, *zargs)
-        # the sampled token must reach the host to stream/EOS-check —
-        # the one deliberate sync of the prefill path
-        return int(np.asarray(tok))
+                    (self._dk, self._dv), page_ids, *zargs)
+        return tok
+
+    def fetch_prefill(self, launched):
+        """The first generated token of a launched prefill (host int):
+        the sampled token must reach the host to stream/EOS-check —
+        the one deliberate sync of the prefill path. A chunked
+        prefill's outputs (the last chunk's first token, every chunk's
+        counters) come back in this ONE fetch (`last_prefill`)."""
+        if not self.chunk_buckets:
+            return int(np.asarray(launched))
+        host = np.stack(jax.device_get(launched))
+        self.last_prefill = {"chunks": len(launched), **dict(zip(
+            self.cfg.step_counters, self._sum_counters(host[:, 1:])))}
+        return int(host[-1, 0])
+
+    def _launch_chunks(self, token_ids, table, start, sargs):
+        """Positions [start, n) of the prompt through the pages, in
+        chunks of at most `cfg.prefill_chunk` tokens: each chunk one
+        dispatch of the program of its (tokens, context pages) bucket,
+        nothing fetched between them. Decode steps do not run between
+        the chunks of one admission (the scheduler's turn is the whole
+        prompt)."""
+        n = len(token_ids)
+        outs, pos = [], start
+        while pos < n:
+            m = min(self.cfg.prefill_chunk, n - pos)
+            tb = pick_bucket(m, self.chunk_buckets)
+            cb = pick_bucket(pages_needed(pos + m, self.page_size),
+                             self.context_buckets)
+            tokens = np.zeros((1, tb), np.int32)
+            tokens[0, :m] = token_ids[pos:pos + m]
+            page_ids = np.full((cb,), SCRATCH_PAGE, np.int32)
+            page_ids[:min(len(table), cb)] = table[:cb]
+            out, self._pools = self._chunk_fns[tb, cb](
+                self._params, tokens, jnp.int32(pos), jnp.int32(pos + m),
+                self._pools, page_ids, *sargs)
+            outs.append(out)
+            pos += m
+        return outs
+
+    def _sum_counters(self, rows):
+        """Counter rows (n, len(step_counters)) as one row: sums, and
+        the largest of a `*_max` counter."""
+        return [int(rows[:, i].max() if name.endswith("_max")
+                    else rows[:, i].sum())
+                for i, name in enumerate(self.cfg.step_counters)]
 
     def step(self, tokens, page_table, lengths, active, seeds=None,
              temps=None, top_ks=None, top_ps=None):
@@ -624,12 +760,23 @@ class DecodeEngine:
         Two leaf spans partition the call: `engine.launch` (row
         padding, host-to-device transfers, the program call returning)
         and `engine.fetch` (the wait for the device and the copy
-        back)."""
+        back). `launch_step` and `fetch_step` are the two halves: a
+        scheduler that keeps steps in flight launches several before
+        it fetches the oldest."""
+        out = self.launch_step(tokens, page_table, lengths, active,
+                               seeds, temps, top_ks, top_ps)
+        return self.fetch_step(out, len(tokens))
+
+    def launch_step(self, tokens, page_table, lengths, active,
+                    seeds=None, temps=None, top_ks=None, top_ps=None):
+        """The step's first half: returns its output still on the
+        device. `tokens` may be `next_tokens(out)` of the step launched
+        before, so that nothing waits for the host between them."""
         bucket = page_table.shape[1]
-        b_in = len(tokens)
         r = self.step_rows
         with _trace.span("engine.launch"):
-            tokens = self._pad_rows(tokens, np.int32, 0)
+            if not isinstance(tokens, jax.Array):
+                tokens = self._pad_rows(tokens, np.int32, 0)
             lengths = self._pad_rows(lengths, np.int32, 0)
             active = self._pad_rows(active, bool, False)
             if page_table.shape[0] < r:
@@ -638,13 +785,28 @@ class DecodeEngine:
                      np.full((r - page_table.shape[0], bucket),
                              SCRATCH_PAGE, np.int32)])
             sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
-            out = self._run_decode(
+            return self._run_decode(
                 self._decode_fns[bucket], self._params, tokens,
-                self._k, self._v, page_table, lengths, active, *sarr)
+                self._pools, page_table, lengths, active, *sarr)
+
+    def fetch_step(self, out, rows):
+        """The step's second half: the wait for the device and the
+        copy back of the rows' tokens and, after them, the step's
+        counters (`last_step_counters`)."""
         with _trace.span("engine.fetch"):
-            # the wait for the device and the copy back
             host = np.asarray(out)
-        return host[:b_in]
+        if self.cfg.step_counters:
+            self.last_step_counters = dict(zip(
+                self.cfg.step_counters,
+                host[self.step_rows:].tolist()))
+        return host[:rows]
+
+    def next_tokens(self, out):
+        """A launched step's tokens as the next step's `tokens`, on the
+        device (its counters, where the block counts, cut off)."""
+        if out.shape[0] == self.step_rows:
+            return out
+        return self._next_tokens_fn(out)
 
     def _pad_rows(self, arr, dtype, fill):
         arr = np.asarray(arr, dtype)
@@ -669,10 +831,11 @@ class DecodeEngine:
             drafts, q_dists, self._dk, self._dv = self._propose_fns[
                 bucket](self._draft_params, tokens, self._dk, self._dv,
                         page_table, lengths, active, *sarr)
-            tokens_out, n_emit, self._k, self._v = self._verify_fns[
+            tokens_out, n_emit, *pools = self._verify_fns[
                 bucket](self._params, tokens, drafts, q_dists, self._k,
                         self._v, page_table, lengths, active, use_draft,
                         *sarr)
+            self._pools = tuple(pools)
         with _trace.span("engine.fetch"):
             host_toks, host_n = jax.device_get((tokens_out, n_emit))
         return np.asarray(host_toks), np.asarray(host_n)
@@ -683,18 +846,19 @@ class DecodeEngine:
         `BlockAllocator.make_writable`."""
         src = jnp.int32(src)
         dst = jnp.int32(dst)
-        self._k = self._copy_fn(self._k, src, dst)
-        self._v = self._copy_fn(self._v, src, dst)
+        self._pools = tuple(self._copy_fn(p, src, dst)
+                            for p in self._pools)
         if self._draft_params is not None:
             self._dk = self._copy_fn(self._dk, src, dst)
             self._dv = self._copy_fn(self._dv, src, dst)
 
     # ----------------------------------------------------- test hooks
     def read_page(self, layer, page):
-        """Host copy of one page's (K, V), dequantized to float32 —
-        test/debug only (the hot paths never materialize this)."""
-        return (np.asarray(_quant.dequant_page(self._k, layer, page)),
-                np.asarray(_quant.dequant_page(self._v, layer, page)))
+        """Host copy of one page of every plane ((K, V) for the dense
+        block), dequantized to float32 — test/debug only (the hot
+        paths never materialize this)."""
+        return tuple(np.asarray(_quant.dequant_page(p, layer, page))
+                     for p in self._pools)
 
     def read_page_raw(self, layer, page):
         """Host copy of one page's stored (K, V, k_scale, v_scale) —
@@ -722,14 +886,41 @@ class DecodeEngine:
         drift oracle bench/CI use to compare kv dtypes position by
         position under teacher forcing. Adds zero traces (nothing is
         jitted) and never mutates the pools."""
-        attn = (_attn.get_ragged_kernel(self.kernel_name)
-                if self.merged_step_enabled else self._attn)
-        logits, _k, _v, _c = _model.decode_logits(
-            self._params, jnp.asarray(tokens, jnp.int32), self._k,
-            self._v, jnp.asarray(page_table, jnp.int32),
+        logits, _picked = self.cfg.probe_step(
+            self._params, jnp.asarray(tokens, jnp.int32), self._pools,
+            jnp.asarray(page_table, jnp.int32),
             jnp.asarray(lengths, jnp.int32), jnp.asarray(active, bool),
-            cfg=self.cfg, attn=attn)
+            kernels=self._kernels)
         return np.asarray(logits, np.float32)
+
+    def probe_selected(self, tokens, page_table, lengths, active):
+        """`probe_logits` for a block that selects what it attends:
+        (logits (B, V), selected positions (layers, B, k), -1 where a
+        row had fewer in reach) of one decode step over the CURRENT
+        pool state, nothing written. One jitted program per (rows,
+        bucket), built at the first call — outside any warmed grid, so
+        call it outside a measured window."""
+        page_table = np.asarray(page_table, np.int32)
+        key = page_table.shape
+        if key not in self._probe_fns:
+            cfg, kernels = self.cfg, self._kernels
+
+            def impl(params, tokens, pools, page_table, lengths, active):
+                return cfg.probe_step(params, tokens, pools, page_table,
+                                      lengths, active, kernels=kernels)
+
+            fam = cfg.program_family
+            self._probe_fns[key] = self._jit(
+                impl, f"{fam}probe_b{key[0]}_p{key[1]}",
+                f"probe@{key[0]}x{key[1]}", ())
+        logits, picked = self._probe_fns[key](
+            self._params, np.asarray(tokens, np.int32), self._pools,
+            page_table, np.asarray(lengths, np.int32),
+            np.asarray(active, bool))
+        if picked is None:
+            raise PageError("this block attends every cached token: "
+                            "nothing is selected")
+        return np.asarray(logits, np.float32), np.asarray(picked)
 
 
 def quant_parity_probe(params, cfg, prompt, max_new=16, *,

@@ -57,6 +57,61 @@ class DecoderConfig:
             raise ValueError("d_model must divide into n_heads")
         return self.d_model // self.n_heads
 
+    # ---- the model contract: what DecodeEngine takes from a
+    # configuration instead of naming one block. `planes` is what a
+    # token of a layer stores in the page pool; `program_family`
+    # prefixes the compiled modules' names; `prefill_chunk` is None
+    # where a whole prompt is one dense program (with the tail program
+    # for prefix-cache hits), or the most tokens of one chunk where a
+    # prompt goes through the pages in chunks (`chunk_step`);
+    # `step_counters` names the int32 values a step's output carries
+    # after its tokens. `kernels` is the engine's choice of paged
+    # attention (`attn`, `attn_multi`), for a block that uses it.
+    program_family = ""
+    step_counters = ()
+    prefill_chunk = None
+
+    @property
+    def planes(self):
+        return (_quant.Plane("k", self.d_model, self.n_heads),
+                _quant.Plane("v", self.d_model, self.n_heads))
+
+    def decode_step(self, params, tokens, pools, page_table, lengths,
+                    active, seeds=None, temps=None, top_ks=None,
+                    top_ps=None, *, kernels, with_stats=False):
+        res = decode_forward(params, tokens, pools[0], pools[1],
+                             page_table, lengths, active, seeds, temps,
+                             top_ks, top_ps, cfg=self, attn=kernels.attn,
+                             with_stats=with_stats)
+        return (res[0], res[1:3]) + res[3:]
+
+    def prefill_step(self, params, tokens, length, pools, page_ids,
+                     seed=None, temperature=None, top_k=None, top_p=None,
+                     *, kernels, attn_fn=None):
+        tok, k, v = prefill_forward(params, tokens, length, pools[0],
+                                    pools[1], page_ids, seed, temperature,
+                                    top_k, top_p, cfg=self,
+                                    attn_fn=attn_fn)
+        return tok, (k, v)
+
+    def probe_step(self, params, tokens, pools, page_table, lengths,
+                   active, *, kernels):
+        """(logits, what each row selected: None, this block attends
+        every cached token) of a decode step that writes nothing."""
+        logits, _k, _v, _c = decode_logits(
+            params, tokens, pools[0], pools[1], page_table, lengths,
+            active, cfg=self, attn=kernels.attn)
+        return logits, None
+
+    def chunk_step(self, params, tokens, start, length, pools, page_ids,
+                   seed=None, temperature=None, top_k=None, top_p=None,
+                   *, kernels):
+        tok, k, v = tail_prefill_forward(
+            params, tokens, start, length, pools[0], pools[1], page_ids,
+            seed, temperature, top_k, top_p, cfg=self,
+            attn_multi=kernels.attn_multi)
+        return tok, (k, v)
+
 
 def init_decoder_params(cfg, seed=0):
     """Seeded random weights (explicit generator: MX005-clean)."""
@@ -147,6 +202,17 @@ def _pick_token(logits, seed, position, temperature, top_k, top_p):
         return jnp.argmax(logits).astype(jnp.int32)
     return _sampling.sample_token(logits, seed, position, temperature,
                                   top_k, top_p)
+
+
+def _sample_rows(logits, seeds, positions, temps, top_ks, top_ps):
+    """Each row's next token: argmax without sampling arrays, else
+    drawn per row on its (seed, position) stream."""
+    if seeds is None:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.vmap(
+        lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
+            lg, sd, p, tm, tk, tp))(
+        logits, seeds, positions, temps, top_ks, top_ps)
 
 
 # --------------------------------------------------------------- prefill
@@ -341,13 +407,8 @@ def decode_forward(params, tokens, k_pages, v_pages, page_table,
         params, tokens, k_pages, v_pages, page_table, lengths, active,
         cfg=cfg, attn=attn)
     with jax.named_scope("sample"):
-        if seeds is None:
-            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            next_tokens = jax.vmap(
-                lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
-                    lg, sd, p, tm, tk, tp))(
-                logits, seeds, lengths + 1, temps, top_ks, top_ps)
+        next_tokens = _sample_rows(logits, seeds, lengths + 1, temps,
+                                   top_ks, top_ps)
     if with_stats:
         bad_rows = jnp.any(~jnp.isfinite(logits), axis=-1)
         nonfinite = jnp.sum(

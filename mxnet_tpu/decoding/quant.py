@@ -17,8 +17,14 @@ shares, fleet handoffs).
 
 Storage layout (one for float32, bf16 and int8; no knob):
 
-  data   [layers, pages, page_size, heads*head_dim]
-  scale  [layers, pages, page_size*heads]    float32, int8 pools only
+  data   [layers, pages, page_size, width]
+  scale  [layers, pages, page_size*groups]   float32, int8 pools only
+
+A model's pool is a tuple of such planes on ONE page table (`Plane`:
+a name, a width a token, the scale groups of a row). The dense block
+has `k` and `v` of width heads*head_dim; a latent-attention block has
+one shared latent row and one index key a token. The allocator, the
+refcounts and the radix cache know page ids alone.
 
 The minor dimension of `data` is a whole K (or V) row of one token,
 all heads side by side; that of `scale` is a page's scales, slot by
@@ -84,6 +90,9 @@ POOL_LAYOUT = ("data[layers,pages,page_size,heads*head_dim] "
 # scale floor: keeps an all-zero (or denormal) K/V row from dividing
 # by zero; 1e-8/127 quantizes everything below float32 noise to 0
 _SCALE_FLOOR = 1e-8
+
+# lanes of the chip's (8, 128) tile: what a stored row is a multiple of
+_LANES = 128
 
 
 def canonical(kv_dtype):
@@ -170,18 +179,57 @@ def as_layer(x):
     return KVLayer(KVPool(x[None], None), 0)
 
 
-def make_pool(shape, kv_dtype):
-    """A zeroed pool for `shape` = (layers, pages, page_size, heads,
-    head_dim) at `kv_dtype`, stored in `POOL_LAYOUT`; int8 pools get
-    their scale plane."""
+class Plane(NamedTuple):
+    """One plane of a model's page pool, as its configuration states
+    it: what a token of a layer stores under this name. `width` values
+    a token a layer; `groups` is how many int8 scales a row carries
+    (the heads of a per-head K or V row; 1 for a row that is one
+    vector). Every plane of a model shares ONE page table: page `p`,
+    slot `s` is the same token in each."""
+
+    name: str
+    width: int
+    groups: int = 1
+
+    @property
+    def stored_width(self):
+        """The minor dimension the plane is stored with: a width past
+        128 that is no multiple of it (a 576-wide latent row) is
+        padded up with zeros. The chip keeps such an array in whole
+        (8, 128) tiles anyway, and with a ragged minor dimension it
+        picks ANOTHER dimension as minor for the program's argument —
+        the whole pool is then copied in and out of every program that
+        writes it (tests/test_chip_compile.py reads the compiled text).
+        Widths under 128 (toy sizes) are stored as they are."""
+        if self.width <= _LANES or self.width % _LANES == 0:
+            return self.width
+        return -(-self.width // _LANES) * _LANES
+
+
+def make_plane(layers, pages, page_size, plane, kv_dtype):
+    """A zeroed pool for one `Plane` at `kv_dtype`, stored in
+    `POOL_LAYOUT` (minor dimension `plane.stored_width`); int8 pools
+    get their scale plane, one scale per (slot, group)."""
     name = canonical(kv_dtype)
-    layers, pages, page_size, heads, head_dim = shape
-    data = jnp.zeros((layers, pages, page_size, heads * head_dim),
+    if name == "int8" and plane.stored_width != plane.width:
+        raise PageError(
+            f"plane {plane.name!r} of width {plane.width} is stored "
+            "padded: int8 groups would straddle the padding")
+    data = jnp.zeros((layers, pages, page_size, plane.stored_width),
                      storage_dtype(name))
     if name != "int8":
         return KVPool(data, None)
-    return KVPool(data, jnp.zeros((layers, pages, page_size * heads),
-                                  jnp.float32))
+    return KVPool(data, jnp.zeros(
+        (layers, pages, page_size * plane.groups), jnp.float32))
+
+
+def make_pool(shape, kv_dtype):
+    """A zeroed pool for `shape` = (layers, pages, page_size, heads,
+    head_dim) at `kv_dtype`: the per-head K (or V) plane of width
+    heads*head_dim (`make_plane`)."""
+    layers, pages, page_size, heads, head_dim = shape
+    return make_plane(layers, pages, page_size,
+                      Plane("kv", heads * head_dim, heads), kv_dtype)
 
 
 def quantize_values(values):
@@ -221,12 +269,21 @@ def kv_scatter(pool, layer, pages, slots, values):
     (..., H*D) at [layer, pages, slots] (index arrays shaped like
     values minus the trailing (H, D)), quantizing INTO the pool's
     storage dtype so a full-precision K/V tensor never exists outside
-    the current activations. On a donated pool the scatter is in
-    place. Returns (pool', clips () i32); clips is 0 for non-int8
-    pools."""
+    the current activations. `values` (..., W), one axis more than the
+    index arrays, is a row of a plane as it is stored (`Plane.width`;
+    an int8 pool splits it into its `groups`). On a donated pool the
+    scatter is in place. Returns (pool', clips () i32); clips is 0 for
+    non-int8 pools."""
+    if values.ndim == jnp.ndim(pages) + 1:
+        groups = 1 if pool.scale is None else \
+            pool.scale.shape[-1] // pool.page_size
+        values = values.reshape(values.shape[:-1] + (groups, -1))
     if pool.scale is None:
-        data = pool.data.at[layer, pages, slots].set(
-            _fold_heads(values).astype(pool.data.dtype))
+        row = _fold_heads(values).astype(pool.data.dtype)
+        pad = pool.data.shape[-1] - row.shape[-1]
+        if pad:     # a plane stored wider than its row (stored_width)
+            row = jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)])
+        data = pool.data.at[layer, pages, slots].set(row)
         return KVPool(data, None), jnp.int32(0)
     q, scale, clips = quantize_values(values)
     data = pool.data.at[layer, pages, slots].set(_fold_heads(q))
@@ -259,6 +316,35 @@ def gather_ctx(layer, page_table, heads):
         return d
     s = pool.scale[i, page_table]
     return d * s.reshape(s.shape[:-1] + (-1, heads, 1))
+
+
+def gather_rows(layer, page_table, positions):
+    """Token-granular read: the rows of `positions` (B, ...) int32
+    (token positions of each row's own sequence) through `page_table`
+    (B, Bp), straight from the pool — (B, ..., stored width) in the
+    storage type (float pools; the sparse attention's read of its
+    selected rows; the caller drops a padded plane's tail). A position
+    past the table reads its last page."""
+    pool, i = as_layer(layer)
+    if pool.scale is not None:
+        raise PageError("gather_rows reads float pools only")
+    p = pool.page_size
+    flat = positions.reshape(positions.shape[0], -1)
+    pages = jnp.take_along_axis(
+        page_table, jnp.clip(flat // p, 0, page_table.shape[1] - 1),
+        axis=1)
+    return pool.data[i, pages, flat % p].reshape(
+        positions.shape + pool.data.shape[-1:])
+
+
+def gather_plane(layer, page_table):
+    """A row's whole context of one plane, as stored: (B, Bp) ->
+    (B, Bp*P, W) (float pools)."""
+    pool, i = as_layer(layer)
+    if pool.scale is not None:
+        raise PageError("gather_plane reads float pools only")
+    d = pool.data[i, page_table]
+    return d.reshape(d.shape[0], -1, d.shape[-1])
 
 
 def dequant_page(pool, layer, page):

@@ -34,6 +34,12 @@ Two work-avoidance layers ride the same loop (ROADMAP item 1):
     propose+verify pair and can emit up to spec_k+1 tokens per target
     step (speculative.py proves output equivalence).
 
+With `run_ahead` = D the loop keeps D plain steps launched beyond the
+one whose tokens it waits for (`_turn_ahead`): the device goes from
+step to step without the host; a request for a free row is admitted
+with the steps still in flight, and every other decision of the list
+above is made with nothing in flight.
+
 Tokens reach callers through `DecodeFuture`: `result()` is the full
 generated list (the serving Future contract), `stream()` returns a
 `TokenStream` iterating tokens as steps complete. The stream OWNS the
@@ -43,6 +49,7 @@ decoding on to max_tokens for a reader that left.
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import queue
 import threading
@@ -203,7 +210,7 @@ class _Sequence:
                  "trace_id", "order", "sampling", "use_draft",
                  "generated", "table", "length", "last_token",
                  "preempted", "t_submit_pc", "pending_tail",
-                 "tail_meta")
+                 "tail_meta", "ahead")
 
     def __init__(self, prompt, max_new, priority, deadline, future,
                  trace_id, order, sampling, use_draft):
@@ -228,6 +235,9 @@ class _Sequence:
         # for the note_prefill/span record at completion
         self.pending_tail = None
         self.tail_meta = None
+        # steps launched for this row whose tokens are not out yet
+        # (ContinuousScheduler.run_ahead)
+        self.ahead = 0
 
     def context_tokens(self):
         """Tokens the KV cache must hold for this sequence: the prompt
@@ -241,10 +251,21 @@ class ContinuousScheduler:
     """The rolling-batch control loop over one DecodeEngine."""
 
     def __init__(self, engine, stats, key, queue_cap=None,
-                 max_tokens=None, eos_id=None):
+                 max_tokens=None, eos_id=None, run_ahead=None):
         self.engine = engine
         self.stats = stats
         self.key = key
+        # steps kept in flight beyond the one whose tokens the loop
+        # waits for (see _turn_ahead); 0 is the turn that launches a
+        # step and waits for it
+        self.run_ahead = int(run_ahead or 0)
+        if self.run_ahead and (engine.spec_enabled
+                               or engine.merged_step_enabled):
+            raise ServingError(
+                "run_ahead keeps plain decode steps in flight: not "
+                "with speculation or the merged step")
+        self._ahead = collections.deque()   # launched, tokens not out
+        self._t_retired = 0.0
         self.queue_cap = queue_cap if queue_cap is not None \
             else _cfg.queue_cap()
         self.default_max_tokens = max_tokens if max_tokens is not None \
@@ -532,9 +553,11 @@ class ContinuousScheduler:
         """Evict for pages: drop the sequence's pages but keep its
         token history; it re-prefills on readmission (bit-identical
         continuation — the XLA prefix-stability property)."""
-        if seq.table is not None:
-            self.engine.allocator.free(seq.table)
-            seq.table = None
+        self._settle()      # its tokens in flight come out first
+        if seq.table is None:
+            return          # one of them was its last
+        self.engine.allocator.free(seq.table)
+        seq.table = None
         seq.preempted = True
         # a merged-step tail in flight dies with the pages: readmission
         # re-plans the whole prompt (possibly re-matching the cache)
@@ -553,6 +576,7 @@ class ContinuousScheduler:
         The requester itself is a candidate (it may BE the lowest
         priority). Returns the victim, or None when nothing is
         preemptible."""
+        self._settle()      # a victim's history must be whole
         victims = self._active()
         if requester is not None and requester.table is None:
             # an admission candidate competes at its own priority
@@ -716,20 +740,31 @@ class ContinuousScheduler:
                 seq.tail_meta = (t0, len(tokens), start, need_total,
                                  len(matched))
                 continue
-            first = self.engine.prefill(
+            launched = self.engine.launch_prefill(
                 tokens, seq.table, start=start,
                 seed=seq.sampling.seed,
                 temperature=seq.sampling.temperature,
                 top_k=seq.sampling.top_k, top_p=seq.sampling.top_p)
+            if self._ahead:
+                # the prefill waits on the device behind the steps in
+                # flight: their tokens go out as they arrive, and the
+                # span is the prefill's own time
+                self._settle()
+                t0 = _trace.now()
+            first = self.engine.fetch_prefill(launched)
             dt = _trace.now() - t0
             self.stats.note_prefill(len(tokens) - start, dt,
                                     readmission=seq.preempted)
+            # how many programs the prompt took, and what a block that
+            # counts (engine.cfg.step_counters) counted over them
+            counted = self.engine.last_prefill
+            self.stats.note_counters(counted)
             _trace.record_span(
                 "decoding.prefill", seq.trace_id, t0, t0 + dt,
                 {"model": self.key, "tokens": len(tokens),
                  "cached_tokens": start, "pages": need_total,
                  "pages_reused": len(matched),
-                 "readmission": seq.preempted})
+                 "readmission": seq.preempted, **counted})
             seq.length = len(tokens)
             if self.cache is not None:
                 # publish this prompt's full pages (existing runs keep
@@ -767,7 +802,8 @@ class ContinuousScheduler:
             # pages covering the step's write positions (clamped to
             # capacity: the host stops at max_context before any
             # clamped write could be read back)
-            cover = min(seq.length + k + 1, self.engine.max_context)
+            cover = min(seq.length + seq.ahead + k + 1,
+                        self.engine.max_context)
             need = pages_needed(cover, P)
             while seq.table is not None and len(seq.table) < need:
                 try:
@@ -780,7 +816,7 @@ class ContinuousScheduler:
                         break
             if seq.table is None or len(seq.table) < need:
                 continue    # preempted itself; back in the queue
-            first = seq.length // P
+            first = (seq.length + seq.ahead) // P
             last = min((cover - 1) // P, len(seq.table) - 1)
             for idx in range(first, last + 1):
                 page, copy_from = None, None
@@ -877,7 +913,7 @@ class ContinuousScheduler:
                 "ctx_tokens": int(lengths[active].sum())
                 + int(active.sum()) * (k + 1),
                 "program": engine.step_program(bucket)}
-        with _trace.span("decoding.step", **step_attrs):
+        with _trace.span("decoding.step", **step_attrs) as step_span:
             t0 = _trace.now()
             if spec:
                 out, n_emit = engine.spec_step(
@@ -885,6 +921,10 @@ class ContinuousScheduler:
             else:
                 out = engine.step(tokens, table, lengths, active, *samp)
             dt = _trace.now() - t0
+            if engine.cfg.step_counters:
+                # came back with the tokens, in the step's one fetch
+                step_span.note(**engine.last_step_counters)
+                self.stats.note_counters(engine.last_step_counters)
         with _trace.span("decoding.emit") as emit_span:
             emitted = self._emit(live, tail_rows, out,
                                  n_emit if spec else None)
@@ -927,7 +967,7 @@ class ContinuousScheduler:
                 continue    # fed through the ragged tail rows below
             tokens[row] = s.last_token
             table[row, :len(s.table)] = s.table
-            lengths[row] = s.length
+            lengths[row] = s.length + s.ahead
             active[row] = True
             use_draft[row] = s.use_draft
             seeds[row] = s.sampling.seed & 0xFFFFFFFF
@@ -999,6 +1039,158 @@ class ContinuousScheduler:
                     self._finish_tail(seq, int(out[last_row]))
         return emitted
 
+    # ------------------------------------------------- steps in flight
+    # With `run_ahead` = D the loop keeps D steps launched beyond the
+    # one whose tokens it waits for, so the device goes from one step
+    # to the next without the host: a step's input tokens are the step
+    # before's output, taken on the device (`engine.next_tokens`); its
+    # lengths, page table and live rows the host knows without the
+    # tokens (a row's budget of steps is `max_new` and the context's
+    # capacity; only an `eos` ends a row unannounced, and what was
+    # launched for it after that is dropped unread). Tokens still go
+    # out one step at a time, as each step's output arrives. Every
+    # DECISION about a live row — cancellation, deadlines, preemption,
+    # shutdown — is made with nothing in flight: what needs one makes
+    # the loop take out all that is launched first (`_settle`), so the
+    # rest of the scheduler never sees a sequence whose `generated`
+    # lags its pages. A request for a FREE row is admitted with the
+    # steps still in flight (`_turn_ahead`). The price is that a
+    # finished row's slot waits D steps for its successor's prefill.
+
+    def _steps_left(self, seq):
+        """Steps this row can still take beyond those in flight."""
+        return min(seq.max_new - len(seq.generated),
+                   self.engine.max_context - seq.length) - seq.ahead
+
+    def _pending(self, now):
+        """What waits for a decision of the loop: None; "admit", a
+        request for a free row and nothing else; or "settle"."""
+        def flagged(s):
+            return s.future._cancel.is_set() or (
+                s.deadline is not None and now > s.deadline)
+
+        with self._cond:
+            if self._closed or self._draining:
+                return "settle"
+            waiting = list(self._waiting)
+            free = None in self._rows
+        if any(flagged(s) for s in waiting) \
+                or any(flagged(s) for s in self._active()):
+            return "settle"
+        return "admit" if waiting and free else None
+
+    def _launch_ahead(self):
+        """Launch the next step of every row that has one left to take;
+        False where no row has, or where the pool could not back every
+        row's next position without reclaiming pages (a decision: the
+        loop then falls back to one step at a time)."""
+        engine = self.engine
+        live = [(row, s) for row, s in enumerate(self._rows)
+                if s is not None and s.table is not None
+                and self._steps_left(s) > 0]
+        if not live:
+            return False
+        if self._ahead:
+            # _grow without pressure: a page to grow by and a page to
+            # copy into for each row at most
+            if engine.allocator.free_pages() < 2 * len(live):
+                return False
+            self._grow()
+        with _trace.span("decoding.pack"):
+            (tokens, table, lengths, active, _draft, *samp), _tails, \
+                bucket = self._pack(live)
+            attrs = {
+                "trace_ids": tuple(s.trace_id for _, s in live),
+                "model": self.key, "live": len(live), "bucket": bucket,
+                "ctx_tokens": int(lengths[active].sum())
+                + int(active.sum()),
+                "program": engine.step_program(bucket)}
+            if self._ahead:
+                tokens = engine.next_tokens(self._ahead[-1][1])
+        out = engine.launch_step(tokens, table, lengths, active, *samp)
+        for _, s in live:
+            s.ahead += 1
+        self._ahead.append((live, out, attrs, _trace.now()))
+        return True
+
+    def _retire(self, step_span):
+        """Take the oldest launched step's tokens out: the fetch, then
+        the rows' bookkeeping and streams. Its attributes go on the
+        `decoding.step` span that holds it."""
+        engine = self.engine
+        live, out, attrs, t_launch = self._ahead.popleft()
+        host = engine.fetch_step(out, engine.max_batch)
+        t_out = _trace.now()
+        step_span.note(**attrs)
+        if engine.cfg.step_counters:
+            step_span.note(**engine.last_step_counters)
+            self.stats.note_counters(engine.last_step_counters)
+        with _trace.span("decoding.emit") as emit_span:
+            emitted = 0
+            for row, s in live:
+                s.ahead -= 1
+                if s.table is None or s.future.done():
+                    continue    # ended by an eos while this was in flight
+                s.length += 1
+                emitted += 1
+                self._handle_token(s, int(host[row]))
+            emit_span.note(tokens=emitted)
+            # the step's own seconds: from its launch, or from the
+            # step before's tokens where it waited behind that
+            self.stats.note_step(
+                emitted, t_out - max(t_launch, self._t_retired))
+            self._t_retired = t_out
+            self.stats.note_pool()
+            if engine._guard and self.stats.steps % 16 == 0:
+                for nf, clips in engine.drain_guard():
+                    if nf:
+                        self.stats.note_nonfinite(nf)
+                    if clips:
+                        self.stats.note_quant_clips(clips)
+
+    def _settle(self):
+        """Take out everything that is launched, oldest first."""
+        while self._ahead:
+            with _trace.span("decoding.step") as step_span:
+                self._retire(step_span)
+
+    def _turn_ahead(self):
+        """One turn with steps in flight: decide what waits for a
+        decision, launch up to `run_ahead` steps beyond the oldest,
+        take the oldest's tokens out. The `decoding.step` span holds
+        the turn's launches, its fetch and its emit, so that the spans
+        of successive turns tile the device's time as the device goes
+        from step to step.
+
+        A request for a free row is admitted WITH the launched steps
+        still in flight: the row is free, so nothing launched reads or
+        writes what the admission touches, the host's share of it runs
+        while the device works, and its prefill queues behind the
+        steps (what would need a victim's whole history, a preemption,
+        takes everything out first: `_reclaim_one`, `_preempt`). When
+        the prefill's token is back all that was launched is done, and
+        comes out before the next launch. Anything else that waits
+        for a decision is decided with nothing in flight."""
+        pending = self._pending(time.monotonic()) if self._ahead \
+            else "settle"
+        if pending == "settle":
+            self._settle()
+        if pending is not None:
+            with _trace.span("decoding.admit"):
+                self._check_deadlines(time.monotonic())
+                self._check_cancelled()
+                self._admit()
+                self._grow()
+            self._settle()
+        if not any(self._rows):
+            return
+        with _trace.span("decoding.step") as step_span:
+            while len(self._ahead) <= self.run_ahead \
+                    and self._launch_ahead():
+                pass
+            if self._ahead:
+                self._retire(step_span)
+
     # -------------------------------------------------------------- loop
     def _loop(self):
         while True:
@@ -1035,6 +1227,9 @@ class ContinuousScheduler:
                 # one turn = decoding.admit, then _step's three spans;
                 # prefills launched by _admit record decoding.prefill
                 # inside (parent decoding.admit)
+                if self.run_ahead:
+                    self._turn_ahead()
+                    continue
                 with _trace.span("decoding.admit"):
                     self._check_deadlines(time.monotonic())
                     self._check_cancelled()
@@ -1042,6 +1237,10 @@ class ContinuousScheduler:
                     self._grow()
                 self._step()
             except Exception as exc:  # never kill the loop silently
+                # what was launched is dropped unread with its rows
+                self._ahead.clear()
+                for s in self._active():
+                    s.ahead = 0
                 for s in self._active():
                     self.stats.note_failed()
                     self._resolve(s, exc=exc)
@@ -1072,7 +1271,8 @@ class DecodedModel:
                  kernel=None, ring_prefill=None, queue_cap=None,
                  max_tokens=None, warmup=True, draft=None,
                  draft_cfg=None, spec_k=None, prefix_cache=None,
-                 merged_step=None, kv_dtype=None):
+                 merged_step=None, kv_dtype=None, chunk_buckets=None,
+                 context_buckets=None, run_ahead=None):
         self.name = name
         self.version = int(version)
         self.cfg = cfg
@@ -1099,13 +1299,14 @@ class DecodedModel:
             kernel=kernel, ring_prefill=ring_prefill,
             draft_params=draft_params, draft_cfg=draft_cfg,
             spec_k=spec_k, prefix_cache=prefix_cache,
-            merged_step=merged_step, kv_dtype=kv_dtype)
+            merged_step=merged_step, kv_dtype=kv_dtype,
+            chunk_buckets=chunk_buckets, context_buckets=context_buckets)
         self.stats = DecodeStats(
             key=self.key, traces_fn=self.engine.traces,
             pool_fn=self.engine.pool_stats)
         self.scheduler = ContinuousScheduler(
             self.engine, self.stats, self.key, queue_cap=queue_cap,
-            max_tokens=max_tokens)
+            max_tokens=max_tokens, run_ahead=run_ahead)
         self.stats._depth_fn = self.scheduler.depth
         if self.scheduler.cache is not None:
             self.stats._prefix_fn = self.scheduler.cache.stats

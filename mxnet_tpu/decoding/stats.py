@@ -141,6 +141,11 @@ class DecodeStats:
             self.nonfinite_logits = 0
             self.quant_clip_steps = 0
             self.quant_clip_values = 0
+            # running sums of what a counting block's programs report
+            # (engine.cfg.step_counters; a `*_max` counter keeps its
+            # largest): empty for a block that counts nothing
+            self.counters = {}
+            self.prefill_chunks = 0
             self.traces_at_warmup = None
             self._prefill_s = 0.0
             self._decode_s = 0.0
@@ -209,6 +214,19 @@ class DecodeStats:
         if live_rows:
             _TOKEN_LATENCY_MS.observe(
                 seconds / live_rows * 1e3, model=self._key)
+
+    def note_counters(self, counted):
+        """One step's or one prefill's counters ({name: int}, with
+        `chunks` for a prefill) into the running sums."""
+        with self._lock:
+            for name, v in counted.items():
+                if name == "chunks":
+                    self.prefill_chunks += v
+                elif name.endswith("_max"):
+                    self.counters[name] = max(
+                        self.counters.get(name, 0), v)
+                else:
+                    self.counters[name] = self.counters.get(name, 0) + v
 
     def note_nonfinite(self, rows, steps=1):
         """Guard trip: `rows` active rows produced NaN/Inf logits
@@ -283,6 +301,8 @@ class DecodeStats:
                 "nonfinite_logits": self.nonfinite_logits,
                 "quant_clip_steps": self.quant_clip_steps,
                 "quant_clip_values": self.quant_clip_values,
+                "prefill_chunks": self.prefill_chunks,
+                **self.counters,
                 "prefill_tokens_per_s": round(
                     self.prefill_tokens / self._prefill_s, 1)
                 if self._prefill_s else 0.0,

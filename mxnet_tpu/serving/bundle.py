@@ -175,6 +175,13 @@ def _served_payload(model, exec_root):
 def _decoded_payload(model, exec_root):
     """Harvest a warm DecodedModel: config, params, decode grid."""
     eng = model.engine
+    if eng.chunk_buckets:
+        # the manifest records the dense block's fields and grid
+        # (prefill + decode programs); a chunk-prefilled block has
+        # another configuration class and program family
+        raise BundleError(
+            "bundles hold the dense decoder block's program grid; a "
+            "chunk-prefilled configuration is not bundled yet")
     jits = [f for f in [eng._copy_fn, *eng._prefill_fns.values(),
                         *eng._decode_fns.values()]
             if _instrumented(f) is not None]

@@ -333,12 +333,17 @@ def test_continuous_batching_parity_concurrent():
         m.close()
 
 
-def test_preempt_then_readmit_bit_identical():
+# with steps in flight a victim's tokens come out before it is preempted
+AHEAD = [{}, {"run_ahead": 3, "merged_step": False}]
+
+
+@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+def test_preempt_then_readmit_bit_identical(ahead):
     """A pool far too small for the offered load: sequences are
     preempted (pages dropped) and readmitted (re-prefilled); the
     continuation must be BIT-identical to an uninterrupted run."""
     m = _model(max_batch=4, num_pages=9, page_buckets=(1, 2, 4),
-               max_tokens=12, queue_cap=64)
+               max_tokens=12, queue_cap=64, **ahead)
     try:
         floor = m.engine.traces()
         prompts = [[int(t) for t in
@@ -361,11 +366,12 @@ def test_preempt_then_readmit_bit_identical():
         m.close()
 
 
-def test_pool_exhaustion_never_crashes():
+@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+def test_pool_exhaustion_never_crashes(ahead):
     """CI gate iii at unit scale: offered load >> pool capacity keeps
     resolving every future (no OOM, no dead scheduler)."""
     m = _model(max_batch=4, num_pages=5, page_buckets=(1, 2),
-               max_tokens=6, queue_cap=64)
+               max_tokens=6, queue_cap=64, **ahead)
     try:
         futs = [m.submit([2 + i, 3, 4], max_new_tokens=5)
                 for i in range(10)]
@@ -453,14 +459,15 @@ def test_admission_errors():
 
 
 # ------------------------------------------------------- randomized soak
-def test_randomized_soak():
+@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+def test_randomized_soak(ahead):
     """Randomized continuous traffic (seeded via mx.random.py_rng —
     MX005-clean): mixed lengths, budgets, priorities, deadlines. Every
     future resolves, non-expired outputs match the reference exactly,
     the allocator ends clean, and the trace count never moves."""
     rng = mx.random.py_rng()
     m = _model(max_batch=3, num_pages=12, page_buckets=(1, 2, 4),
-               queue_cap=128, max_tokens=10)
+               queue_cap=128, max_tokens=10, **ahead)
     try:
         floor = m.engine.traces()
         jobs = []
@@ -523,7 +530,7 @@ def test_decoding_stats_view_shape_pinned():
         assert sorted(snap) == sorted((
             "submitted", "completed", "failed", "rejected", "expired",
             "cancelled", "preemptions", "readmissions", "prefills",
-            "prefill_tokens", "decode_tokens", "steps",
+            "prefill_tokens", "decode_tokens", "steps", "prefill_chunks",
             "spec_proposed", "spec_accepted", "spec_acceptance_rate",
             "tokens_per_target_step",
             "nonfinite_logit_steps", "nonfinite_logits",
@@ -710,12 +717,21 @@ def test_scheduler_turn_is_partitioned_by_leaf_spans():
     assert step.attrs["ctx_tokens"] == 5 + 10 + 1
     assert "tokens" in by["decoding.emit"][10].attrs
     # the children of a step partition it
-    kids = sorted((s for s in spans if s.parent == "decoding.step"
-                   and s.t0 >= step.t0 and s.t1 <= step.t1),
-                  key=lambda s: s.t0)
-    assert [k.name for k in kids] == ["engine.launch", "engine.fetch"]
-    assert kids[0].t1 <= kids[1].t0
-    assert sum(k.t1 - k.t0 for k in kids) >= 0.95 * (step.t1 - step.t0)
+    # on a loaded host the loop's thread loses the CPU between two
+    # spans for milliseconds, under six test workers in most turns:
+    # order and no overlap hold for EVERY step and turn; the share the
+    # leaves cover is a fact of the code between them, which every turn
+    # runs and a lost time slice only adds to, so the BEST-covered of
+    # the fifteen shows it
+    shares = []
+    for st in by["decoding.step"][5:20]:
+        kids = sorted((s for s in spans if s.parent == "decoding.step"
+                       and s.t0 >= st.t0 and s.t1 <= st.t1),
+                      key=lambda s: s.t0)
+        assert [k.name for k in kids] == ["engine.launch", "engine.fetch"]
+        assert kids[0].t1 <= kids[1].t0
+        shares.append(sum(k.t1 - k.t0 for k in kids) / (st.t1 - st.t0))
+    assert max(shares) >= 0.95, shares
     # turns 5..15: admit, pack, step, emit follow each other without
     # overlap and fill the period between two admits
     admits = by["decoding.admit"]
@@ -734,7 +750,202 @@ def test_scheduler_turn_is_partitioned_by_leaf_spans():
     # what the leaves leave out of a turn is the loop's own lock check
     # and the spans' bookkeeping: some tens of microseconds, a share
     # that only a toy step of a millisecond makes visible
-    assert sum(s.t1 - s.t0 for s in leaves) >= 0.85 * (hi - lo)
+    turns = [leaves[i:i + 4] for i in range(0, 40, 4)]
+    ends = [t[0].t0 for t in turns[1:]] + [hi]
+    assert max(sum(s.t1 - s.t0 for s in t) / (end - t[0].t0)
+               for t, end in zip(turns, ends)) >= 0.85
+
+
+# ------------------------------------------------------ steps in flight
+def _run_jobs(m, jobs, **kw):
+    futs = [m.submit(p, max_new_tokens=n, **kw) for p, n in jobs]
+    return [f.result(120) for f in futs], futs
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+@pytest.mark.parametrize("depth", [1, 3])
+def test_run_ahead_gives_the_tokens_of_one_step_at_a_time(depth,
+                                                          prefix_cache):
+    """With steps kept in flight every stream is what the loop that
+    waits for each step gives: more requests than rows, budgets that
+    end mid-flight, a context that ends at capacity, shared prefixes."""
+    rng = random.Random(7)
+    head = [rng.randrange(2, CFG.vocab) for _ in range(5)]
+    jobs = [((head if i % 2 else [])
+             + [rng.randrange(2, CFG.vocab)
+                for _ in range(rng.randint(1, 7))],
+             rng.randint(1, 9)) for i in range(14)]
+    kw = dict(max_batch=3, num_pages=64, page_buckets=(1, 2, 4),
+              max_tokens=9, prefix_cache=prefix_cache,
+              merged_step=False)
+    m = _model(**kw)
+    try:
+        want, _ = _run_jobs(m, jobs)
+    finally:
+        m.close()
+    m = _model(run_ahead=depth, **kw)
+    try:
+        floor = m.engine.traces()
+        got, futs = _run_jobs(m, jobs)
+        assert got == want
+        assert any(f.finish_reason == "length" for f in futs)
+        assert m.engine.traces() == floor
+        snap = m.stats.snapshot()
+        assert snap["completed"] == len(jobs)
+        assert snap["decode_tokens"] + snap["prefills"] \
+            == sum(len(g) for g in got)
+        assert snap["pages_free"] == 63 - snap.get("prefix_cached_pages", 0)
+    finally:
+        m.close()
+
+
+def test_run_ahead_sampled_streams_and_eos():
+    """Sampled rows are (seed, position)-pure, so steps in flight draw
+    what one step at a time draws; an `eos` ends a row unannounced and
+    what was launched for it after that is dropped unread."""
+    import dataclasses
+
+    m = _model()
+    try:
+        known = m.generate([5, 6, 7], max_new_tokens=6)
+    finally:
+        m.close()
+    cfg_eos = dataclasses.replace(CFG, eos_id=known[2])
+    sp = dec.SamplingParams(temperature=0.9, top_k=8, seed=11)
+    outs = []
+    for depth in (0, 4):
+        m = dec.DecodedModel("lm-eos", 1, PARAMS, cfg_eos, max_batch=2,
+                             page_size=4, num_pages=32,
+                             page_buckets=(1, 2, 4), max_tokens=8,
+                             merged_step=False, run_ahead=depth)
+        try:
+            f1 = m.submit([5, 6, 7], max_new_tokens=8)
+            f2 = m.submit([9, 3], max_new_tokens=8, sampling=sp)
+            f3 = m.submit([4, 4, 4, 2], max_new_tokens=5)
+            outs.append([f.result(60) for f in (f1, f2, f3)])
+            assert f1.finish_reason == "eos"
+            assert outs[-1][0] == known[:2]
+            snap = m.stats.snapshot()
+            assert snap["pages_free"] \
+                == 31 - snap.get("prefix_cached_pages", 0)
+        finally:
+            m.close()
+    assert outs[0] == outs[1]
+
+
+def test_run_ahead_keeps_steps_in_flight_and_tiles_the_turns():
+    """A lone request: `run_ahead` + 1 steps are launched before the
+    first is fetched, a launch a turn after that; the `decoding.step`
+    span holds the turn's pack, launches, fetch and emit and carries
+    the attributes of the step it took out."""
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    ttrace.set_capacity(4096)
+    try:
+        m = _model(max_tokens=24, page_buckets=(1, 2, 4, 8),
+                   merged_step=False, run_ahead=3)
+        calls = []
+        launch, fetch = m.engine.launch_step, m.engine.fetch_step
+        m.engine.launch_step = lambda *a, **k: (
+            calls.append("launch"), launch(*a, **k))[1]
+        m.engine.fetch_step = lambda *a, **k: (
+            calls.append("fetch"), fetch(*a, **k))[1]
+        fut = m.submit([3, 4, 5, 6, 7], max_new_tokens=24)
+        assert len(fut.result(timeout=120)) == 24
+        m.close()
+        spans = ttrace.recent_spans()
+    finally:
+        ttrace.set_capacity(ttrace._env_capacity())
+    # 23 steps after the prefill's token: four launched, then one in
+    # and one out, then the last three taken out
+    assert calls == ["launch"] * 4 + ["fetch", "launch"] * 19 \
+        + ["fetch"] * 4
+    steps = [s for s in spans if s.name == "decoding.step"]
+    assert len(steps) == 23
+    assert [s.attrs["ctx_tokens"] for s in steps] \
+        == [5 + k + 1 for k in range(23)]
+    assert {s.attrs["program"] for s in steps} \
+        <= {m.engine.step_program(b) for b in (1, 2, 4, 8)}
+    for name in ("decoding.pack", "engine.launch", "engine.fetch",
+                 "decoding.emit"):
+        assert {s.parent for s in spans if s.name == name} \
+            == {"decoding.step"}, name
+    assert sum(s.attrs["tokens"] for s in spans
+               if s.name == "decoding.emit") == 23
+
+
+def test_run_ahead_admits_into_a_free_row_with_steps_in_flight():
+    """A request for a free row does not wait for what is launched:
+    its prefill queues behind the steps in flight, and both streams
+    are what one step at a time gives."""
+    kw = dict(max_tokens=40, page_buckets=(1, 2, 4, 8, 16),
+              merged_step=False)
+    m = _model(**kw)
+    try:
+        want = [m.generate([3, 4, 5], max_new_tokens=40),
+                m.generate([9, 8, 7, 6], max_new_tokens=12)]
+    finally:
+        m.close()
+    m = _model(run_ahead=4, **kw)
+    try:
+        in_flight = []
+        launch = m.engine.launch_prefill
+        m.engine.launch_prefill = lambda *a, **k: (
+            in_flight.append(len(m.scheduler._ahead)), launch(*a, **k))[1]
+        first = m.submit([3, 4, 5], max_new_tokens=40)
+        stream = first.stream(timeout=60)
+        head = [next(stream) for _ in range(5)]
+        second = m.submit([9, 8, 7, 6], max_new_tokens=12)
+        assert second.result(60) == want[1]
+        assert head + list(stream) == want[0]
+        # the first request found nothing launched, the second the
+        # first's steps
+        assert in_flight[0] == 0 and in_flight[1] >= 1
+    finally:
+        m.close()
+    # the steps in flight came out before the span of the prefill that
+    # waited behind them begins: the span is the prefill's own time
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    spans = [s for s in ttrace.recent_spans()
+             if (s.attrs or {}).get("model") == m.key]
+    fill = [s for s in spans if s.name == "decoding.prefill"][-1]
+    assert not [s for s in spans if s.name == "decoding.step"
+                and s.t0 < fill.t1 and s.t1 > fill.t0]
+
+
+def test_run_ahead_settles_before_a_decision():
+    """A cancellation and a deadline are acted on with nothing in
+    flight, and the pages come back."""
+    m = _model(max_tokens=48, page_buckets=(1, 2, 4, 8, 16),
+               merged_step=False, run_ahead=4)
+    try:
+        fut = m.submit([3, 4, 5], max_new_tokens=48)
+        stream = fut.stream(timeout=60)
+        got = [next(stream) for _ in range(6)]
+        stream.close()
+        late = m.submit([3, 4, 5], max_new_tokens=48, deadline_ms=1)
+        with pytest.raises(serving.DeadlineExceededError):
+            late.result(60)
+        again = m.submit([3, 4, 5], max_new_tokens=6)
+        assert again.result(60) == got
+        deadline = time.time() + 30
+        def held():
+            snap = m.stats.snapshot()
+            return 31 - snap["pages_free"] \
+                - snap.get("prefix_cached_pages", 0)
+
+        while held() and time.time() < deadline:
+            time.sleep(0.01)
+        assert held() == 0
+        assert not m.scheduler._ahead
+    finally:
+        m.close()
+
+
+def test_run_ahead_is_the_plain_steps():
+    with pytest.raises(serving.ServingError):
+        _model(run_ahead=2, draft="self", spec_k=2)
 
 
 def test_reply_span_of_a_cancelled_request_is_the_handoff_alone():

@@ -274,7 +274,7 @@ def test_fit_dispatch_is_partitioned_by_leaf_spans():
     dispatches = [s for s in spans if s.name == "fit.dispatch"]
     assert len(dispatches) == 16 and all(s.parent is None
                                          for s in dispatches)
-    waited = 0
+    waited, uncovered = 0, []
     for disp in dispatches[2:]:          # the first two compile
         kids = sorted((s for s in spans if s.name in leaves
                        and s.t0 >= disp.t0 and s.t1 <= disp.t1),
@@ -286,9 +286,16 @@ def test_fit_dispatch_is_partitioned_by_leaf_spans():
         waited += len(names) == 4
         for a, b in zip(kids, kids[1:]):
             assert a.t1 <= b.t0
-        covered = sum(k.t1 - k.t0 for k in kids)
-        assert covered >= 0.95 * (disp.t1 - disp.t0) \
-            or (disp.t1 - disp.t0) - covered < 100e-6
+        uncovered.append((disp.t1 - disp.t0)
+                         - sum(k.t1 - k.t0 for k in kids))
+    # the leaves sum to the dispatch: what they leave out is the spans'
+    # own bookkeeping, some tens of microseconds. On a loaded host the
+    # thread can lose the CPU between two leaves for milliseconds, in
+    # one dispatch or another but not in most: the MEDIAN dispatch is
+    # held to the slack, every dispatch to order and no overlap (above)
+    lengths = sorted(d.t1 - d.t0 for d in dispatches[2:])
+    assert sorted(uncovered)[len(uncovered) // 2] < max(
+        100e-6, 0.05 * lengths[len(lengths) // 2]), uncovered
     assert waited, "no dispatch had to wait for the window"
     for w in (s for s in spans if s.name == "fit.window_wait"):
         assert 0 <= w.attrs["fetch_us"] <= w.duration_us

@@ -8,8 +8,9 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
 
   blocks     free-list page allocator + per-sequence page tables with
              refcounts (prefix sharing, copy-on-write fork)
-  attention  page-table attention kernels: gather-based lax reference
-             and a scalar-prefetch Pallas flash kernel
+  attention  page-table attention kernels: a gather-based lax kernel
+             over the rows as stored (the query spread over the heads'
+             lanes) and a scalar-prefetch Pallas flash kernel
              (MXNET_DECODE_KERNEL=lax|pallas); sparse selection inside
              paged attention (an index score over every cached token,
              an exact top-k, attention over the selected rows only)

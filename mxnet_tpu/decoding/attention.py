@@ -11,9 +11,8 @@ Contract shared by both kernels:
                                side, in the minor dimension (quant.py
                                says why). The kernels read pages
                                `data[layer, page]` straight from the
-                               pool — no layer is sliced out first —
-                               and split heads only on what they have
-                               read; int8 pages dequantize INSIDE the
+                               pool — no layer is sliced out first;
+                               int8 pages dequantize INSIDE the
                                kernel. A bare float array (N, P, H*D)
                                is taken as a one-layer pool
   v_pages     quant.KVLayer    the same layer of the V pool
@@ -31,9 +30,20 @@ pages bucket and steady-state decode provably adds zero traces.
 Two implementations behind `MXNET_DECODE_KERNEL`:
 
   lax     (default) gather the Bp pages per row into a contiguous
-          (B, Bp*P, H*D) context, split its rows into heads and run
-          masked softmax attention — pure lax, runs anywhere, XLA
-          fuses the gather.
+          (B, Bp*P, H*D) context in the pool's storage type and run
+          masked softmax attention over the rows AS STORED: the query
+          is spread over the heads' lanes ((B, H, H*D), head h's
+          values on its own lanes, zeros elsewhere), scores and
+          values are one batched matmul each, and each head keeps its
+          own lanes of the result. The rows are never split into
+          (H, D): a minor dimension of head_dim 64 cannot be a bitcast
+          on the chip, and XLA made the split as a padded float32 copy
+          of the whole context, four times its bytes, written and read
+          back in every layer (95% of OPT-1.3B's decode step). Pure
+          lax, runs anywhere. The MULTI-query variant (tail prefill,
+          verify) keeps the split: its S queries share one context, so
+          the products dominate and a spread query would do H times
+          the work.
   pallas  flash-style online-softmax kernel on a (B, Bp) grid whose
           K/V block index maps read the page table via scalar
           prefetch (PrefetchScalarGridSpec) — pages stream HBM->VMEM
@@ -80,7 +90,13 @@ def _check_shapes(q, k_pages, v_pages, page_table, lengths):
 
 def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
                         scale=None):
-    """Gather-based reference kernel (see module docstring)."""
+    """Gather-based single-query kernel (see module docstring): the
+    gathered rows are attended AS STORED, (B, T, H*D) in the pool's
+    type, with the query spread over the heads' lanes. Softmax and
+    weights are float32 and the V product runs at `highest` (the
+    weights are not rounded to bf16). An int8 pool's scales, per
+    (token, head), go onto the scores (K) and onto the weights (V):
+    the arithmetic of dequantizing the rows."""
     k_pages = _quant.as_layer(k_pages)
     v_pages = _quant.as_layer(v_pages)
     b, h, d, p, bp = _check_shapes(
@@ -88,20 +104,34 @@ def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     t = bp * p
-    # (B, Bp, P, H, D) -> (B, T, H, D): pages are seq-ordered, so the
-    # flattened axis IS the token axis (positions >= length masked);
-    # gather_ctx dequantizes only the gathered pages, never the pool
-    k_ctx = _quant.gather_ctx(k_pages, page_table, h).reshape(b, t, h, d)
-    v_ctx = _quant.gather_ctx(v_pages, page_table, h).reshape(b, t, h, d)
-    s = jnp.einsum("bhd,bthd->bht", q, k_ctx,
+    highest = jax.lax.Precision.HIGHEST
+    # (B, Bp, P, H*D) -> (B, T, H*D): pages are seq-ordered, so the
+    # flattened axis IS the token axis (positions >= length masked)
+    k_rows, k_scale = _quant.gather_stored(k_pages, page_table)
+    v_rows, v_scale = _quant.gather_stored(v_pages, page_table)
+    own = (jnp.arange(h * d)[None, :] // d
+           == jnp.arange(h)[:, None])                    # (H, H*D)
+    # the products' type: the query's, or the rows' where that is
+    # wider. bf16 (and int8, exact in bf16) operands multiply exactly
+    # in one pass and sum in float32; float32 operands take every pass
+    ct = jnp.promote_types(q.dtype, k_rows.dtype)
+    q_heads = jnp.where(own, q.reshape(b, 1, h * d).astype(ct), 0)
+    s = jnp.einsum("bhc,btc->bht", q_heads, k_rows.astype(ct),
+                   precision=highest if ct == jnp.float32 else None,
                    preferred_element_type=jnp.float32) * scale
+    if k_scale is not None:
+        s = s * k_scale.transpose(0, 2, 1)
     mask = jnp.arange(t)[None, :] < lengths[:, None]
     s = jnp.where(mask[:, None, :], s, NEG_INF)
     m = s.max(axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     w = e / e.sum(axis=-1, keepdims=True)
-    out = jnp.einsum("bht,bthd->bhd", w, v_ctx,
-                     preferred_element_type=jnp.float32)
+    if v_scale is not None:
+        w = w * v_scale.transpose(0, 2, 1)
+    o = jnp.einsum("bht,btc->bhc", w, v_rows.astype(jnp.float32),
+                   precision=highest,
+                   preferred_element_type=jnp.float32)   # (B, H, H*D)
+    out = jnp.where(own, o, 0).sum(axis=1).reshape(b, h, d)
     return out.astype(q.dtype)
 
 

@@ -37,10 +37,12 @@ every layer's write.) With this order a token's write is an in-place
 scatter on the donated buffer — one row of `data`, one window of
 `heads` scales at lane `slot*heads` — a read gathers
 `data[layer, page_table]` straight from the pool, the layer an index
-of the gather and never a slice taken first, and heads are split only
+of the gather and never a slice taken first. The single-query
+attention works on the gathered rows as they are stored (the query is
+spread over the heads' lanes); only the multi-query path splits heads,
 on the gathered context. No decode-tier program holds a pool-sized or
-layer-sized temporary (tests/test_chip_compile.py reads the compiled
-text).
+layer-sized temporary, and no single-query one a float32 copy of a
+context (tests/test_chip_compile.py reads the compiled text).
 
 Quantization scheme (symmetric, zero-point-free):
 
@@ -56,9 +58,10 @@ cache and fleet affinity routing depend on. With maxabs scaling the
 round-trip error is bounded by scale/2 per element and quantizing a
 value twice is idempotent — cached pages stay byte-stable.
 
-Dequantization happens INSIDE the attention paths (the lax gather and
-the pallas kernel both upcast per page as they read), so no
-full-precision copy of the pool is ever materialized.
+Dequantization happens INSIDE the attention paths (the single-query
+kernels put the scales onto scores and weights, the multi-query lax
+gather upcasts the pages it has read), so no full-precision copy of
+the pool is ever materialized.
 
 Dtype enum (MXNET_DECODE_KV_DTYPE): float32 (default), bf16 (plain
 storage cast, no scale plane), int8 (scaled), fp8 — ACCEPTED by the
@@ -67,8 +70,9 @@ beat int8, a silicon-backlog item; selecting it raises today so the
 knob's surface is already the final one.
 
 Hot paths: `kv_scatter` runs inside every prefill/decode/verify
-program and `gather_ctx` inside every lax attention call — both are
-pure jax (listed in the mxlint HOT_PATH_MANIFEST; no blocking calls).
+program and `gather_stored` / `gather_ctx` inside every lax attention
+call — all are pure jax (listed in the mxlint HOT_PATH_MANIFEST; no
+blocking calls).
 """
 from __future__ import annotations
 
@@ -303,12 +307,15 @@ def kv_scatter(pool, layer, pages, slots, values):
 
 
 def gather_ctx(layer, page_table, heads):
-    """The lax attention paths' read: gather page_table's pages of one
-    layer straight from the pool (the layer is an index of the SAME
-    gather) and dequantize them in-flight — (B, Bp) int32 ->
-    (B, Bp, P, H, D) float32. Heads are split here, on the gathered
-    context; only the gathered pages are ever upcast, never the
-    pool."""
+    """The MULTI-query lax path's read (tail prefill, speculative
+    verify): gather page_table's pages of one layer straight from the
+    pool (the layer is an index of the SAME gather) and dequantize
+    them in-flight — (B, Bp) int32 -> (B, Bp, P, H, D) float32. Heads
+    are split here, on the gathered context; only the gathered pages
+    are ever upcast, never the pool. A head_dim under 128 cannot be a
+    bitcast on the chip, so the split is a padded float32 copy of the
+    context: S queries share it there. The single-query kernel reads
+    `gather_stored` and never splits."""
     pool, i = as_layer(layer)
     d = pool.data[i, page_table].astype(jnp.float32)
     d = d.reshape(d.shape[:-1] + (heads, d.shape[-1] // heads))
@@ -316,6 +323,23 @@ def gather_ctx(layer, page_table, heads):
         return d
     s = pool.scale[i, page_table]
     return d * s.reshape(s.shape[:-1] + (-1, heads, 1))
+
+
+def gather_stored(layer, page_table):
+    """A row's whole context of one plane AS STORED, any storage type:
+    (B, Bp) -> (rows (B, Bp*P, W) in the storage type, scales
+    (B, Bp*P, groups) float32 or None). The layer is an index of the
+    gather; nothing is upcast and no row is split. An int8 pool's
+    scales are the gathered scale rows, 1/head_dim of the context's
+    bytes: the single-query attention puts them onto its scores and
+    weights."""
+    pool, i = as_layer(layer)
+    d = pool.data[i, page_table]
+    rows = d.reshape(d.shape[0], -1, d.shape[-1])
+    if pool.scale is None:
+        return rows, None
+    s = pool.scale[i, page_table]
+    return rows, s.reshape(s.shape[0], rows.shape[1], -1)
 
 
 def gather_rows(layer, page_table, positions):
@@ -340,11 +364,10 @@ def gather_rows(layer, page_table, positions):
 def gather_plane(layer, page_table):
     """A row's whole context of one plane, as stored: (B, Bp) ->
     (B, Bp*P, W) (float pools)."""
-    pool, i = as_layer(layer)
-    if pool.scale is not None:
+    rows, scale = gather_stored(layer, page_table)
+    if scale is not None:
         raise PageError("gather_plane reads float pools only")
-    d = pool.data[i, page_table]
-    return d.reshape(d.shape[0], -1, d.shape[-1])
+    return rows
 
 
 def dequant_page(pool, layer, page):

@@ -116,26 +116,32 @@ def _on_layer(kernel, layer=1):
         q, k.layer(layer), v.layer(layer), table, lengths)
 
 
+def _entry_instructions(text):
+    """(name, result type, op, line) of each instruction of the compiled
+    ENTRY computation: what the program keeps in device memory (what a
+    fusion computes inside itself is not listed there). Parameters,
+    tuples and views of a buffer are left out."""
+    entry = text[text.index("ENTRY "):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
+                     line)
+        if m and m.group(3) not in ("parameter", "tuple",
+                                    "get-tuple-element", "bitcast"):
+            yield (*m.groups(), line)
+
+
 def _pool_sized_ops(text, pool_dims, carried=()):
     """Instructions of the compiled ENTRY computation whose result has
     the pool's or one layer's shape and is not an in-place scatter (a
     fusion of a `scatter` whose result aliases its operand): `copy`,
-    `fusion` (a relayout or a slice made whole) and the like.
-    Parameters, tuples and views of the donated buffer do not count,
-    nor do the ops named in `carried` (a `while` whose loop state holds
+    `fusion` (a relayout or a slice made whole) and the like. The ops
+    named in `carried` do not count (a `while` whose loop state holds
     the pool by reference: a copy inside it would show in the
     program's temporaries)."""
-    entry = text[text.index("ENTRY "):]
     shapes = [",".join(map(str, pool_dims)), ",".join(map(str, pool_dims[1:]))]
     found = []
-    for line in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(",
-                     line)
-        if not m:
-            continue
-        name, result, op = m.groups()
-        if op in ("parameter", "tuple", "get-tuple-element", "bitcast") \
-                or op in carried:
+    for name, result, op, line in _entry_instructions(text):
+        if op in carried:
             continue
         if not any(f"[{dims}]" in result for dims in shapes):
             continue
@@ -143,6 +149,19 @@ def _pool_sized_ops(text, pool_dims, carried=()):
                 and '"aliasing_operands"' in line:
             continue    # written in place: the result IS operand 0
         found.append(f"{name} = {result} {op}")
+    return found
+
+
+def _context_sized_arrays(text, elements, pool_elements):
+    """(name, type, op) of every ENTRY instruction whose result holds
+    an array of at least `elements` elements and less than a pool's
+    (the donated pools are larger than a context)."""
+    found = []
+    for name, result, op, _ in _entry_instructions(text):
+        for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]", result):
+            n = int(np.prod([int(x) for x in dims.split(",")]))
+            if elements <= n < pool_elements:
+                found.append((name, f"{dtype}[{dims}]", op))
     return found
 
 
@@ -176,24 +195,29 @@ CELL = dict(pages=1152, page_size=16, heads=32, head_dim=64, rows=48,
 SPEC_K = 4
 
 
-@pytest.fixture(scope="module")
-def cell_engine():
-    """A DecodeEngine whose program builders the tests below lower over
-    DESCRIBED pools: its own pool is small and its weights are absent
-    (a builder closes over neither). Donation is on, as on the chip."""
+def _cell_engine(**kw):
     from mxnet_tpu import decoding as dec
 
     c = CELL
     cfg = dec.DecoderConfig(
         vocab=512, d_model=c["heads"] * c["head_dim"],
         n_layers=c["layers"], n_heads=c["heads"], d_ff=512, max_len=2048)
+    if kw.pop("draft", False):
+        kw.update(draft_params={}, draft_cfg=cfg, spec_k=SPEC_K)
     eng = dec.DecodeEngine(
         {}, cfg, max_batch=c["rows"], page_size=c["page_size"],
         num_pages=c["bucket"] + 1, page_buckets=(c["bucket"],),
-        kernel="lax", prefix_cache=True, merged_step=False,
-        draft_params={}, draft_cfg=cfg, spec_k=SPEC_K)
+        kernel="lax", prefix_cache=True, **kw)
     eng._donate = True
     return eng
+
+
+@pytest.fixture(scope="module")
+def cell_engine():
+    """A DecodeEngine whose program builders the tests below lower over
+    DESCRIBED pools: its own pool is small and its weights are absent
+    (a builder closes over neither). Donation is on, as on the chip."""
+    return _cell_engine(merged_step=False, draft=True)
 
 
 def _decoder_param_structs(cfg):
@@ -217,7 +241,7 @@ def _cell_program(eng, program, kv_dtype, pages):
     params = _decoder_param_structs(eng.cfg)
     scalar = [_s((), jnp.uint32), _s((), jnp.float32), _s((), jnp.int32),
               _s((), jnp.float32)]
-    r = c["rows"]
+    r = eng.step_rows
     row = [_s((r,), jnp.uint32), _s((r,), jnp.float32), _s((r,), jnp.int32),
            _s((r,), jnp.float32)]
     rows_in = (_s((r, c["bucket"]), jnp.int32), _s((r,), jnp.int32),
@@ -249,6 +273,16 @@ def _cell_program(eng, program, kv_dtype, pages):
     return getattr(fn, "fn", fn), args, pool
 
 
+def _compile_cell_program(v5e, eng, program, kv_dtype, pages):
+    """(compiled program, one pool's struct): `_cell_program` compiled
+    for the described chip."""
+    fn, args, pool = _cell_program(eng, program, kv_dtype, pages)
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        args)
+    return fn.lower(*placed).compile(), pool
+
+
 @pytest.mark.parametrize("program,kv_dtype", [
     (program, kv_dtype)
     for program in ("decode", "prefill", "prefill_tail")
@@ -262,11 +296,8 @@ def test_engine_program_holds_no_pool_sized_copy(v5e, cell_engine, program,
     grow with the pool."""
     temps = []
     for pages in (CELL["pages"], 2 * CELL["pages"]):
-        fn, args, pool = _cell_program(cell_engine, program, kv_dtype, pages)
-        placed = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            args)
-        compiled = fn.lower(*placed).compile()
+        compiled, pool = _compile_cell_program(v5e, cell_engine, program,
+                                               kv_dtype, pages)
         temps.append(compiled.memory_analysis().temp_size_in_bytes)
         if pages == CELL["pages"]:
             text = compiled.as_text()
@@ -275,6 +306,38 @@ def test_engine_program_holds_no_pool_sized_copy(v5e, cell_engine, program,
     # twice the pool: what is left (the gathered contexts) depends on
     # rows and bucket only
     assert temps[1] - temps[0] <= 0.05 * temps[0], temps
+
+
+@pytest.mark.parametrize("program,kv_dtype", [
+    ("decode", "bf16"), ("decode", "float32"), ("decode", "int8"),
+    ("draft", "bf16"), ("merged", "bf16")])
+def test_single_query_program_attends_the_rows_as_stored(
+        v5e, cell_engine, program, kv_dtype):
+    """The single-query programs never split a gathered context into
+    heads: nothing of the context's size (rows x bucket x page_size x
+    heads x head_dim elements) is float32 unless the pool is, nothing
+    of that size is a `reshape`, `copy` or `transpose`, and the bf16
+    program's temporaries are the gathered rows alone. `merged` is the
+    ragged step's decode program: a page of tail rows beside the
+    decode rows, each ONE query over its own context."""
+    c = CELL
+    eng = cell_engine
+    if program == "merged":
+        eng, program = _cell_engine(merged_step=True), "decode"
+    context = (eng.step_rows * c["bucket"] * c["page_size"]
+               * c["heads"] * c["head_dim"])
+    compiled, pool = _compile_cell_program(v5e, eng, program,
+                                           kv_dtype, c["pages"])
+    arrays = _context_sized_arrays(compiled.as_text(), context,
+                                   pool.data.size)
+    assert arrays, "the gathered rows themselves should be listed"
+    if kv_dtype != "float32":
+        assert not [a for a in arrays if a[1].startswith("f32")], arrays
+    assert not [a for a in arrays
+                if a[2] in ("reshape", "copy", "transpose")], arrays
+    if kv_dtype == "bf16":
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= 0.25 * 2 ** 30, temp
 
 
 # the sparse latent block at its published attention widths (a 576-wide

@@ -209,6 +209,72 @@ def test_paged_attention_kernels_match_dense():
         np.testing.assert_allclose(out_pls[row], ref, atol=1e-5)
 
 
+@pytest.mark.parametrize("kv_dtype,q_dtype,atol", [
+    ("float32", "float32", 1e-5), ("bf16", "float32", 1e-5),
+    ("int8", "float32", 1e-5),
+    # the served case: bf16 activations, one-pass products, a bf16 result
+    ("bf16", "bfloat16", 2e-2), ("int8", "bfloat16", 2e-2)])
+def test_single_query_lax_attends_stored_rows(kv_dtype, q_dtype, atol):
+    """`paged_attention_lax` works on the gathered rows as the pool
+    stores them (no split into heads) for every storage type. Oracle: a
+    plain dense softmax attention per row over the values the pool
+    holds, no pool and no pages. Ragged lengths: one token, a full
+    bucket, a half-filled last page, padding entries on the scratch
+    page. EVERY slot no row may read is poisoned — the scratch page
+    and each page's slots past its row's length: K rows (an int8
+    pool's K scales) NaN, V rows and scales 1e30 (a weight of exactly 0
+    times 1e30 is 0; times NaN it would be NaN in any kernel) — so a
+    masked row read into a result shows. The Pallas kernel, interpreted
+    here, has to agree on the same pools."""
+    from mxnet_tpu.decoding import quant
+
+    rs = np.random.RandomState(11)
+    b, h, d, p, bp, n = 4, 4, 8, 4, 3, 16
+    lengths = np.asarray([1, bp * p, 5, 9], np.int32)
+    q = jnp.asarray(rs.randn(b, h, d), q_dtype)
+    table = np.zeros((b, bp), np.int32)            # padding: scratch page 0
+    free = iter(rs.permutation(np.arange(1, n)))
+    held = {"k": [], "v": []}
+    pools = {}
+    for name, poison in (("k", np.nan), ("v", 1e30)):
+        pool = quant.make_pool((1, n, p, h, d), kv_dtype)
+        data = jnp.full_like(pool.data, 127 if kv_dtype == "int8"
+                             else poison)
+        scale = None if pool.scale is None \
+            else jnp.full_like(pool.scale, poison)
+        pools[name] = quant.KVPool(data, scale)
+    for row, ln in enumerate(lengths):
+        pages = [int(next(free)) for _ in range(pages_needed(ln, p))]
+        table[row, :len(pages)] = pages
+        at = np.arange(ln)
+        for name in ("k", "v"):
+            vals = jnp.asarray(rs.randn(ln, h, d), jnp.float32)
+            pools[name], _ = quant.kv_scatter(
+                pools[name], 0, np.asarray(pages)[at // p], at % p, vals)
+            if kv_dtype == "int8":
+                qv, sc, _ = quant.quantize_values(vals)
+                vals = quant.dequantize_values(qv, sc)
+            elif kv_dtype == "bf16":
+                vals = vals.astype(jnp.bfloat16)
+            held[name].append(np.asarray(vals, np.float64))
+
+    layers = pools["k"].layer(0), pools["v"].layer(0)
+    out = np.asarray(dec.paged_attention_lax(
+        q, *layers, table, lengths), np.float64)
+    assert np.isfinite(out).all()
+    q64 = np.asarray(q, np.float64)
+    for row in range(b):
+        sc = np.einsum("hd,thd->ht", q64[row], held["k"][row]) / np.sqrt(d)
+        e = np.exp(sc - sc.max(axis=-1, keepdims=True))
+        ref = np.einsum("ht,thd->hd", e / e.sum(axis=-1, keepdims=True),
+                        held["v"][row])
+        np.testing.assert_allclose(out[row], ref, atol=atol,
+                                   err_msg=f"row {row}")
+    out_pls = np.asarray(dec.paged_attention_pallas(
+        q, *layers, table, lengths), np.float64)
+    np.testing.assert_allclose(out, out_pls, atol=atol)
+
+
 @pytest.mark.parametrize("kv_dtype", ["float32", "bf16", "int8"])
 @pytest.mark.parametrize("kernel", ["lax", "pallas"])
 def test_engine_tokens_match_dense_reference(kernel, kv_dtype):

@@ -3,20 +3,20 @@
 # native builds, test tiers, docs generation, and deploy bundle).
 #
 # Every test/check target runs on the CPU backend (JAX_PLATFORMS=cpu).
-# `bench` and `chip-smoke` need a TPU and fail without one; from a
-# sandbox without a chip, run them through the chip tool (README
-# "Testing").
+# `chip-smoke` needs a TPU and fails without one; from a sandbox
+# without a chip, run it through the chip tool (README "Testing").
+# Speed is measured by perfbench/run.py on the chip (PERF.md).
 
 PY      ?= python
 CPUENV  := JAX_PLATFORMS=cpu
 XLA8    := XLA_FLAGS=--xla_force_host_platform_device_count=8
 
 .PHONY: all test nightly examples lint lint-check libs predict perl \
-	docs dryrun bench chip-smoke cache-check serving-check sync-check data-check \
+	docs dryrun chip-smoke cache-check serving-check sync-check data-check \
 	passes-check telemetry-check decode-check race-check \
-	effects-check fusion-check \
+	effects-check \
 	shard-check profiling-check numerics-check coldstart-check \
-	fleet-check quant-check elastic-check bench-diff clean
+	fleet-check quant-check elastic-check clean
 
 all: libs test
 
@@ -76,7 +76,8 @@ docs:
 cache-check:
 	$(CPUENV) bash ci/check_exec_cache.sh
 
-# serving tier: test suite + dynamic-batching >=2x / zero-retrace gate
+# serving tier: the test suite (bucketing, flush policy, backpressure,
+# deadlines, zero-retrace steady state)
 serving-check:
 	$(CPUENV) bash ci/check_serving.sh
 
@@ -107,20 +108,9 @@ telemetry-check:
 # decode tier: test suite + runtime gates (zero retraces over a
 # >=64-step continuous decode with mid-stream admission/eviction/
 # preemption, greedy parity vs an unbatched reference loop, page-pool
-# exhaustion preempts instead of crashing) + paged-vs-rectangular
-# KV-memory bench gate
+# exhaustion preempts instead of crashing)
 decode-check:
 	$(CPUENV) bash ci/check_decode.sh
-
-# generated-kernel codegen gate: test suite + runtime gates (every
-# __fusion_group__ lowers with an interpret-mode parity proof or a
-# counted fallback reason — no silent drops; fused vs fallback
-# programs key separately in the exec cache; kind="kernel"
-# calibration records back the tuner's fuse-vs-fallback call; the
-# merged ragged step drops the tail-prefill programs from the warmup
-# grid at token parity with zero retraces)
-fusion-check:
-	$(CPUENV) bash ci/check_fusion.sh
 
 # effects + protocol gate: MX010-MX013 clean tree with no baseline,
 # then one seeded violation per rule (jit impurity, use-after-donate,
@@ -140,8 +130,8 @@ race-check:
 # sharding tier: test suite + runtime gates (bitwise training parity
 # across unsharded / dp-only / dp*tp*fsdp plans on exact arithmetic,
 # fsdp per-device storage <= 1/2 replicated, zero steady-state
-# retraces, pre-trace rejection of non-dividing explicit specs) +
-# storage/step-time bench gate on 8 virtual devices
+# retraces, pre-trace rejection of non-dividing explicit specs) on 8
+# virtual devices
 shard-check:
 	$(CPUENV) $(XLA8) bash ci/check_sharding.sh
 
@@ -155,7 +145,7 @@ profiling-check:
 # numerics tier: test suite + runtime gates (injected NaN detected at
 # the seeded step within one drain interval, attributed to the op fed
 # by the poisoned parameter, durable flight record, host-sync budget
-# unchanged with numerics on) + paired A/B overhead bench gate
+# unchanged with numerics on)
 numerics-check:
 	$(CPUENV) bash ci/check_numerics.sh
 
@@ -168,8 +158,7 @@ coldstart-check:
 
 # fleet tier: control-plane test suite, then the three-replica
 # runtime gate (one bundle -> 0 traces/0 compiles per replica;
-# SIGKILL + graceful drain both zero-loss and bit-identical) and the
-# affinity-vs-random routing bench A/B
+# SIGKILL + graceful drain both zero-loss and bit-identical)
 fleet-check:
 	$(CPUENV) bash ci/check_fleet.sh
 
@@ -186,23 +175,12 @@ quant-check:
 # fault injector, survivor finishes bitwise equal to the
 # uninterrupted reference with every example consumed exactly once;
 # 1→2 re-grow at zero example loss and zero steady-state retraces)
-# and the transition-cost bench
 elastic-check:
 	$(CPUENV) bash ci/check_elastic.sh
-
-# regression diff of two bench captures (nonzero exit on >10% drops):
-#   make bench-diff OLD=BENCH_r04.json NEW=BENCH_r05.json
-bench-diff:
-	$(PY) tools/benchdiff.py $(OLD) $(NEW)
 
 # multi-chip sharding dryrun (DP / SP+TP / PP / EP) on 8 virtual devices
 dryrun:
 	$(PY) __graft_entry__.py
-
-# needs a TPU (exits non-zero without one; BENCH_PLATFORM=cpu is the
-# tiny CPU dry-run the ci/ gates use)
-bench:
-	$(PY) bench.py
 
 # the quickest proof the train and decode paths start on the chip: one
 # process, one chip; `make chip-smoke ARGS="--chips 4"` on a four-chip

@@ -38,10 +38,8 @@ import argparse
 import gc
 import json
 import math
-import os
 import random
 import sys
-import tempfile
 import time
 import traceback
 
@@ -56,7 +54,6 @@ SIZES = {
                    new=(4, 24)),
         probe=dict(prompt=40, new=16, page_buckets=(8,)),
         flash=(4, 2048, 16, 128),
-        codegen_shape=(2048, 8192),
         transformer=dict(d_model=2048, num_heads=16, d_ff=8192,
                          num_layers=4, seq=2048, batch=8),
     ),
@@ -70,7 +67,6 @@ SIZES = {
                    new=(2, 8)),
         probe=dict(prompt=6, new=8, page_buckets=(4,)),
         flash=(2, 64, 2, 16),
-        codegen_shape=(16, 256),
         transformer=dict(d_model=32, num_heads=4, d_ff=64, num_layers=2,
                          seq=16, batch=8),
     ),
@@ -226,7 +222,7 @@ def phase_train(size, seed):
     import jax
 
     import mxnet_tpu as mx
-    from mxnet_tpu import exec_cache, passes, profiling
+    from mxnet_tpu import exec_cache, profiling
 
     cfg, steps = size["resnet"], size["train_steps"]
     dev = mx.tpu().jax_device()
@@ -246,8 +242,7 @@ def phase_train(size, seed):
     del mod, fused
     gc.collect()
 
-    # what bench.py makes the default on an accelerator: k steps per
-    # dispatch through the compiled lax.scan loop
+    # k steps per dispatch through the compiled lax.scan loop
     k = STEPS_PER_DISPATCH
     mod, k_losses = _resnet_fit(cfg, seed, mx.tpu(), steps,
                                 steps_per_dispatch=k)
@@ -260,9 +255,6 @@ def phase_train(size, seed):
         "k_fit_ended_in_fetched_finite_params": _params_finite(mod),
     })
     del mod
-    fusion = passes.fusion_stats()
-    checks["no_kernel_compile_refused"] = \
-        "compile_refused" not in fusion["fallback_reasons"]
     cache = exec_cache.cache_stats()
     return {
         "checks": checks,
@@ -275,8 +267,6 @@ def phase_train(size, seed):
         "exec_cache": {n: cache[n] for n in ("hits", "misses", "traces")},
         "compiles": profiling.device_stats().get("totals", {})
         .get("compiles"),
-        "codegen": {n: fusion[n] for n in (
-            "groups_seen", "groups_lowered", "fallback_reasons")},
         "fence": fence,
         "default_backend": jax.default_backend(),
     }
@@ -493,74 +483,6 @@ def _kernel_paged(dcfg, spec, kv_dtype, seed):
                 "tpu_custom_call" in _compiled_text(pallas, *args)}
 
 
-def _codegen_nets():
-    """One fusion group per codegen template, of exactly-rounded ops
-    (a kernel and its lax twin may differ in transcendentals' last
-    bits; these may not)."""
-    import mxnet_tpu as mx
-
-    x, y, z = (mx.sym.Variable(n) for n in "xyz")
-    return {
-        "elementwise": mx.sym.abs(mx.sym.square(x) - y),
-        "scale_bias_act": mx.sym.Activation(
-            mx.sym.elemwise_add(mx.sym.elemwise_mul(x, y), z),
-            act_type="relu"),
-        "reduction": mx.sym.sum(mx.sym.relu(x) * y),
-    }
-
-
-def _kernel_codegen(shape, seed):
-    """Each surviving template, bound through the executor so the
-    codegen stage itself builds, compiles, verifies and routes the
-    kernel; the output is compared with the stage switched off (the
-    composed-lax path). The stage's own timings of kernel and twin
-    (one warm call each) are reported beside it."""
-    import jax
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import exec_cache, passes
-    from mxnet_tpu.profiling import calibration_store
-
-    rs = np.random.RandomState(seed)
-    out = {}
-    for template, net in _codegen_nets().items():
-        names = net.list_arguments()
-        vals = {n: rs.uniform(-1, 1, shape).astype("float32")
-                for n in names}
-        results = {}
-        for codegen in ("0", "1"):
-            os.environ["MXNET_FUSION_CODEGEN"] = codegen
-            exec_cache.clear()
-            passes.clear_memo()
-            passes.reset_fusion_stats()
-            exe = net.simple_bind(mx.tpu(), **{n: shape for n in names})
-            exe.forward(is_train=False,
-                        **{n: mx.nd.array(v, ctx=mx.tpu())
-                           for n, v in vals.items()})
-            results[codegen] = exe.outputs[0].asnumpy()
-        os.environ.pop("MXNET_FUSION_CODEGEN")
-        stats = passes.fusion_stats()
-        (digest,) = passes.fusion_group_records()
-        store, platform = calibration_store(), jax.default_backend()
-        err = _max_err(results["1"], results["0"])
-        scale = float(np.max(np.abs(results["0"]))) or 1.0
-        out[template] = {
-            "shape": list(shape), "max_err": err,
-            "lowered": stats["templates"].get(template, 0),
-            "fallback_reasons": stats["fallback_reasons"],
-            "parity_failures": stats["parity_failures"],
-            "kernel_call_s":
-                store.measured_seconds(digest, platform, "kernel"),
-            "lax_call_s":
-                store.measured_seconds(digest, platform, "kernel_lax"),
-            "ok": (stats["templates"].get(template, 0) == 1
-                   and not stats["fallback_reasons"]
-                   and err <= 1e-5 * scale),
-        }
-    return out
-
-
 def _kernel_rtc():
     import numpy as np
 
@@ -583,32 +505,6 @@ def phase_kernels(size, seed, on_tpu):
     for kv in ("float32", "int8"):
         res[f"paged_{kv}"] = _kernel_paged(
             size["decoder"], size["serve"], kv, seed)
-    # off the TPU the codegen stage takes its lax fallback unless the
-    # interpreter is forced; forcing it changes nothing there (every
-    # Pallas kernel is interpreted off the TPU) but lets the rehearsal
-    # walk the same build-verify-route path
-    force = not on_tpu
-    if force:
-        os.environ["MXNET_FUSION_INTERPRET"] = "1"
-    # a calibration table of this sub-phase's own: a record left by an
-    # earlier run may demote a group to its twin (`calibrated_slower`)
-    # before it is ever built here, and these toy groups' timings have
-    # no place in the user's table
-    table = os.environ.get("MXNET_CALIBRATION_CACHE")
-    try:
-        with tempfile.TemporaryDirectory() as tmp:
-            os.environ["MXNET_CALIBRATION_CACHE"] = os.path.join(
-                tmp, "calibration.json")
-            for template, rec in _kernel_codegen(
-                    size["codegen_shape"], seed).items():
-                res[f"codegen_{template}"] = rec
-    finally:
-        if table is None:
-            os.environ.pop("MXNET_CALIBRATION_CACHE")
-        else:
-            os.environ["MXNET_CALIBRATION_CACHE"] = table
-        if force:
-            os.environ.pop("MXNET_FUSION_INTERPRET")
     res["rtc_pallas_kernel"] = _kernel_rtc()
     checks = {name: bool(rec["ok"]) for name, rec in res.items()}
     checks["compiled_not_interpreted"] = (

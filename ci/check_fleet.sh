@@ -13,9 +13,10 @@
 #    SIGKILL mid-stream and graceful drain both finish every request
 #    with zero failures and token streams bit-identical to an
 #    uninterrupted single-process reference.
-# 3. Benchmark gate: BENCH_MODE=fleet runs the affinity-vs-random
-#    routing A/B; affinity must strictly win on fleet-wide prefix
-#    hit rate AND on total KV pages allocated for the same traffic.
+#
+# test_affinity_routing_beats_random_on_hits_and_pages holds the
+# routing A/B: affinity must strictly win on fleet-wide prefix hit
+# rate AND on total KV pages allocated for the same traffic.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -27,24 +28,3 @@ export MXNET_EXEC_CACHE_DIR=
 python -m pytest tests/test_fleet.py -q -p no:cacheprovider
 
 python ci/check_fleet.py
-
-out=$(BENCH_MODE=fleet BENCH_PLATFORM=cpu python bench.py)
-echo "$out"
-RECORD="$out" python - <<'EOF'
-import json, os
-rec = json.loads(os.environ["RECORD"].strip().splitlines()[-1])
-assert rec.get("unit") == "hit_rate", rec
-aff, rnd = rec["fleet_prefix_hit_rate"], \
-    rec["fleet_prefix_hit_rate_random"]
-assert aff > rnd, (
-    f"affinity routing does not beat random on fleet-wide prefix "
-    f"hit rate: {aff} vs {rnd}")
-pages, pages_rnd = rec["fleet_pages_allocated"], \
-    rec["fleet_pages_allocated_random"]
-assert pages < pages_rnd, (
-    f"affinity routing does not beat random on total pages "
-    f"allocated: {pages} vs {pages_rnd}")
-print(f"fleet bench OK: hit rate {aff} vs {rnd} random, "
-      f"{pages} vs {pages_rnd} pages, advantage "
-      f"{rec['fleet_affinity_advantage']}")
-EOF

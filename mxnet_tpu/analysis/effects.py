@@ -66,12 +66,6 @@ JIT_ENTRY_MANIFEST = {
     # effect or ambient read here varies across workers
     "mxnet_tpu/elastic/trainer.py": ("ElasticSGD.update",
                                      "combine_grads"),
-    # generated-kernel lax twins: composed into custom_vjp bodies and
-    # traced inside every fused program
-    "mxnet_tpu/passes/pallas_codegen.py": (
-        "_compose_lax", "_elementwise_lax", "_scale_bias_act_lax",
-        "_reduction_lax",
-    ),
 }
 
 #: sanctioned trace-time effects: functions whose ONLY job is a

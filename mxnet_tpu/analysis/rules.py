@@ -17,7 +17,7 @@ backend):
          raw Lock.acquire)
   MX005  nondeterminism: global-RNG draws outside mxnet_tpu.random,
          wall-clock in cache keys
-  MX009  raw pl.pallas_call outside the codegen entry points, or an
+  MX009  raw pl.pallas_call outside the kernel entry points, or an
          allowlisted kernel module missing its lax fallback twin
 
 Every rule is a pure function over one parsed file (`FileContext`);
@@ -601,18 +601,14 @@ def check_mx005(ctx):
 # --------------------------------------------------------------------------
 # MX009 — pallas_call outside the sanctioned kernel entry points
 # --------------------------------------------------------------------------
-# Generated kernels flow through ONE pass (passes/pallas_codegen.py),
-# which guarantees every kernel a lax twin: build-time interpret parity,
-# a counted runtime fallback, and calibration records. A raw
-# pl.pallas_call anywhere else reintroduces exactly the hand-rolled,
-# unverified kernel the codegen tier exists to retire. The two
-# attention modules predate the pass and carry their own reference
-# implementations, so they are allowlisted — but even there the rule
-# demands visible fallback evidence (a module-level def whose name
-# says "lax"/"reference", or a kernel-registry dict with a "lax" key),
-# so the escape hatch never silently loses its escape.
+# A Pallas kernel lives in one of the two attention modules, each of
+# which carries its own reference implementation: a raw pl.pallas_call
+# anywhere else is a hand-rolled kernel with nothing to be compared
+# with and nothing to fall back to. Even in the allowlisted modules
+# the rule demands visible fallback evidence (a module-level def whose
+# name says "lax"/"reference", or a kernel-registry dict with a "lax"
+# key), so the escape hatch never silently loses its escape.
 _MX009_ALLOWED = {
-    "mxnet_tpu/passes/pallas_codegen.py",
     "mxnet_tpu/decoding/attention.py",
     "mxnet_tpu/parallel/attention.py",
 }
@@ -651,12 +647,11 @@ def check_mx009(ctx):
         for node in calls:
             findings.append(RawFinding(
                 "MX009", node.lineno, node.col_offset,
-                "raw `pl.pallas_call` outside the codegen entry points "
-                "(passes/pallas_codegen.py, decoding/attention.py, "
-                "parallel/attention.py): hand-rolled kernels skip the "
-                "build-time parity proof, the counted lax fallback, and "
-                "calibration — emit through passes.pallas_codegen, or "
-                "add the file to the allowlist WITH a lax twin"))
+                "raw `pl.pallas_call` outside the kernel entry points "
+                "(decoding/attention.py, parallel/attention.py): a "
+                "hand-rolled kernel with no lax twin has nothing to be "
+                "compared with or to fall back to — add the file to "
+                "the allowlist WITH a lax twin"))
     elif not _mx009_has_fallback(ctx.tree):
         for node in calls:
             findings.append(RawFinding(
@@ -676,7 +671,7 @@ ALL_RULES = {
     "MX003": (check_mx003, "unregistered MXNET_* environment read"),
     "MX004": (check_mx004, "concurrency hygiene"),
     "MX005": (check_mx005, "nondeterministic draw / wall-clock key"),
-    "MX009": (check_mx009, "pallas_call outside codegen entry points"),
+    "MX009": (check_mx009, "pallas_call outside kernel entry points"),
 }
 
 #: project-scope rules — computed once over the whole tree by

@@ -50,10 +50,10 @@ Two implementations behind `MXNET_DECODE_KERNEL`:
           per grid step instead of materializing the gathered
           context. Compiled on a TPU, interpreted elsewhere.
 
-The knob is read through `passes.codegen_config()` (one switch
-surface with the MXNET_FUSION_* kernel-generation flags); the
-`ragged_paged_attention_*` entries below serve MIXED prefill+decode
-batches for the merged-step engine (MXNET_DECODE_MERGED_STEP).
+The switch is read by `decoding.config.kernel()`. The
+`ragged_paged_attention_*` entries below serve MIXED
+prefill+decode batches for the merged-step engine
+(MXNET_DECODE_MERGED_STEP).
 """
 from __future__ import annotations
 

@@ -28,11 +28,7 @@ def page_buckets():
 
 
 def kernel():
-    # read through the codegen config: MXNET_DECODE_KERNEL is part of
-    # the one kernel-generation switch surface (passes.pallas_codegen)
-    from ..passes import codegen_config
-
-    return codegen_config().decode_kernel
+    return str(utils.getenv("MXNET_DECODE_KERNEL"))
 
 
 def merged_step():

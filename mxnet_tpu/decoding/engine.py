@@ -313,8 +313,8 @@ class DecodeEngine:
         st = self.allocator.stats()
         # measured bytes per pooled token position over every plane
         # (scale planes included): the float32/int8 ratio of this
-        # number is the capacity multiplier BENCH_MODE=decode and
-        # quant-check report
+        # number is the capacity multiplier ci/check_quant.py
+        # reports
         per_tok = sum(_quant.kv_bytes_per_token(p) for p in self._pools)
         return {
             "pages_total": st["pages_total"],
@@ -931,8 +931,8 @@ def quant_parity_probe(params, cfg, prompt, max_new=16, *,
     BOTH engines token by token, so every step compares the two
     precisions over IDENTICAL context (a free-running comparison
     would stop counting at the first divergence, understating
-    agreement). The drift/agreement oracle behind BENCH_MODE=decode's
-    quantization keys, ci/check_quant.py, and tests/test_quant.py.
+    agreement). The drift/agreement oracle behind ci/check_quant.py
+    and tests/test_quant.py.
 
     Returns a dict: `top1_agreement` (fraction of positions where the
     quantized argmax matches float32's), `logit_drift_max` /

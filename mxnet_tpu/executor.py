@@ -133,18 +133,6 @@ class Executor:
         graph_key = (raw_key if self._opt_symbol is self._symbol
                      else self._opt_symbol.structure_key())
 
-        # codegen lowering of the __fusion_group__ stamps: per-group
-        # generated-kernel-or-fallback decisions for THIS bind's
-        # shapes/platform (passes.pallas_codegen). The plan's
-        # cache_component joins the key below, so a program traced
-        # with a group fused can never be replayed for a bind where
-        # that group fell back (and vice versa).
-        self._codegen_plan = _passes.plan_for(
-            self._opt_symbol,
-            input_shapes={n: tuple(a.shape)
-                          for n, a in {**self.arg_dict,
-                                       **self.aux_dict}.items()})
-
         mirror = _os.environ.get(
             "MXNET_BACKWARD_DO_MIRROR", "0") not in ("0", "", "false")
         self._cache_key = (
@@ -162,7 +150,6 @@ class Executor:
             tuple(self._grad_names),
             (self._sharding_plan.digest()
              if self._sharding_plan is not None else None),
-            self._codegen_plan.cache_component,
             mirror,
         )
         # HBM pre-flight BEFORE any program is looked up or traced:
@@ -263,21 +250,6 @@ class Executor:
         }
         aux_set = set(self._aux_names)
 
-        # fused-group routing (passes.pallas_codegen.plan_for): the
-        # plan's node indices are positions in the same _topo order as
-        # `nodes`, translated here to object ids. The per-op plan stays
-        # COMPLETE — it is the lax fallback (and what the monitored /
-        # per-op debug path iterates); only run_graph skips the interior
-        # of a fused group and calls the generated kernel at its output.
-        cg = getattr(self, "_codegen_plan", None)
-        fused_skip = frozenset(
-            id(nodes[i]) for i in cg.skip) if cg else frozenset()
-        fused_call = {
-            id(nodes[i]): (fn, tuple((id(nodes[s]), oi)
-                                     for s, oi in ext))
-            for i, (fn, ext) in (cg.fused.items() if cg else ())
-        }
-
         def run_graph(arg_vals, aux_vals, rng, is_train):
             _exec_cache.note_graph_replay()
             env = {}
@@ -288,17 +260,6 @@ class Executor:
             aux_updates = {}
             for (opdef, params, n_out, in_keys, nid, node_idx, nname,
                  dev) in plan:
-                if nid in fused_skip:
-                    continue
-                if nid in fused_call:
-                    ffn, ext_keys = fused_call[nid]
-                    ext_vals = [env[k] for k in ext_keys]
-                    if dev is not None:
-                        ext_vals = [jax.device_put(v, dev)
-                                    for v in ext_vals]
-                    with jax.named_scope(nname):
-                        env[(nid, 0)] = ffn(*ext_vals)
-                    continue
                 in_vals = [env[k] for k in in_keys]
                 if dev is not None:
                     in_vals = [

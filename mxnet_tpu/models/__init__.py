@@ -2,8 +2,8 @@
 (reference example/image-classification/symbol_*.py, example/rnn/).
 
 Each builder returns a Symbol ending in SoftmaxOutput, ready for
-Module.fit. ResNet is the flagship/benchmark model (BASELINE.md
-headline: ResNet-50 throughput + MFU).
+Module.fit. ResNet is the flagship/benchmark model (the benchmark's
+ResNet-50 cells: PERF.md section 4).
 """
 from .mlp import get_mlp
 from .lenet import get_lenet

@@ -879,7 +879,7 @@ class FusedTrainStep:
         """FLOPs of one compiled train step, from XLA cost analysis.
 
         When only a multi-step loop was compiled (run_steps-only use,
-        e.g. BENCH_MULTISTEP), per-step work is estimated from the
+        steps_per_dispatch > 1), per-step work is estimated from the
         k-loop program. XLA cost analysis counts a while/scan body ONCE
         regardless of trip count, so the k-loop program's reported cost
         is (scan body) + (the one peeled final step) ~= 2x one step for

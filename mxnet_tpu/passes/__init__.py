@@ -16,17 +16,8 @@ from __future__ import annotations
 
 import hashlib
 
-from . import cost_model, ir, pallas_codegen, transforms, tuner  # noqa: F401
+from . import cost_model, ir, transforms, tuner  # noqa: F401
 from .ir import Graph, GraphNode  # noqa: F401
-from .pallas_codegen import (  # noqa: F401
-    CodegenConfig,
-    CodegenPlan,
-    codegen_config,
-    fusion_group_records,
-    fusion_stats,
-    plan_for,
-    reset_fusion_stats,
-)
 from .manager import (  # noqa: F401
     PassManager,
     clear_memo,

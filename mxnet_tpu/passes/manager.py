@@ -29,7 +29,6 @@ from collections import OrderedDict
 
 from ..base import MXNetError
 from ..telemetry import register_view as _register_view
-from . import pallas_codegen as _pc
 from . import transforms as _t
 from .ir import Graph
 
@@ -62,8 +61,6 @@ register_pass("fold", _t.fold)
 register_pass("cse", _t.cse)
 register_pass("layout", _t.layout_nhwc, default_on=False)
 register_pass("canonicalize", _t.canonicalize)
-register_pass("fusion_hints", _t.fusion_hints)
-register_pass("pallas_codegen", _pc.pallas_codegen)
 
 
 def default_pipeline():
@@ -85,8 +82,6 @@ def _zero_stats():
         "cse_hits": 0,
         "layout_rewrites": 0,
         "canonical_rewrites": 0,
-        "fusion_groups": 0,
-        "fusion_lowered": 0,
         "verify_failures": 0,
         "pass_time_us": {},
     }
@@ -101,8 +96,6 @@ _PASS_COUNTERS = {
     "cse": "cse_hits",
     "layout": "layout_rewrites",
     "canonicalize": "canonical_rewrites",
-    "fusion_hints": "fusion_groups",
-    "pallas_codegen": "fusion_lowered",
 }
 
 

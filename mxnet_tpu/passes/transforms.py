@@ -18,16 +18,6 @@ around. Pipeline order (manager.DEFAULT_PIPELINE):
                params, dense renaming of auto-named nodes — runs LAST
                of the structural passes so names reflect the final
                graph (and a second pipeline run is a no-op)
-  fusion_hints annotate single-consumer elementwise chains with
-               `__fusion_group__` (advisory: surfaced to profiling
-               and consumed by the codegen stage below)
-  pallas_codegen
-               absorb eligible trailing reductions into their chains
-               and stamp each group `candidate:<digest>` or
-               `fallback:<reason>` — the lowering verdict
-               `plan_for`/Executor turn into generated Pallas kernels
-               (pallas_codegen.py; docs/passes.md "From hints to
-               kernels")
 
 Invariants every pass preserves: variable nodes are never renamed,
 created, or merged away (binding is by-name against the ORIGINAL
@@ -40,23 +30,6 @@ from __future__ import annotations
 import re
 
 from ..base import MXNetError
-
-# Elementwise (shape-preserving, pointwise) ops for fusion grouping.
-# Canonical registry names only — `canonicalize` rewrites aliases first,
-# and `fusion_hints` resolves through the registry anyway.
-ELEMWISE_OPS = frozenset({
-    "relu", "sigmoid", "tanh", "exp", "log", "log1p", "expm1", "sqrt",
-    "rsqrt", "square", "abs", "sign", "negative", "reciprocal",
-    "softsign", "erf", "identity", "_copy", "cast", "clip",
-    "Activation", "LeakyReLU", "smooth_l1",
-    "elemwise_add", "elemwise_sub", "elemwise_mul", "elemwise_div",
-    "_power", "_maximum", "_minimum", "_mod",
-    "broadcast_add", "broadcast_sub", "broadcast_mul", "broadcast_div",
-    "broadcast_power", "broadcast_maximum", "broadcast_minimum",
-    "_plus_scalar", "_minus_scalar", "_rminus_scalar", "_mul_scalar",
-    "_div_scalar", "_rdiv_scalar", "_power_scalar", "_rpower_scalar",
-    "_maximum_scalar", "_minimum_scalar",
-})
 
 # Ops that materialize a deterministic value from params alone.
 CONST_SOURCE_OPS = frozenset({
@@ -345,61 +318,3 @@ def canonicalize(graph):
             gn.name = new
             changed += 1
     return changed
-
-
-# -------------------------------------------------------- fusion hints
-def fusion_hints(graph):
-    """Annotate producer-consumer elementwise chains with a
-    `__fusion_group__` tag (fg0, fg1, ... in topo order). A node joins
-    its producer's group only when it is that producer's sole consumer
-    and the producer is not a head — exactly the shape XLA fuses into
-    one kernel. Advisory: tags surface in serialized graphs and
-    `graphPassStats`, and are NOT part of the exec-cache key (Symbol
-    structure_key ignores extra attrs), so hints never fragment the
-    cache."""
-    consumers = graph.consumers()
-    head_nodes = {s for s, _ in graph.heads}
-
-    def _elementwise(gn):
-        if gn.is_variable:
-            return False
-        try:
-            return gn.opdef().name in ELEMWISE_OPS
-        except MXNetError:
-            return False
-
-    group = {}
-    members = []
-    for i, gn in enumerate(graph.nodes):
-        if not _elementwise(gn):
-            continue
-        g = None
-        for s, _ in gn.inputs:
-            if (s in group and len(consumers[s]) == 1
-                    and s not in head_nodes):
-                g = group[s]
-                break
-        if g is None:
-            g = len(members)
-            members.append([])
-        group[i] = g
-        members[g].append(i)
-
-    changed = 0
-    real = [m for m in members if len(m) >= 2]
-    tags = {}
-    for gid, m in enumerate(real):
-        for i in m:
-            tags[i] = f"fg{gid}"
-    for i, gn in enumerate(graph.nodes):
-        want = tags.get(i)
-        have = gn.extra.get("__fusion_group__")
-        if want != have:
-            changed += 1
-            if want is None:
-                del gn.extra["__fusion_group__"]
-            else:
-                gn.extra["__fusion_group__"] = want
-    # report group count (stable), not churn: re-running is a no-op and
-    # returns 0 only when tags were already in place
-    return changed and len(real)

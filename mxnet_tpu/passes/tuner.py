@@ -177,28 +177,6 @@ def _calibration_forward_s(digest, platform):
         return None
 
 
-def choose_fusion_kernel(group_digest, platform):
-    """'pallas' | 'lax' for one fusion group, from the kind="kernel" /
-    "kernel_lax" CalibrationStore measurements pallas_codegen records
-    at build time. Data-driven demotion only: the lax path must be
-    measurably faster (>5%) to override the generated kernel; missing
-    or partial measurements keep the kernel — the first build IS the
-    measurement."""
-    try:
-        from ..profiling import calibration_store
-
-        store = calibration_store()
-        kernel_s = store.measured_seconds(
-            group_digest, platform, "kernel")
-        lax_s = store.measured_seconds(
-            group_digest, platform, "kernel_lax")
-    except Exception:
-        return "pallas"
-    if kernel_s is None or lax_s is None:
-        return "pallas"
-    return "lax" if lax_s < kernel_s * 0.95 else "pallas"
-
-
 def _k_for_window(step_s):
     k = 1
     for cand in _MULTISTEP_CHOICES:

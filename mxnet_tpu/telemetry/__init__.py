@@ -69,11 +69,3 @@ from .flight import dump_flight_record, flight_record, maybe_dump
 # crash hooks chain the previous handlers and no-op until
 # MXNET_TELEMETRY_FLIGHT_DIR is set — free to install eagerly
 flight.install()
-
-
-def bench_snapshot():
-    """Compact queryable telemetry series for bench.py JSON output."""
-    return {
-        "spans": trace_stats(),
-        "span_summary": span_summary(),
-    }

@@ -237,8 +237,7 @@ def trace_stats():
 
 
 def span_summary():
-    """{name: {count, total_us}} aggregated over the retained ring —
-    the compact queryable series bench.py embeds in its JSON."""
+    """{name: {count, total_us}} aggregated over the retained ring."""
     out = {}
     for s in recent_spans():
         agg = out.setdefault(s.name, {"count": 0, "total_us": 0.0})

@@ -46,13 +46,10 @@ def getenv(name):
 def pallas_interpret():
     """Whether a Pallas kernel runs in interpret mode — the ONE place
     that decides. Off the TPU it must (Mosaic compiles for TPUs only).
-    On a TPU a kernel is compiled or the call raises; only
-    MXNET_FUSION_INTERPRET=1 (the parity-debugging hatch) selects the
-    interpreter there."""
+    On a TPU a kernel is compiled or the call raises."""
     import jax
 
-    return (jax.default_backend() != "tpu"
-            or bool(getenv("MXNET_FUSION_INTERPRET")))
+    return jax.default_backend() != "tpu"
 
 
 def describe_env():
@@ -79,16 +76,6 @@ register_env(
     "(reference env_var.md)",
 )
 register_env(
-    "MXNET_KVSTORE_REDUCTION_NTHREADS", int, 4,
-    "threads for CPU-side gradient reduction (reference comm.h)",
-)
-register_env(
-    "MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN", int, 0,
-    "unused: XLA compiles the whole graph as one computation (the "
-    "logical endpoint of the reference's bulk-exec segments, "
-    "graph_executor.cc:678); kept for CLI compat",
-)
-register_env(
     "MXNET_TPU_OPT_STATE_DTYPE", str, "",
     "dtype for optimizer state (momentum/moments) in the fused train "
     "step, e.g. 'bfloat16': halves optimizer-update HBM traffic; "
@@ -108,10 +95,6 @@ register_env(
     "own donated step and the canonical training state hands over on "
     "bucket switch (module/bucketing_module.py _ensure_owner); "
     "default keeps the reference's shared-NDArray eager updates.",
-)
-register_env(
-    "MXNET_ENABLE_GPU_P2P", bool, True,
-    "unused on TPU (ICI is always peer-to-peer); kept for CLI compat",
 )
 register_env(
     "MXNET_TPU_COORDINATOR", str, "",
@@ -134,10 +117,6 @@ register_env(
     "MXNET_TPU_XLA_TRACE_DIR", str, "",
     "when set, profiler_set_state('run') also captures an XLA device "
     "trace via jax.profiler into this directory",
-)
-register_env(
-    "MXNET_EXEC_NUM_TEMP", int, 1,
-    "unused: XLA plans temp buffers (reference resource.cc); compat",
 )
 register_env(
     "MXNET_BACKWARD_DO_MIRROR", bool, False,
@@ -241,8 +220,7 @@ register_env(
     "MXNET_GRAPH_PASSES", str, "1",
     "graph-optimization pass pipeline run on every bind ahead of the "
     "exec-cache lookup (mxnet_tpu.passes): '1'/'on' = the default "
-    "pipeline (dce, fold, cse, canonicalize, fusion_hints, "
-    "pallas_codegen); '0'/'off' "
+    "pipeline (dce, fold, cse, canonicalize); '0'/'off' "
     "= trace graphs exactly as constructed; a comma list selects and "
     "orders passes explicitly, e.g. 'dce,fold,cse,layout,"
     "canonicalize' to add the opt-in NCHW->NHWC layout rewrite "
@@ -254,29 +232,6 @@ register_env(
     "const subgraph whose result (or declared shape param) exceeds "
     "this many elements stays in the traced graph instead of being "
     "baked into the serialized form as a _graph_constant.",
-)
-register_env(
-    "MXNET_FUSION_CODEGEN", bool, True,
-    "pallas codegen (passes.pallas_codegen): lower __fusion_group__ "
-    "chains to generated Pallas kernels at bind time. 0 = every group "
-    "takes the composed lax fallback path (counted, never dropped); "
-    "the exec-cache key records the decision either way so fused and "
-    "fallback programs never collide (docs/passes.md).",
-)
-register_env(
-    "MXNET_FUSION_MIN_GROUP", int, 2,
-    "pallas codegen: minimum elementwise ops in a fusion group before "
-    "a kernel is generated; smaller groups fall back with reason "
-    "'too_small'. The fusion win is HBM round-trips saved, so a "
-    "1-op 'chain' has nothing to fuse.",
-)
-register_env(
-    "MXNET_FUSION_INTERPRET", bool, False,
-    "pallas codegen: force every generated kernel to run in Pallas "
-    "interpret mode even on TPU — the parity-debugging escape hatch, "
-    "and the switch that lets the codegen path (and its tests) run "
-    "on CPU. Off-TPU platforms use interpret mode implicitly only "
-    "when this flag is set; otherwise they take the lax fallback.",
 )
 register_env(
     "MXNET_TUNING_CACHE", str, "~/.cache/mxnet_tpu/tuning.json",
@@ -358,9 +313,7 @@ register_env(
     "decoding: page-table attention implementation: 'lax' (gather + "
     "masked softmax, runs anywhere) or 'pallas' (flash-style online-"
     "softmax kernel whose K/V block index maps read the page table "
-    "via scalar prefetch; interpret-mode on CPU). Read through "
-    "passes.codegen_config() — one switch surface with the "
-    "MXNET_FUSION_* kernel-generation knobs.",
+    "via scalar prefetch; interpret-mode on CPU).",
 )
 register_env(
     "MXNET_DECODE_MERGED_STEP", bool, True,

@@ -2,16 +2,16 @@
 
 The reference reports headline throughput in img/s and leaves FLOP math
 to the reader; for MFU we need the *analytic* convention used by the
-scaling literature (and BASELINE.md's 60% north star): count 2 FLOPs per
-MAC in the matmul-class ops (Convolution, FullyConnected, Deconvolution,
-dot), forward only, and take a training step as 3x forward (backward =
-grad-wrt-input + grad-wrt-weight, each the same MAC count as forward).
+scaling literature: count 2 FLOPs per MAC in the matmul-class ops
+(Convolution, FullyConnected, Deconvolution, dot), forward only, and
+take a training step as 3x forward (backward = grad-wrt-input +
+grad-wrt-weight, each the same MAC count as forward).
 
 This deliberately differs from XLA `cost_analysis()` on the compiled
 step, which counts *executed* FLOPs — including zero-multiplies in
 dilated gradient convolutions, rematerialized subgraphs, and whatever
-else the compiler scheduled. bench.py reports both: `mfu` (analytic,
-the comparable number) and `mfu_executed` (XLA's accounting).
+else the compiler scheduled. The benchmark's `train_mfu`
+(perfbench/harness/costs.py) follows the analytic convention.
 """
 from __future__ import annotations
 

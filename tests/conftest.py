@@ -44,6 +44,14 @@ os.environ["MXNET_EXEC_CACHE_DIR"] = _tempfile.mkdtemp(
 
 import pytest  # noqa: E402
 
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers",
+        "slow: takes tens of seconds or real replicas; left out of the "
+        "tier-1 run (-m 'not slow'), run by the ci/check_*.sh gates")
+
+
 # Threaded test modules run under the runtime lock witness in raise
 # mode: a genuine lock-order cycle anywhere in serving/decoding/data/
 # telemetry surfaces as LockOrderViolation at the acquisition attempt
@@ -71,3 +79,32 @@ def _lock_witness(request):
     finally:
         if not was_installed:
             lockwitness.uninstall()
+
+
+@pytest.fixture(autouse=True)
+def _jax_compile_cache_as_found():
+    """The exec cache's disk tier is on for the whole run (above), and
+    the first Executor bind of a process then turns jax's persistent
+    compilation cache on (`exec_cache_disk.configure_jax_cache`):
+    process-wide, so every LATER test of that worker compiled through
+    it, whichever file it came from. On this CPU backend an executable
+    read back from that cache cannot be serialized again: a bundle
+    saved from one (tests/test_quant.py's int8 bundles) failed at its
+    first call, `NOT_FOUND: Function ... not found`, but only in a
+    worker that had bound an Executor before. A test leaves the
+    cache's configuration as it found it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    if all(getattr(jax.config, n) == v for n, v in was.items()):
+        return
+    from mxnet_tpu import exec_cache_disk
+
+    for n, v in was.items():
+        jax.config.update(n, v)
+    exec_cache_disk._jax_cache_configured_for = None
+    compilation_cache.reset_cache()

@@ -241,7 +241,7 @@ def test_mx005_library_only_and_owned_generators_ok(tmp_path):
     r = random.random()
     """
     # user-side code (tools/, examples/) is out of contract
-    assert not _lint_src(src, "tools/bench.py", tmp_path=tmp_path,
+    assert not _lint_src(src, "tools/sample.py", tmp_path=tmp_path,
                          select={"MX005"})
     owned = """
     import numpy as np

@@ -1,8 +1,9 @@
-"""The flagship bench configuration, gated at tiny scale on CPU: the
-exact path bench.py measures (Module + KVStore('tpu') fused step +
-cast_compute(bfloat16) + NHWC + space-to-depth stem) must train with
-finite loss and updating parameters — so driver bench runs can't be
-broken by a config-interaction regression the per-feature tests miss.
+"""The benchmark's ResNet configuration, gated at tiny scale on CPU:
+the path the ResNet cells of perfbench/ measure (Module +
+KVStore('tpu') fused step + cast_compute(bfloat16) + NHWC +
+space-to-depth stem) must train with finite loss and updating
+parameters — so a cell's run can't be broken by a config-interaction
+regression the per-feature tests miss.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +13,7 @@ from mxnet_tpu.models import get_resnet
 
 
 def _flagship_module(batch, classes=5):
-    """EXACTLY the bench.py flagship config at tiny scale (resnet-18,
+    """The ResNet cells' configuration at tiny scale (resnet-18,
     64px, NHWC, s2d stem, KVStore('tpu'), sgd-momentum, bf16 compute)
     — one definition so both gates certify the same config."""
     net = get_resnet(num_classes=classes, num_layers=18,
@@ -65,10 +66,9 @@ def test_flagship_bench_config_trains():
 
 
 def test_flagship_bench_multistep_config_trains():
-    """The ACCELERATOR-default bench path: BENCH_MULTISTEP=8 drives
-    run_steps with stacked per-step batches over the same flagship
-    config (bench.py:multistep branch) — must train finitely and
-    report positive per-step flops through the k-loop estimate."""
+    """steps_per_dispatch > 1: run_steps with stacked per-step batches
+    over the same flagship config — must train finitely and report
+    positive per-step flops through the k-loop estimate."""
     np.random.seed(0)
     batch, classes, k = 4, 5, 3
     mod = _flagship_module(batch, classes)

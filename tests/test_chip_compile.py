@@ -9,10 +9,9 @@ Two halves:
     it: these are the cases a later PR breaks without noticing.
     Skipped (not failed) where the topology cannot be described.
   * the rules that stop a missing TPU from degrading quietly: context
-    resolution, Module placement, the compile-cache helper, bench.py
-    and chip_smoke.py without a chip.
+    resolution, Module placement, the compile-cache helper, and
+    chip_smoke.py without a chip.
 """
-import json
 import os
 import re
 import subprocess
@@ -25,13 +24,11 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import exec_cache_disk, passes, utils
+from mxnet_tpu import exec_cache_disk, utils
 from mxnet_tpu.context import resolve_device
 from mxnet_tpu.decoding import attention as paged
 from mxnet_tpu.decoding import quant
 from mxnet_tpu.parallel.attention import attention
-from mxnet_tpu.passes import pallas_codegen as pc
-from mxnet_tpu.passes.ir import Graph
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -421,42 +418,6 @@ def test_sparse_latent_program_holds_no_pool_sized_copy(v5e, sparse_engine,
     assert temps[1] - temps[0] <= 0.05 * temps[0], temps
 
 
-def _group_spec(net):
-    graph = Graph.from_symbol(passes.optimize(net, collect_stats=False))
-    (members,) = pc._groups_in(graph.nodes).values()
-    return pc._group_spec(graph.nodes, sorted(members))
-
-
-@pytest.mark.parametrize("template,dtype", [
-    ("elementwise", "float32"), ("elementwise", "bfloat16"),
-    ("scale_bias_act", "float32"), ("reduction", "float32"),
-])
-def test_codegen_template_compiles(compile_for_chip, template, dtype):
-    """Each surviving template's generated kernel, the smoke's graph
-    and shape, through the emitter the codegen stage calls."""
-    spec, ext = _group_spec(chip_smoke._codegen_nets()[template])
-    assert pc._template_of(spec) == template
-    avals = [(FULL["codegen_shape"], jnp.dtype(dtype))] * len(ext)
-    structs = [_s(s, d) for s, d in avals]
-    out_aval = jax.eval_shape(pc.group_lax_fn(spec), *structs)
-    kernel = pc._EMITTERS[template](spec, avals, out_aval,
-                                    utils.pallas_interpret())
-    assert "tpu_custom_call" in compile_for_chip(kernel, *structs)
-
-
-def test_codegen_blocks_fill_vmem_not_one_tile():
-    """A ResNet-sized activation is a few hundred grid steps, not one
-    (8, 128) register tile per step."""
-    r, c = pc._norm2d((256, 56, 56, 256))
-    block, grid = pc._tiling(r, c, np.float32, False, n_operands=2)
-    assert block[0] % 8 == 0 and block[1] % 128 == 0
-    assert r % block[0] == 0 and c % block[1] == 0
-    assert grid[0] * grid[1] < 1000
-    assert block[0] * block[1] * 4 * 2 <= pc._BLOCK_BYTES
-    with pytest.raises(pc._Unsupported, match="irregular_shapes"):
-        pc._tiling(5, 7, np.float32, False, n_operands=2)
-
-
 def test_rtc_pallas_kernel_compiles(compile_for_chip):
     def double_kernel(x_ref, o_ref):
         o_ref[...] = x_ref[...] * 2.0
@@ -464,33 +425,6 @@ def test_rtc_pallas_kernel_compiles(compile_for_chip):
     fn = mx.rtc.PallasKernel("double", double_kernel).compiled([(8,)])
     assert "tpu_custom_call" in compile_for_chip(
         fn, _s((8,), jnp.float32))
-
-
-def test_refused_kernel_is_counted_compile_refused(monkeypatch):
-    """A compiler refusal at build time is caught there, under its own
-    reason — not inside the step's compile, not as irregular_shapes."""
-    monkeypatch.setenv("MXNET_FUSION_INTERPRET", "1")
-
-    def refusing(spec, ext_avals, out_aval, interpret):
-        def kernel(*vals):
-            raise RuntimeError("Mosaic says no")
-        return kernel
-
-    monkeypatch.setitem(pc._EMITTERS, "elementwise", refusing)
-    passes.reset_fusion_stats()
-    passes.clear_memo()
-    mx.exec_cache.clear()
-    net = chip_smoke._codegen_nets()["elementwise"]
-    exe = net.simple_bind(mx.cpu(), x=(8, 128), y=(8, 128))
-    assert passes.fusion_stats()["fallback_reasons"] == \
-        {"compile_refused": 1}
-    # the group still runs, through its lax twin
-    exe.forward(is_train=False, x=mx.nd.ones((8, 128)),
-                y=mx.nd.ones((8, 128)))
-    assert float(exe.outputs[0].asnumpy().max()) == 0.0
-    passes.reset_fusion_stats()
-    passes.clear_memo()
-    mx.exec_cache.clear()
 
 
 # ------------------------------------------- no fallback hides the device
@@ -507,8 +441,6 @@ def test_pallas_interprets_only_off_the_tpu(monkeypatch):
     assert utils.pallas_interpret()          # this process: CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert not utils.pallas_interpret()
-    monkeypatch.setenv("MXNET_FUSION_INTERPRET", "1")
-    assert utils.pallas_interpret()
 
 
 @pytest.mark.parametrize("device_type,device_id,devices", [
@@ -609,22 +541,3 @@ def test_chip_smoke_without_a_tpu_fails_and_prints_no_result():
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout, proc.stdout
     assert "needs a TPU" in proc.stderr
-
-
-def test_bench_without_a_tpu_exits_nonzero():
-    proc = _run("bench.py", BENCH_PLATFORM="")
-    assert proc.returncode != 0
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "bench_error" and "TPU" in rec["error"]
-
-
-def test_bench_unknown_device_kind_is_an_error():
-    import bench
-
-    class Dev:
-        platform, device_kind = "tpu", "TPU v99 mega"
-
-    with pytest.raises(RuntimeError, match="no peak-FLOP/s row"):
-        bench._detect_peak_flops(Dev())
-    Dev.device_kind = "TPU v5 lite"
-    assert bench._detect_peak_flops(Dev()) == 197e12

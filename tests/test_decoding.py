@@ -14,6 +14,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import decoding as dec
 from mxnet_tpu import serving
+from mxnet_tpu.decoding import attention as attn
 from mxnet_tpu.decoding.blocks import (BlockAllocator, PageError,
                                        PagePoolExhausted, SCRATCH_PAGE,
                                        pages_needed)
@@ -341,6 +342,51 @@ def test_get_kernel():
     assert dec.get_kernel("pallas") is dec.paged_attention_pallas
     with pytest.raises(ValueError):
         dec.get_kernel("nope")
+
+
+def test_kernel_switch_is_read_from_the_environment(monkeypatch):
+    """MXNET_DECODE_KERNEL picks the engine's attention kernel where
+    the caller names none (the chip's lax-against-Pallas reading went
+    through it); an engine built that way serves the same greedy
+    tokens, and a caller's own choice wins over the variable."""
+    monkeypatch.setenv("MXNET_DECODE_KERNEL", "pallas")
+    for named, want in ((None, "pallas"), ("lax", "lax")):
+        m = _model(kernel=named)
+        try:
+            assert m.engine.kernel_name == want
+            assert m.generate([5, 6, 7], max_new_tokens=4, timeout=60) \
+                == _ref_greedy([5, 6, 7], 4)
+        finally:
+            m.close()
+
+
+# ------------------------------------------- paged vs rectangular cache
+def test_paged_cache_reserves_less_than_a_rectangular_one():
+    """What the pages are for, as a count: over ragged requests the
+    pool reserves ceil(context / page_size) pages a sequence, so the
+    share of reserved slots that never hold a token is under what the
+    (request, max_context) rectangle of a one-shot batcher leaves
+    empty. The pages are the allocator's own count."""
+    m = _model(prefix_cache=False)
+    try:
+        page = m.engine.allocator.page_size
+        prompts = [[5, 6, 7], [3], list(range(2, 13)), [9] * 6]
+        before = m.engine.pool_stats()["pages_allocated"]
+        outs = [m.generate(p, max_new_tokens=5, timeout=60)
+                for p in prompts]
+        pages = m.engine.pool_stats()["pages_allocated"] - before
+        # a sequence holds its prompt and every token it emitted but
+        # the last, which no step has fed back
+        held = [len(p) + len(o) - 1 for p, o in zip(prompts, outs)]
+        assert pages == sum(pages_needed(c, page) for c in held)
+        tokens = sum(held)
+        waste_paged = 1 - tokens / (pages * page)
+        waste_oneshot = 1 - tokens / (len(prompts)
+                                      * m.engine.max_context)
+        assert 0 <= waste_paged < waste_oneshot
+        assert m.stats.snapshot()["traces_since_warmup"] == 0
+    finally:
+        m.close()
 
 
 # ----------------------------------------------- parity + zero retrace
@@ -1038,3 +1084,94 @@ def test_reply_span_of_a_cancelled_request_is_the_handoff_alone():
     assert 0 < lifetime <= t_done - t_submit
     assert reply.t1 - reply.t0 < lifetime / 5
     assert reply.t0 > t_submit + lifetime / 2
+
+
+# ------------------------------------------------- ragged attention
+def test_ragged_kernel_mixed_prefill_decode_matches_dense():
+    """ONE fixed-shape ragged call serving decode rows (full context)
+    and tail-prefill rows (mid-prompt positions) must match a dense
+    numpy softmax oracle row by row."""
+    rs = np.random.RandomState(7)
+    b, h, d, p, bp, n = 4, 2, 8, 4, 3, 16
+    q = rs.randn(b, h, d).astype(np.float32)
+    k_pages = rs.randn(n, p, h, d).astype(np.float32)
+    v_pages = rs.randn(n, p, h, d).astype(np.float32)
+    table = np.stack([rs.choice(np.arange(1, n), size=bp,
+                                replace=False) for _ in range(b)]
+                     ).astype(np.int32)
+    # rows 0-1: decode rows attending their whole context; rows 2-3:
+    # prompt-tail rows mid-prefill, attending only positions < their
+    # own (intra-chunk causality via the per-row length)
+    lengths = np.asarray([9, 12, 3, 6], np.int32)
+
+    scale = 1.0 / np.sqrt(d)
+
+    def oracle(row):
+        ctx_k = k_pages[table[row]].reshape(bp * p, h, d)
+        ctx_v = v_pages[table[row]].reshape(bp * p, h, d)
+        ln = lengths[row]
+        s = np.einsum("hd,thd->ht", q[row], ctx_k[:ln]) * scale
+        e = np.exp(s - s.max(axis=-1, keepdims=True))
+        w = e / e.sum(axis=-1, keepdims=True)
+        return np.einsum("ht,thd->hd", w, ctx_v[:ln])
+
+    for name in ("lax", "pallas"):
+        out = np.asarray(attn.get_ragged_kernel(name)(
+            q, k_pages.reshape(n, p, h * d),
+            v_pages.reshape(n, p, h * d), table, lengths))
+        for row in range(b):
+            np.testing.assert_allclose(out[row], oracle(row),
+                                       atol=1e-5,
+                                       err_msg=f"{name} row {row}")
+
+
+# ------------------------------------------------- merged decode step
+def test_merged_step_shrinks_warmup_grid_and_keeps_parity():
+    """The merged engine drops every per-length-bucket tail-prefill
+    program from the warmup grid, and prefix-cache-hit traffic
+    (which exercises the ragged tail rows) stays token-identical to
+    the dense reference at zero steady-state retraces."""
+    split = _model(prefix_cache=True, merged_step=False)
+    split_counts = split.engine.trace_counts()
+    split.close()
+    assert any(k.startswith("prefill_tail@") for k in split_counts)
+
+    m = _model(prefix_cache=True, merged_step=True)
+    try:
+        counts = m.engine.trace_counts()
+        assert not any(k.startswith("prefill_tail@") for k in counts)
+        assert sum(counts.values()) < sum(split_counts.values())
+
+        floor = m.engine.traces()
+        shared = [5, 6, 7, 8, 9, 10, 11, 12]   # two full pages
+        prompts = [shared + [13], shared + [14, 15], [3, 4],
+                   shared + [16, 17, 18]]
+        for prompt in prompts:
+            out = m.generate(prompt, max_new_tokens=6, timeout=60)
+            assert out == _ref_greedy(prompt, 6), prompt
+        assert m.engine.traces() == floor
+        assert m.stats.snapshot()["traces_since_warmup"] == 0
+    finally:
+        m.close()
+
+
+def test_merged_engine_rejects_dedicated_tail_prefill():
+    m = _model(prefix_cache=True, merged_step=True)
+    try:
+        table = m.engine.allocator.alloc(2)
+        with pytest.raises(PageError):
+            m.engine.prefill(list(range(2, 8)), table, start=4)
+        m.engine.allocator.free(table)
+    finally:
+        m.close()
+
+
+def test_merged_step_off_without_prefix_cache():
+    """No prefix cache -> no tail to merge: the engine stays on the
+    split grid (speculative engines likewise keep their own step)."""
+    m = _model(prefix_cache=False, merged_step=True)
+    try:
+        assert not m.engine.merged_step_enabled
+        assert m.engine.step_rows == m.engine.max_batch
+    finally:
+        m.close()

@@ -26,6 +26,26 @@ def test_every_registered_env_documented():
         assert f"`{name}`" in doc, name
 
 
+def test_every_registered_env_is_read_by_the_program():
+    """A registered variable that no file of the package reads is a
+    switch wired to nothing: the registry (and docs/env_vars.md) then
+    promises what the program does not do."""
+    from mxnet_tpu import utils
+
+    registry = os.path.join(ROOT, "mxnet_tpu", "utils", "__init__.py")
+    sources = []
+    for root, _, files in os.walk(os.path.join(ROOT, "mxnet_tpu")):
+        for name in files:
+            path = os.path.join(root, name)
+            if name.endswith(".py") and path != registry:
+                with open(path) as f:
+                    sources.append(f.read())
+    text = "\n".join(sources)
+    unread = [name for name in utils._ENV_REGISTRY
+              if not re.search(rf"""["']{name}["']""", text)]
+    assert not unread, unread
+
+
 def test_doc_api_references_exist():
     import mxnet_tpu as mx
 

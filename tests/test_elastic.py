@@ -336,9 +336,11 @@ def test_shrink_and_grow_bitwise_identical():
         c.stop()
     for n in ref:
         assert np.array_equal(ref[n], got[n])
-    assert snap["transitions_shrink"] == 1
-    assert snap["reshard_bytes_moved"] < \
+    assert snap["transitions"] == snap["transitions_shrink"] == 1
+    # the placement delta beats restoring everyone, and is not empty
+    assert 0 < snap["reshard_bytes_moved"] < \
         snap["reshard_bytes_full_restore"]
+    assert snap["digest_mismatches"] == 0
 
     c = ElasticCoordinator(ENTRY, {}, name="t_grow",
                            initial_world=1).start()
@@ -354,7 +356,9 @@ def test_shrink_and_grow_bitwise_identical():
         c.stop()
     for n in ref:
         assert np.array_equal(ref[n], got[n])
-    assert snap["transitions_grow"] == 1
+    assert snap["transitions"] == snap["transitions_grow"] == 1
+    assert 0 < snap["reshard_bytes_moved"] < \
+        snap["reshard_bytes_full_restore"]
     assert snap["digest_mismatches"] == 0
 
 

@@ -494,6 +494,27 @@ def _load(server, name, params, cfg):
                                page_size=4, num_pages=64)
 
 
+def _thread_replicas(params, cfg, started=None):
+    """A FleetRouter `spawn_fn` whose replicas are threads of this
+    process, each with a server and a paged decoder of its own;
+    `started` collects (server, model) as they come up."""
+    def spawn(rid, port):
+        def run():
+            server = ModelServer()
+            model = _load(server, f"lm-{rid}", params, cfg)
+            if started is not None:
+                started.append((server, model))
+            sock = socket.create_connection(("127.0.0.1", port))
+            fleet.ReplicaWorker(server, model,
+                                fleet.Channel(sock, name=rid), rid,
+                                heartbeat_ms=50,
+                                hello_extra={"traces": 0,
+                                             "compiles": 0}).run()
+        threading.Thread(target=run, daemon=True).start()
+
+    return spawn
+
+
 @pytest.mark.slow
 def test_drain_handoff_resumes_bit_identical(tiny):
     cfg, params, ref_model = tiny
@@ -558,21 +579,9 @@ def test_fleet_end_to_end_drain_over_wire(tiny):
     ref = ref_model.generate(prompt, max_new_tokens=200, sampling=SAMP)
     assert len(ref) > 8
 
-    def spawn(rid, port):
-        def run():
-            server = ModelServer()
-            model = _load(server, f"lm-{rid}", params, cfg)
-            sock = socket.create_connection(("127.0.0.1", port))
-            chan = fleet.Channel(sock, name=rid)
-            fleet.ReplicaWorker(server, model, chan, rid,
-                                heartbeat_ms=50,
-                                hello_extra={"traces": 0,
-                                             "compiles": 0}).run()
-        threading.Thread(target=run, daemon=True).start()
-        return None
-
     router = fleet.FleetRouter(replicas=2, heartbeat_ms=50,
-                               page_size=4, spawn_fn=spawn,
+                               page_size=4,
+                               spawn_fn=_thread_replicas(params, cfg),
                                name="e2e", seed=1)
     router.start(wait=True, timeout=60)
     try:
@@ -593,3 +602,59 @@ def test_fleet_end_to_end_drain_over_wire(tiny):
         assert router.stats.snapshot()["handoffs"] == 1
     finally:
         router.stop()
+
+
+def _routed_arm(policy, params, cfg, prompts, families):
+    """One fleet of two thread-backed replicas (each its own server
+    and paged decoder) serving `prompts` under `policy`: the fleet's
+    prefix hits, misses and pages allocated, from the replicas' own
+    stats."""
+    started = []
+    router = fleet.FleetRouter(replicas=2, heartbeat_ms=50, page_size=4,
+                               policy=policy,
+                               spawn_fn=_thread_replicas(params, cfg,
+                                                         started),
+                               name=f"ab-{policy}", seed=0)
+    router.start(wait=True, timeout=120)
+    try:
+        # a wave is one request a family; the wait between waves lets
+        # heartbeats advertise what the wave left cached
+        for i in range(0, len(prompts), families):
+            futs = [router.submit(p, max_new_tokens=2)
+                    for p in prompts[i:i + families]]
+            for f in futs:
+                f.result(120)
+            time.sleep(0.2)
+    finally:
+        router.stop()
+    snaps = [model.stats.snapshot() for _, model in started]
+    for server, _ in started:
+        server.stop(drain=False)
+    return {k: sum(s[k] for s in snaps)
+            for k in ("prefix_hits", "prefix_misses", "pages_allocated")}
+
+
+@pytest.mark.slow
+def test_affinity_routing_beats_random_on_hits_and_pages(tiny):
+    """Why the router routes by prefix: the same chat-shaped traffic
+    (four families, each a shared three-page preamble and a short
+    tail of its own) costs fewer prefix misses and fewer pages when a
+    family keeps to the replica that cached it than when it is dealt
+    at random."""
+    cfg, params, _ = tiny
+    rs = np.random.RandomState(0)
+    families = 4
+    heads = [rs.randint(2, cfg.vocab, size=12).tolist()
+             for _ in range(families)]
+    prompts = [heads[i % families]
+               + rs.randint(2, cfg.vocab, size=int(rs.randint(2, 4)))
+               .tolist() for i in range(6 * families)]
+    aff = _routed_arm("affinity", params, cfg, prompts, families)
+    rnd = _routed_arm("random", params, cfg, prompts, families)
+
+    def hit_rate(arm):
+        return arm["prefix_hits"] / (arm["prefix_hits"]
+                                     + arm["prefix_misses"])
+
+    assert hit_rate(aff) > hit_rate(rnd), (aff, rnd)
+    assert aff["pages_allocated"] < rnd["pages_allocated"], (aff, rnd)

@@ -68,7 +68,7 @@ def test_mxlint_exits_nonzero_on_violation(tmp_path):
 
 
 def test_mx009_pallas_call_containment():
-    """MX009 keeps pl.pallas_call behind the codegen entry points: a
+    """MX009 keeps pl.pallas_call behind the kernel entry points: a
     raw call anywhere else is flagged, and even the allowlisted kernel
     modules must carry a visible lax/reference twin."""
     import ast
@@ -87,7 +87,7 @@ def test_mx009_pallas_call_containment():
     # outside the allowlist: flagged no matter what else the file has
     found = findings("mxnet_tpu/my_kernel.py", raw_kernel)
     assert len(found) == 1 and found[0].rule == "MX009"
-    assert "outside the codegen entry points" in found[0].message
+    assert "outside the kernel entry points" in found[0].message
 
     # allowlisted module WITHOUT a lax twin: still flagged
     found = findings("mxnet_tpu/decoding/attention.py", raw_kernel)

@@ -413,7 +413,7 @@ def test_uint8_batches_train_fused(tmp_path):
     """End-to-end: uint8 raw-pixel batches feed the fused train step —
     the jit promotes unsigned data to the compute dtype on device, the
     graph's input BatchNorm normalizes — and training converges the
-    same as float32 batches (the BENCH_U8 path)."""
+    same as float32 batches."""
     import mxnet_tpu as mx
 
     # 4-class task: per-class brightness + noise (trivially learnable)

@@ -299,21 +299,6 @@ def test_layout_pass_is_idempotent():
     assert once.tojson() == twice.tojson()
 
 
-def test_fusion_hints_tag_elementwise_chains():
-    x = mx.sym.Variable("x")
-    chain = mx.sym.sum(mx.sym.tanh(mx.sym.exp(x) + 1.0))
-    opt = passes.optimize(chain)
-    tagged = [n for n in json.loads(opt.tojson())["nodes"]
-              if n.get("attrs", {}).get("__fusion_group__")]
-    assert len(tagged) >= 2
-    groups = {n["attrs"]["__fusion_group__"] for n in tagged}
-    assert len(groups) >= 1
-    # hints are metadata only: they must not fragment the exec cache
-    assert (opt.structure_key()
-            == passes.optimize(chain, passes=["canonicalize"])
-            .structure_key())
-
-
 # -------------------------------------------------------------- manager
 def test_every_pass_output_is_verified():
     @passes.register_pass("_test_broken", default_on=False)

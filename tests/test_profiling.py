@@ -591,44 +591,6 @@ def test_executor_stamps_node_names_into_hlo():
 
 
 # ---------------------------------------------------------------------
-# benchdiff
-# ---------------------------------------------------------------------
-def test_benchdiff_flags_regressions(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "benchdiff", os.path.join(os.path.dirname(__file__), "..",
-                                  "tools", "benchdiff.py"))
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(
-        {"metric": "m", "value": 100.0, "unit": "img/s",
-         "p99_ms": 10.0}) + "\n")
-    # throughput down 20%, latency up 50%: two regressions
-    new.write_text(json.dumps(
-        {"metric": "m", "value": 80.0, "unit": "img/s",
-         "p99_ms": 15.0}) + "\n")
-    assert bd.main([str(old), str(new)]) == 1
-    # the improvement direction passes
-    assert bd.main([str(new), str(old)]) == 0
-    # within threshold passes
-    ok = tmp_path / "ok.json"
-    ok.write_text(json.dumps(
-        {"metric": "m", "value": 95.0, "unit": "img/s",
-         "p99_ms": 10.4}) + "\n")
-    assert bd.main([str(old), str(ok)]) == 0
-    # wrapper format ({"tail": ...}) parses too
-    wrapped = tmp_path / "wrapped.json"
-    wrapped.write_text(json.dumps(
-        {"n": 1, "tail": "noise\n" + json.dumps(
-            {"metric": "m", "value": 101.0, "p99_ms": 9.0})}))
-    assert bd.main([str(old), str(wrapped)]) == 0
-
-
-# ---------------------------------------------------------------------
 # decoding stats: prefill latency histogram
 # ---------------------------------------------------------------------
 def test_prefill_latency_histogram_buckets():

@@ -171,14 +171,27 @@ def _toy_sym():
     return mx.symbol.LinearRegressionOutput(fc, name="lro")
 
 
+def _toy_iter():
+    rng = np.random.RandomState(0)
+    X = rng.randint(-1, 2, size=(8, 4)).astype(np.float32) / 2.0
+    Y = rng.randint(-1, 2, size=(8, 8)).astype(np.float32) / 2.0
+    return mx.io.NDArrayIter(X, Y, batch_size=8, label_name="lro_label")
+
+
+def _toy_epochs(mod, n):
+    it = _toy_iter()
+    for _ in range(n):
+        it.reset()
+        for batch in it:
+            mod.forward_backward(batch)
+            mod.update()
+
+
 def _toy_fit(plan=None, mesh_shape=None, n_steps=3):
     """3 SGD steps on one no-bias FC with dyadic-rational data: every
     intermediate stays exactly representable in f32, so the final
     params are bitwise-identical under ANY sharding."""
-    rng = np.random.RandomState(0)
-    X = rng.randint(-1, 2, size=(8, 4)).astype(np.float32) / 2.0
-    Y = rng.randint(-1, 2, size=(8, 8)).astype(np.float32) / 2.0
-    it = mx.io.NDArrayIter(X, Y, batch_size=8, label_name="lro_label")
+    it = _toy_iter()
     mod = mx.mod.Module(_toy_sym(), data_names=("data",),
                         label_names=("lro_label",),
                         sharding=plan, mesh_shape=mesh_shape)
@@ -189,11 +202,7 @@ def _toy_fit(plan=None, mesh_shape=None, n_steps=3):
                     aux_params={}, force_init=True)
     mod.init_optimizer(optimizer="sgd",
                        optimizer_params={"learning_rate": 0.5})
-    for _ in range(n_steps):
-        it.reset()
-        for batch in it:
-            mod.forward_backward(batch)
-            mod.update()
+    _toy_epochs(mod, n_steps)
     params, _ = mod.get_params()
     return mod, {k: v.asnumpy() for k, v in params.items()}
 
@@ -244,6 +253,25 @@ def test_dp_tp_fsdp_parity_and_storage():
     assert device_param_bytes(fs.params) * 2 <= replicated
     # gather-before-use was wired (storage != compute for the weight)
     assert "out_head_weight" in fs._gather_sh
+
+
+@needs8
+@pytest.mark.parametrize("mesh", [{"data": 8},
+                                  {"data": 2, "fsdp": 2, "tp": 2}])
+def test_sharded_steady_state_adds_no_trace(mesh):
+    """After its first steps a plan-driven Module traces nothing more:
+    no executor-cache trace and no sharded jit build in further
+    epochs, replicated or partitioned."""
+    from mxnet_tpu import exec_cache
+    from mxnet_tpu.sharding import lower_stats
+
+    mod, _ = _toy_fit(plan=ShardingPlan(mesh))
+    before = (exec_cache.cache_stats()["traces"],
+              lower_stats()["jit_builds"])
+    _toy_epochs(mod, 3)
+    mod.sync()
+    assert (exec_cache.cache_stats()["traces"],
+            lower_stats()["jit_builds"]) == before
 
 
 @needs8

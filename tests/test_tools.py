@@ -110,24 +110,6 @@ def test_yarn_command_quoting():
     assert inner  # quoting round-trips
 
 
-def test_bench_transformer_emits_json():
-    rec = _run_tool("bench_transformer.py", [
-        "--batch", "2", "--seq", "64", "--d-model", "32",
-        "--d-ff", "64", "--num-layers", "1", "--iters", "2"])[-1]
-    assert rec["unit"] == "tokens/s" and rec["value"] > 0
-    assert rec["step_flops_analytic"] > 0
-
-
-def test_bench_transformer_multistep():
-    """--multistep k routes through the compiled k-loop and still
-    emits a sane record."""
-    rec = _run_tool("bench_transformer.py", [
-        "--batch", "2", "--seq", "64", "--d-model", "32",
-        "--d-ff", "64", "--num-layers", "1", "--iters", "4",
-        "--multistep", "2"])[-1]
-    assert rec["unit"] == "tokens/s" and rec["value"] > 0
-
-
 def test_kill_mxnet_dry_run():
     import subprocess as sp
     import time

@@ -40,8 +40,8 @@ def render():
         "- `XLA_PYTHON_CLIENT_MEM_FRACTION` / `_PREALLOCATE` — set via "
         "`mx.set_memory_fraction()`; see docs/perf.md.",
         "- `JAX_COMPILATION_CACHE_DIR` — where jax's persistent compile "
-        "cache lives. When set, no code moves it; unset, `bench.py` and "
-        "`chip_smoke.py` use `<checkout>/.jax_cache` and the exec-cache "
+        "cache lives. When set, no code moves it; unset, "
+        "`chip_smoke.py` uses `<checkout>/.jax_cache` and the exec-cache "
         "disk tier uses `<MXNET_EXEC_CACHE_DIR>/xla` "
         "(`exec_cache_disk.place_jax_cache`).",
         "",

@@ -5,7 +5,7 @@ data-nthreads scaling in docs/how_to/perf.md:36-45). Synthesizes an
 ImageNet-shaped RecordIO, then measures img/s through the full
 read->decode->augment->batch pipeline per thread count, printing one
 JSON line per configuration. Tells whether IO can feed the training
-throughput bench.py reports.
+throughput the benchmark reports (`train_throughput`, PERF.md).
 
   python tools/io_bench.py --num-images 512 --threads 1,4,8
 """

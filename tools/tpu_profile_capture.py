@@ -1,17 +1,17 @@
 #!/usr/bin/env python
 """Profiled short training run on the chip.
 
-Runs the flagship bench config for a handful of steps with the merged
-host+device profiler armed (docs/perf.md method: jax.profiler trace +
+Runs the ResNet-50 training configuration for a handful of steps with
+the merged host+device profiler armed (docs/perf.md method: jax.profiler trace +
 HLO-attributed device timeline), then writes
 
     <outdir>/profile_merged.json   — one merged Chrome trace
     <outdir>/step_summary.json     — per-step wall times
 
 so a chip run leaves OPTIMIZABLE evidence (where the step time
-goes), not just a throughput number. Kept separate from bench.py on
-purpose: the bench must stay unprofiled (tracing skews throughput).
-One process holds the chip: run this alone, not beside a bench.
+goes), not just a throughput number. Kept separate from the
+benchmark's untraced runs on purpose (tracing skews throughput).
+One process holds the chip: run this alone.
 
 Usage: python tools/tpu_profile_capture.py [outdir]  (default
 <repo>/chiprun_out/profile — the directory the chip tool brings back)

@@ -12,8 +12,9 @@ from --seed), and checks what comes out by the repo's own means:
            DecoderConfig at d_model 2048 / 16 heads / d_ff 8192 /
            8 layers / vocab 32000 / max_len 2048: every stream
            token-identical to the unbatched greedy reference, with the
-           default and the Pallas paged-attention kernel; int8 pages'
-           top-1 agreement
+           tier's default paged attention (the in-place kernel on a
+           TPU, the lax form elsewhere) and with the other one; int8
+           pages' top-1 agreement
   kernels  every Pallas kernel on those paths, compiled (never
            interpreted on a TPU), against its lax reference
   --chips 4   (and then no other phase) the ResNet step data-parallel
@@ -346,8 +347,10 @@ def _serve(size, seed):
               dec.init_decoder_params(cfg, seed=seed).items()}
     want = _greedy_reference(params, cfg, jobs, max_context)
     server = serving.ModelServer()
+    # the tier's default and the form it did not pick
+    other = "lax" if dec.config.kernel() == "pallas" else "pallas"
     try:
-        for kernel in (None, "pallas"):
+        for kernel in (None, other):
             name = f"lm-{kernel or 'default'}"
             model = server.load_decoder(
                 name, params, cfg, kernel=kernel,
@@ -377,8 +380,9 @@ def _serve(size, seed):
             server.unload(name)
             del model
             gc.collect()
-        checks["pallas_run_has_tpu_custom_call"] = \
-            runs["lm-pallas"]["tpu_custom_call"]
+        checks["pallas_run_has_tpu_custom_call"] = all(
+            run["tpu_custom_call"] for run in runs.values()
+            if run.get("kernel") == "pallas")
     finally:
         server.stop(drain=False)
     # int8 pages: teacher-forced top-1 agreement with float pages (the
@@ -386,7 +390,7 @@ def _serve(size, seed):
     # ones already compiled)
     rng = random.Random(seed + 1)
     prompt = [rng.randrange(2, cfg.vocab) for _ in range(probe["prompt"])]
-    for kernel in (None, "pallas"):
+    for kernel in (None, other):
         res = quant_parity_probe(
             params, cfg, prompt, max_new=probe["new"], kv_dtype="int8",
             page_size=spec["page_size"], num_pages=spec["num_pages"],
@@ -451,8 +455,8 @@ def _kernel_paged(dcfg, spec, kv_dtype, seed):
     from mxnet_tpu.decoding import quant
 
     h, d = dcfg["n_heads"], dcfg["d_model"] // dcfg["n_heads"]
-    b, p = spec["max_batch"], spec["page_size"]
-    bp, n = spec["page_buckets"][0], spec["num_pages"]
+    b, p = 8, spec["page_size"]
+    bp, n = spec["page_buckets"][-1], spec["num_pages"]
     rs = np.random.RandomState(seed)
     # two layers, the second one read: the kernels take the whole pool
     # and the layer's index (decoding/quant.py), never a slice of it
@@ -466,7 +470,14 @@ def _kernel_paged(dcfg, spec, kv_dtype, seed):
         pools.append(pool)
     q = jnp.asarray(rs.standard_normal((b, h, d)), jnp.float32)
     table = jnp.asarray(rs.randint(1, n, (b, bp)), jnp.int32)
-    lengths = jnp.asarray(rs.randint(1, bp * p + 1, (b,)), jnp.int32)
+    # ragged: an empty row (the kernel starts no copy for it and
+    # returns zeros), one token, one page, the full bucket, the rest
+    # anywhere; a row's pages past its length are whatever the table
+    # drew, which no row may read
+    lengths = rs.randint(1, bp * p + 1, (b,))
+    lengths[:4] = 0, 1, p, bp * p
+    live = lengths > 0
+    lengths = jnp.asarray(lengths, jnp.int32)
 
     def on_layer(kernel):
         return jax.jit(lambda q, k, v, table, lengths: kernel(
@@ -476,9 +487,11 @@ def _kernel_paged(dcfg, spec, kv_dtype, seed):
     lax = on_layer(attn.paged_attention_lax)
     args = (q, pools[0], pools[1], table, lengths)
     with jax.default_matmul_precision("highest"):  # the lax twin's dots
-        err = _max_err(pallas(*args), lax(*args))
+        got = np.asarray(pallas(*args))
+        err = _max_err(got[live], np.asarray(lax(*args))[live])
     return {"shape": [b, h, d], "page": [p, bp], "kv_dtype": kv_dtype,
-            "max_err": round(err, 7), "ok": err < 1e-4,
+            "lengths": lengths.tolist(), "max_err": round(err, 7),
+            "ok": err < 1e-4 and not got[~live].any(),
             "tpu_custom_call":
                 "tpu_custom_call" in _compiled_text(pallas, *args)}
 

@@ -10,8 +10,11 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
              refcounts (prefix sharing, copy-on-write fork)
   attention  page-table attention kernels: a gather-based lax kernel
              over the rows as stored (the query spread over the heads'
-             lanes) and a scalar-prefetch Pallas flash kernel
-             (MXNET_DECODE_KERNEL=lax|pallas); sparse selection inside
+             lanes) and a Pallas kernel of the same arithmetic that
+             reads each row's live pages in place, several a block
+             with the next block's copies in flight: the default on a
+             TPU (MXNET_DECODE_KERNEL=lax|pallas, unset: by the
+             backend); sparse selection inside
              paged attention (an index score over every cached token,
              an exact top-k, attention over the selected rows only)
   model      the MODEL CONTRACT and its first instance: a

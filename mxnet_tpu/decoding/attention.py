@@ -27,9 +27,12 @@ Every shape is a function of (max_batch, pages_bucket) only — never of
 actual sequence lengths — so the engine pre-traces one program per
 pages bucket and steady-state decode provably adds zero traces.
 
-Two implementations behind `MXNET_DECODE_KERNEL`:
+Two implementations behind `MXNET_DECODE_KERNEL`. Unset, the backend
+decides (`decoding.config.kernel()`, the one place): `pallas` on a TPU,
+`lax` elsewhere — interpreted, the kernel would make every CPU test of
+the tier crawl.
 
-  lax     (default) gather the Bp pages per row into a contiguous
+  lax     gather the Bp pages per row into a contiguous
           (B, Bp*P, H*D) context in the pool's storage type and run
           masked softmax attention over the rows AS STORED: the query
           is spread over the heads' lanes ((B, H, H*D), head h's
@@ -40,23 +43,38 @@ Two implementations behind `MXNET_DECODE_KERNEL`:
           on the chip, and XLA made the split as a padded float32 copy
           of the whole context, four times its bytes, written and read
           back in every layer (95% of OPT-1.3B's decode step). Pure
-          lax, runs anywhere. The MULTI-query variant (tail prefill,
-          verify) keeps the split: its S queries share one context, so
-          the products dominate and a spread query would do H times
-          the work.
-  pallas  flash-style online-softmax kernel on a (B, Bp) grid whose
-          K/V block index maps read the page table via scalar
-          prefetch (PrefetchScalarGridSpec) — pages stream HBM->VMEM
-          per grid step instead of materializing the gathered
-          context. Compiled on a TPU, interpreted elsewhere.
+          lax, runs anywhere: the CPU's form and the reference the
+          kernel is tested against. It writes the context out and
+          reads it back, and it moves the BUCKET, not the rows: six
+          passes over Bp*P tokens a row whatever the rows hold (28 of
+          the 34 ms of OPT-1.3B's decode step on a v5e). The
+          MULTI-query variant (tail prefill, verify) keeps the split:
+          its S queries share one context, so the products dominate
+          and a spread query would do H times the work.
+  pallas  the same arithmetic on the pages WHERE THEY LIE: a grid over
+          the rows, each row's own blocks of `_BLOCK_TOKENS` tokens
+          walked by a loop as long as the row, a block's pages copied
+          HBM->VMEM one `make_async_copy` each (page ids, lengths and
+          the layer by scalar prefetch) with the NEXT block's copies —
+          the same row's, or the next live row's first — started
+          before the block is computed. No page a row does not own is
+          read, no gathered context exists, an empty row costs
+          nothing. Online softmax in float32 across blocks; the value
+          product keeps the float32 weights unrounded as the lax form
+          does (`_paged_attn_kernel`). 0.155 ms a layer where the lax
+          form takes 1.16 at OPT-1.3B's chat mix (48 rows, bucket 48,
+          28% of the page slots live; PERF.md section 6, PR 32), and
+          ahead of it at every bucket measured, a full p16 included:
+          ONE path on a TPU, no choice by shape. Compiled on a TPU,
+          interpreted elsewhere.
 
-The switch is read by `decoding.config.kernel()`. The
-`ragged_paged_attention_*` entries below serve MIXED
+The `ragged_paged_attention_*` entries below serve MIXED
 prefill+decode batches for the merged-step engine
 (MXNET_DECODE_MERGED_STEP).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -236,148 +254,255 @@ def sparse_latent_attention(q, latent, page_table, selected, q_pos,
 
 
 # ---------------------------------------------------------------- pallas
-def _paged_attn_kernel(page_size, heads, quantized):
-    """Kernel body on a (B, Bp) grid: one (page, row) tile per step,
-    online-softmax accumulated in VMEM scratch across the Bp axis.
+# tokens a block of pages holds, where the bucket allows: one MXU tile
+# of keys, and at OPT-1.3B's row (2048 bf16) 2 x (K, V) x 512 KB of VMEM
+_BLOCK_TOKENS = 128
 
-    A page lands in VMEM as the pool stores it, (P, H*D): every head
-    of a token side by side on the lanes. Mosaic will not split the
-    lane dimension into (H, D) for D under 128, so the heads are never
-    split: the elementwise product q*K is summed per head by a matmul
-    with the 0/1 segment matrix `seg` (H, H*D) (seg[h, j] = 1 where
-    lane j belongs to head h), giving scores (P, H); the same matrix
-    spreads the softmax weights (P, H) back over their head's lanes
-    for the product with V. Running max and sum stay (1, H), the
-    accumulator (1, H*D). Quantized pools carry two extra scale refs
-    (P, H), one per K/V page, applied to the scores and to the weights
-    — per (slot, head), the same arithmetic as dequantizing the page —
-    so the pool is never upcast in HBM, which is the whole point of
-    int8 pages."""
+
+def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
+                       quantized):
+    """Kernel body on a grid over the batch rows: a row's own blocks of
+    `block_pages` pages are walked by a loop whose trip count is the
+    row's length, so neither a padding page nor an empty row costs a
+    step.
+
+    The pools stay in HBM (`pl.ANY`). A block's pages are copied as
+    they are stored, page (P, H*D) by page, by one `make_async_copy`
+    each from `pool[layer, page_table[b, i*G + j]]` into slot j of a
+    (2, G*P, H*D) VMEM buffer: the page ids, lengths and the layer come
+    by scalar prefetch. Before a block is computed the copies of the
+    NEXT block that has work (this row's next, else the next live
+    row's first) are started into the other buffer; the first block of
+    the call is started at grid step 0. Slots past a row's
+    `ceil(length / P)` pages are neither copied nor waited for: they
+    hold what an earlier block left, which the position mask keeps out
+    of the scores and whose weights are exactly 0 (the V buffers are
+    zeroed once, so what is left is finite pool content).
+
+    The arithmetic is `paged_attention_lax`'s: the query spread over
+    its heads' lanes, (H, H*D), scores as ONE product with the block
+    (bf16 and int8-as-bf16 operands exact in one pass, float32 ones at
+    `highest`), the softmax online in float32 across blocks, and the
+    value product with the float32 weights unrounded, head h keeping
+    its own lanes of the (H, H*D) accumulator at the end. Against bf16
+    (or int8) values the weights are split into three bf16 terms,
+    stacked (3H, G*P), so ONE pass over the block keeps exactly the
+    products that `highest` keeps of a float32 weight and a value whose
+    low terms are zero; a float32 pool takes `highest` itself. An int8
+    pool's scales, (H, G*P) a block, go onto the scores and weights."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    def per_head(x, seg):
-        # (R, H*D) -> (R, H): sum each head's lanes
-        return jax.lax.dot_general(
-            x, seg, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-    def over_lanes(x, seg):
-        # (R, H) -> (R, H*D): repeat each head's value over its lanes
-        return jnp.dot(x, seg, precision=jax.lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
+    g, p = block_pages, page_size
+    gp = g * p
+    highest = jax.lax.Precision.HIGHEST
+    # named, so that a caller's `jax.default_matmul_precision` cannot
+    # ask Mosaic for float32 passes over bf16 operands (it refuses)
+    one_pass = jax.lax.Precision.DEFAULT
 
     def kernel(pt_ref, len_ref, layer_ref, q_ref, *refs):
-        if quantized:
-            k_ref, ks_ref, v_ref, vs_ref = refs[:4]
-        else:
-            k_ref, v_ref = refs[:2]
-        o_ref, acc_ref, m_ref, l_ref = refs[-4:]
-        i = pl.program_id(1)
-        nbp = pl.num_programs(1)
+        k_hbm, v_hbm = refs[:2]
+        ks_ref, vs_ref = refs[2:4] if quantized else (None, None)
+        o_ref, k_buf, v_buf, acc_ref, sems, slot_ref = refs[-6:]
         b = pl.program_id(0)
+        layer = layer_ref[0]
 
-        @pl.when(i == 0)
-        def _init():
+        def length_of(row):
+            # a length past the table reads the table's pages, as the
+            # lax form's mask over the gathered context does
+            return jnp.minimum(len_ref[row], bucket * p)
+
+        length = length_of(b)
+
+        def copies(row, blk, slot, act):
+            # start (or wait for) the copies of the pages row `row`
+            # owns in its block `blk`: the same descriptors both times
+            first = blk * g
+            owned = jnp.minimum(pl.cdiv(length_of(row), p) - first, g)
+
+            def one(j, _):
+                page = pt_ref[row, first + j]
+                at = pl.ds(pl.multiple_of(j * p, p), p)
+                for n, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    act(pltpu.make_async_copy(
+                        hbm.at[layer, page], buf.at[slot, at],
+                        sems.at[n, slot]))
+
+            jax.lax.fori_loop(0, owned, one, None)
+
+        def start(row, blk, slot):
+            copies(row, blk, slot, lambda c: c.start())
+
+        def start_first_block_from(row, slot):
+            # the first live row at or after `row`, if there is one
+            nxt = jax.lax.while_loop(
+                lambda r: jnp.logical_and(
+                    r < rows, len_ref[jnp.minimum(r, rows - 1)] == 0),
+                lambda r: r + 1, row)
+
+            @pl.when(nxt < rows)
+            def _():
+                start(nxt, 0, slot)
+
+        @pl.when(b == 0)
+        def _first():
+            v_buf[...] = jnp.zeros_like(v_buf)
+            slot_ref[0] = 0
+            start_first_block_from(0, 0)
+
+        @pl.when(length == 0)
+        def _empty():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(length > 0)
+        def _row():
+            hd = q_ref.shape[-1]
+            d = hd // heads
+            own = (jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1) // d
+                   == jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0))
+            ct = jnp.promote_types(
+                q_ref.dtype,
+                jnp.bfloat16 if quantized else k_buf.dtype)
+            # (H, H*D); the select in float32: Mosaic has no relayout
+            # of the 32-bit mask for 16-bit operands
+            q_heads = jnp.where(own, q_ref[0].astype(jnp.float32),
+                                0).astype(ct)
+            blocks = pl.cdiv(length, gp)
             acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
 
-        qb = q_ref[0].astype(jnp.float32)          # (1, H*D)
-        kb = k_ref[0, 0].astype(jnp.float32)       # (P, H*D)
-        vb = v_ref[0, 0].astype(jnp.float32)
-        hd = qb.shape[-1]
-        d = hd // heads
-        seg = (jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1) // d
-               == jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0)
-               ).astype(jnp.float32)               # (H, H*D)
-        s = per_head(qb * kb, seg) * (1.0 / math.sqrt(d))   # (P, H)
-        if quantized:
-            s = s * ks_ref[0, 0]
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(pos < len_ref[b], s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]         # (1, H)
-        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        e = jnp.exp(s - m_new)                          # (P, H)
-        l_ref[...] = l_prev * corr + e.sum(axis=0, keepdims=True)
-        w = e * vs_ref[0, 0] if quantized else e
-        # one spread for the weights and the correction: (P + 1, H)
-        spread = over_lanes(jnp.concatenate([w, corr], axis=0), seg)
-        acc_ref[...] = acc_ref[...] * spread[page_size:] + jnp.sum(
-            spread[:page_size] * vb, axis=0, keepdims=True)
-        m_ref[...] = m_new
+            def block(i, carry):
+                m_prev, l_prev = carry                  # (H, 1) float32
+                slot = slot_ref[0]
 
-        @pl.when(i == nbp - 1)
-        def _flush():
-            norm = over_lanes(1.0 / l_ref[...], seg)
-            o_ref[0] = (acc_ref[...] * norm).astype(o_ref.dtype)
+                @pl.when(i + 1 < blocks)
+                def _():
+                    start(b, i + 1, 1 - slot)
+
+                @pl.when(i + 1 == blocks)
+                def _():
+                    start_first_block_from(b + 1, 1 - slot)
+
+                slot_ref[0] = 1 - slot
+                copies(b, i, slot, lambda c: c.wait())
+                s = jax.lax.dot_general(
+                    q_heads, k_buf[slot].astype(ct),
+                    (((1,), (1,)), ((), ())),
+                    precision=highest if ct == jnp.float32 else one_pass,
+                    preferred_element_type=jnp.float32) * scale  # (H, G*P)
+                if quantized:
+                    s = s * ks_ref[0, i]
+                pos = i * gp + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = jnp.where(pos < length, s, NEG_INF)
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                e = jnp.exp(s - m_new)
+                l_new = l_prev * corr + e.sum(axis=1, keepdims=True)
+                w = e * vs_ref[0, i] if quantized else e
+                v = v_buf[slot]
+                if v.dtype == jnp.float32:
+                    pv = jnp.dot(w, v, precision=highest,
+                                 preferred_element_type=jnp.float32)
+                else:
+                    terms, rest = [], w
+                    for _ in range(3):
+                        terms.append(rest.astype(jnp.bfloat16))
+                        rest = rest - terms[-1].astype(jnp.float32)
+                    pv = jnp.dot(jnp.concatenate(terms, axis=0),
+                                 v.astype(jnp.bfloat16), precision=one_pass,
+                                 preferred_element_type=jnp.float32)
+                    pv = pv[:heads] + pv[heads:2 * heads] + pv[2 * heads:]
+                acc_ref[...] = acc_ref[...] * corr + pv
+                return m_new, l_new
+
+            _, l_end = jax.lax.fori_loop(
+                0, blocks, block,
+                (jnp.full((heads, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((heads, 1), jnp.float32)))
+            out = jnp.where(own, acc_ref[...] / l_end, 0)
+            o_ref[0] = out.sum(axis=0, keepdims=True).astype(o_ref.dtype)
 
     return kernel
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
-                           scale=None):
-    """Flash-style paged kernel; the layer index and the page ids
-    drive the K/V block index maps through scalar prefetch, so only
-    the pages a row actually owns ever move HBM->VMEM, straight from
-    the pool as it is stored. A quantized pool stores a page's scales
-    as one row of page_size*heads lanes, which Mosaic cannot turn into
-    the (P, H) the scores want; they come as a per-call view of the
-    GATHERED scale rows (B, Bp, P, H) — 1/head_dim of the context's
-    bytes — never of the pool. Compiled on a TPU, interpreted
-    elsewhere (utils.pallas_interpret)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+                           scale=None, block_pages=None):
+    """The in-place kernel (`_paged_attn_kernel`): only the pages a
+    row owns ever move HBM->VMEM, straight from the pool as it is
+    stored, `block_pages` of them a block (default: 128 tokens' worth,
+    at most the bucket). A row of length 0 returns zeros. Compiled on
+    a TPU, interpreted elsewhere (utils.pallas_interpret)."""
     k_pages = _quant.as_layer(k_pages)
     v_pages = _quant.as_layer(v_pages)
     b, h, d, p, bp = _check_shapes(
         q, k_pages, v_pages, page_table, lengths)
-    if scale is not None and not math.isclose(
-            scale, 1.0 / math.sqrt(d)):
-        raise ValueError(
-            "pallas kernel hard-codes scale=1/sqrt(head_dim)")
-    quantized = k_pages.pool.scale is not None
-
-    def page_spec(width):
-        return pl.BlockSpec(
-            (1, 1, p, width),
-            lambda bb, i, pt, ln, ly: (ly[0], pt[bb, i], 0, 0))
-
-    row_spec = pl.BlockSpec((1, 1, h * d),
-                            lambda bb, i, pt, ln, ly: (bb, 0, 0))
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    g = min(block_pages or max(1, _BLOCK_TOKENS // p), bp)
     # one prefetched index serves both pools: every caller reads the
     # same layer of K and V
     layer = jnp.asarray(k_pages.index, jnp.int32).reshape(1)
-    in_specs = [row_spec]
-    operands = [q.reshape(b, 1, h * d)]
-    for layer_ in (k_pages, v_pages):
-        in_specs.append(page_spec(h * d))
-        operands.append(layer_.pool.data)
-        if quantized:
+    return _paged_call(q, k_pages.pool, v_pages.pool, page_table, lengths,
+                       layer, scale=float(scale), block_pages=g,
+                       interpret=_utils.pallas_interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "block_pages", "interpret"))
+def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
+                block_pages, interpret):
+    """The kernel's call, a jitted function of its own with the layer
+    a traced index: a decode program's 24 layers then share ONE trace
+    and ONE lowering of the kernel (Mosaic's lowering runs in every
+    process, whatever jax's compile cache holds: 15 s a program of 24
+    separate calls on the chip's host, 0.6 s so). A quantized pool
+    stores a page's scales as one row of page_size*heads lanes, which
+    Mosaic cannot turn into the (H, G*P) the scores want; they come as
+    a per-call view of the GATHERED scale rows, (B, blocks, H, G*P) —
+    1/head_dim of the context's bytes — never of the pool."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    p = k_pool.page_size
+    bp = page_table.shape[1]
+    g = block_pages
+    blocks = -(-bp // g)
+    quantized = k_pool.scale is not None
+    row_spec = pl.BlockSpec((1, 1, h * d),
+                            lambda bb, pt, ln, ly: (bb, 0, 0))
+    pools = (k_pool, v_pool)
+    in_specs = [row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands = [q.reshape(b, 1, h * d)] + [pool.data for pool in pools]
+    if quantized:
+        pad = [(0, 0), (0, blocks * g - bp), (0, 0)]
+        for pool in pools:
+            rows_ = jnp.pad(pool.scale[layer[0], page_table], pad)
+            operands.append(rows_.reshape(b, blocks, g * p, h)
+                            .transpose(0, 1, 3, 2))
             in_specs.append(pl.BlockSpec(
-                (1, 1, p, h), lambda bb, i, pt, ln, ly: (bb, i, 0, 0)))
-            operands.append(
-                layer_.pool.scale[layer_.index, page_table].reshape(
-                    b, bp, p, h))
+                (1, blocks, h, g * p),
+                lambda bb, pt, ln, ly: (bb, 0, 0, 0)))
+    stored = k_pool.data.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,   # page_table, lengths, layer
-        grid=(b, bp),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, h * d), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
+            pltpu.VMEM((2, g * p, h * d), stored),          # K blocks
+            pltpu.VMEM((2, g * p, h * d), stored),          # V blocks
+            pltpu.VMEM((h, h * d), jnp.float32),            # accumulator
+            pltpu.SemaphoreType.DMA((2, 2)),                # (K|V, buffer)
+            pltpu.SMEM((1,), jnp.int32),                    # buffer in use
         ],
     )
     fn = pl.pallas_call(
-        _paged_attn_kernel(p, h, quantized),
+        _paged_attn_kernel(b, bp, h, p, g, scale, quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
-        interpret=_utils.pallas_interpret(),
+        interpret=interpret,
         name="paged_attention",
     )
     return fn(page_table, lengths, layer, *operands).reshape(b, h, d)
@@ -405,10 +530,10 @@ def ragged_paged_attention_lax(q, k_pages, v_pages, page_table,
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_table,
                                   lengths, scale=None):
-    """Ragged mixed prefill+decode batch through the flash-style paged
-    kernel — same per-row length masking as the lax twin (see
-    `ragged_paged_attention_lax`), pages streamed HBM->VMEM via the
-    scalar-prefetch page table."""
+    """Ragged mixed prefill+decode batch through the in-place kernel —
+    same per-row length masking as the lax twin (see
+    `ragged_paged_attention_lax`), each row's live pages copied
+    HBM->VMEM from where the page table says they lie."""
     return paged_attention_pallas(q, k_pages, v_pages, page_table,
                                   lengths, scale=scale)
 

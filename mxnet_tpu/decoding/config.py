@@ -28,7 +28,14 @@ def page_buckets():
 
 
 def kernel():
-    return str(utils.getenv("MXNET_DECODE_KERNEL"))
+    """The single-query paged attention. Unset, the backend decides
+    (the fact `utils.pallas_interpret` reads): the in-place kernel on a
+    TPU, the lax form elsewhere, where the kernel would be interpreted
+    and every test of the tier would crawl. An explicit value wins."""
+    named = str(utils.getenv("MXNET_DECODE_KERNEL"))
+    if named:
+        return named
+    return "lax" if utils.pallas_interpret() else "pallas"
 
 
 def merged_step():

@@ -354,7 +354,10 @@ def decode_logits(params, tokens, k_pages, v_pages, page_table,
         page_table[rows, jnp.clip(lengths // page_size, 0, bp - 1)],
         SCRATCH_PAGE)
     slots = lengths % page_size
-    ctx_len = jnp.where(active, lengths + 1, 1)
+    # an inactive slot attends nothing: the in-place kernel starts no
+    # copy for it and returns zeros, the lax form the mean of what it
+    # gathered (finite); the engine discards the row either way
+    ctx_len = jnp.where(active, lengths + 1, 0)
 
     clips = jnp.int32(0)
     with jax.named_scope("embed"):

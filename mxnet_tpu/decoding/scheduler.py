@@ -912,6 +912,9 @@ class ContinuousScheduler:
                 # a roofline of the attention kernel is owed
                 "ctx_tokens": int(lengths[active].sum())
                 + int(active.sum()) * (k + 1),
+                # pages those positions lie in: what the in-place
+                # kernel copies, of the `rows x bucket` it is given
+                "live_pages": self._live_pages(lengths, active, k + 1),
                 "program": engine.step_program(bucket)}
         with _trace.span("decoding.step", **step_attrs) as step_span:
             t0 = _trace.now()
@@ -929,7 +932,8 @@ class ContinuousScheduler:
             emitted = self._emit(live, tail_rows, out,
                                  n_emit if spec else None)
             emit_span.note(tokens=emitted)
-            self.stats.note_step(emitted, dt)
+            self.stats.note_step(emitted, dt, step_attrs["live_pages"],
+                                 engine.step_rows * bucket)
             self.stats.note_pool()
             if engine._guard and self.stats.steps % 16 == 0:
                 # interval drain of the numerics guard (one fetch per
@@ -941,6 +945,12 @@ class ContinuousScheduler:
                         self.stats.note_nonfinite(nf)
                     if clips:
                         self.stats.note_quant_clips(clips)
+
+    def _live_pages(self, lengths, active, new):
+        """Pages that hold the context a step's attention reads: each
+        active row's `lengths + new` positions, in whole pages."""
+        reach = lengths[active] + new
+        return int((-(-reach // self.engine.page_size)).sum())
 
     def _pack(self, live):
         """The step's fixed-shape row arrays: returns ((tokens, table,
@@ -1104,6 +1114,7 @@ class ContinuousScheduler:
                 "model": self.key, "live": len(live), "bucket": bucket,
                 "ctx_tokens": int(lengths[active].sum())
                 + int(active.sum()),
+                "live_pages": self._live_pages(lengths, active, 1),
                 "program": engine.step_program(bucket)}
             if self._ahead:
                 tokens = engine.next_tokens(self._ahead[-1][1])
@@ -1138,7 +1149,8 @@ class ContinuousScheduler:
             # the step's own seconds: from its launch, or from the
             # step before's tokens where it waited behind that
             self.stats.note_step(
-                emitted, t_out - max(t_launch, self._t_retired))
+                emitted, t_out - max(t_launch, self._t_retired),
+                attrs["live_pages"], engine.step_rows * attrs["bucket"])
             self._t_retired = t_out
             self.stats.note_pool()
             if engine._guard and self.stats.steps % 16 == 0:

@@ -14,6 +14,11 @@ TOKENS and PAGES, the units continuous batching actually schedules:
   preemptions/readmissions  sequences evicted for pages and brought
                             back (re-prefilled) — nonzero is healthy
                             under overload, a crash is not
+  live_page_share           live_pages / bucket_pages: pages the
+                            steps' contexts lay in over the page slots
+                            (rows x bucket) their programs were given —
+                            how much of a step's bucket the in-place
+                            attention kernel has to read
   p50/p95/p99_token_ms      per-token decode latency
   traces_since_warmup       decode/prefill traces after warmup —
                             MUST stay 0 in steady state (the decode
@@ -137,6 +142,10 @@ class DecodeStats:
             self.prefill_tokens = 0
             self.decode_tokens = 0
             self.steps = 0
+            # pages the steps' contexts lay in, of the page slots
+            # (rows x bucket) their programs were given
+            self.live_pages = 0
+            self.bucket_pages = 0
             self.nonfinite_logit_steps = 0
             self.nonfinite_logits = 0
             self.quant_clip_steps = 0
@@ -202,11 +211,16 @@ class DecodeStats:
         _TOKENS.inc(tokens, phase="prefill", model=self._key)
         _PREFILL_LATENCY_MS.observe(seconds * 1e3, model=self._key)
 
-    def note_step(self, live_rows, seconds):
-        """One continuous-decode step: `live_rows` tokens emitted."""
+    def note_step(self, live_rows, seconds, live_pages=0,
+                  bucket_pages=0):
+        """One continuous-decode step: `live_rows` tokens emitted, its
+        context in `live_pages` pages of the program's
+        `bucket_pages` slots."""
         with self._lock:
             self.steps += 1
             self.decode_tokens += live_rows
+            self.live_pages += live_pages
+            self.bucket_pages += bucket_pages
             self._decode_s += seconds
             if live_rows:
                 per_tok = seconds / live_rows
@@ -297,6 +311,11 @@ class DecodeStats:
                 "prefill_tokens": self.prefill_tokens,
                 "decode_tokens": self.decode_tokens,
                 "steps": self.steps,
+                "live_pages": self.live_pages,
+                "bucket_pages": self.bucket_pages,
+                "live_page_share": round(
+                    self.live_pages / self.bucket_pages, 4)
+                if self.bucket_pages else 0.0,
                 "nonfinite_logit_steps": self.nonfinite_logit_steps,
                 "nonfinite_logits": self.nonfinite_logits,
                 "quant_clip_steps": self.quant_clip_steps,

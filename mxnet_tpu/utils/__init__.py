@@ -309,11 +309,15 @@ register_env(
     "powers of two up to the pool-derived per-sequence maximum.",
 )
 register_env(
-    "MXNET_DECODE_KERNEL", str, "lax",
-    "decoding: page-table attention implementation: 'lax' (gather + "
-    "masked softmax, runs anywhere) or 'pallas' (flash-style online-"
-    "softmax kernel whose K/V block index maps read the page table "
-    "via scalar prefetch; interpret-mode on CPU).",
+    "MXNET_DECODE_KERNEL", str, "",
+    "decoding: single-query page-table attention implementation. "
+    "Unset, the backend decides: 'pallas' on a TPU, 'lax' elsewhere. "
+    "'lax' gathers a row's pages into a context and runs masked "
+    "softmax attention over it (runs anywhere; the CPU's form and the "
+    "kernel's reference). 'pallas' reads each row's live pages in "
+    "place, several pages a block with the next block's copies in "
+    "flight, and does no work for pages a row does not own "
+    "(interpreted off the TPU).",
 )
 register_env(
     "MXNET_DECODE_MERGED_STEP", bool, True,

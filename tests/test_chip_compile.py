@@ -172,6 +172,23 @@ def test_paged_attention_compiles(compile_for_chip, kv_dtype, bucket):
     assert not _pool_sized_ops(text, args[1].data.shape)
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_attention_compiles_under_a_global_precision(
+        compile_for_chip, kv_dtype):
+    """bf16 activations on bf16 and int8 pools (the served case), with
+    the caller's default matmul precision at `highest` as
+    `chip_smoke.py`'s serve phase sets it: the kernel's one-pass
+    products name their precision, so Mosaic is not asked for float32
+    passes over bf16 operands (which it refuses: found on the chip in
+    PR 32, where the float32-query cases above had passed)."""
+    q, *rest = _paged_args(kv_dtype, FULL["serve"]["max_batch"],
+                           FULL["serve"]["page_buckets"][-1])
+    with jax.default_matmul_precision("highest"):
+        text = compile_for_chip(_on_layer(paged.paged_attention_pallas),
+                                _s(q.shape, jnp.bfloat16), *rest)
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
 def test_ragged_paged_attention_compiles(compile_for_chip, kv_dtype):
     """The merged step's shape: max_batch decode rows plus a page of
@@ -192,7 +209,7 @@ CELL = dict(pages=1152, page_size=16, heads=32, head_dim=64, rows=48,
 SPEC_K = 4
 
 
-def _cell_engine(**kw):
+def _cell_engine(kernel="lax", **kw):
     from mxnet_tpu import decoding as dec
 
     c = CELL
@@ -204,7 +221,7 @@ def _cell_engine(**kw):
     eng = dec.DecodeEngine(
         {}, cfg, max_batch=c["rows"], page_size=c["page_size"],
         num_pages=c["bucket"] + 1, page_buckets=(c["bucket"],),
-        kernel="lax", prefix_cache=True, **kw)
+        kernel=kernel, prefix_cache=True, **kw)
     eng._donate = True
     return eng
 
@@ -335,6 +352,37 @@ def test_single_query_program_attends_the_rows_as_stored(
     if kv_dtype == "bf16":
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp <= 0.25 * 2 ** 30, temp
+
+
+@pytest.mark.parametrize("program,kv_dtype", [
+    ("decode", "bf16"), ("decode", "int8"), ("merged", "bf16")])
+def test_kernel_program_holds_no_gathered_context(
+        v5e, compile_for_chip, program, kv_dtype):
+    """With the in-place kernel (the tier's default on a TPU) a
+    single-query program holds one `tpu_custom_call` a layer and NO
+    array of a gathered context's size in any type (`[2304,16,2048]`
+    at the cell's decode step): the pages are read where they lie. Its
+    temporaries are under the lax form's, which holds one gathered
+    context at a time. (`compile_for_chip` is here for what it patches:
+    the kernel compiled, not interpreted.)"""
+    c = CELL
+    merged = program == "merged"
+    program = "decode" if merged else program
+    temps = {}
+    for kernel in ("pallas", "lax"):
+        eng = _cell_engine(kernel, merged_step=merged)
+        compiled, pool = _compile_cell_program(v5e, eng, program,
+                                               kv_dtype, c["pages"])
+        temps[kernel] = compiled.memory_analysis().temp_size_in_bytes
+        if kernel == "lax":
+            continue
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= c["layers"]
+        context = (eng.step_rows * c["bucket"] * c["page_size"]
+                   * c["heads"] * c["head_dim"])
+        assert not _context_sized_arrays(text, context, pool.data.size)
+        assert not _pool_sized_ops(text, pool.data.shape)
+    assert temps["pallas"] < temps["lax"], temps
 
 
 # the sparse latent block at its published attention widths (a 576-wide

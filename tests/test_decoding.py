@@ -8,6 +8,7 @@ zero-retrace guarantee over the pre-traced decode grid, and the
 import random
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -193,8 +194,6 @@ def test_paged_attention_kernels_match_dense():
     v_rows = v_pages.reshape(n, p, h * d)
     out_lax = np.asarray(dec.paged_attention_lax(
         q, k_rows, v_rows, table, lengths))
-    out_pls = np.asarray(dec.paged_attention_pallas(
-        q, k_rows, v_rows, table, lengths))
 
     # dense oracle: gather each row's true context and softmax it
     scale = 1.0 / np.sqrt(d)
@@ -207,7 +206,6 @@ def test_paged_attention_kernels_match_dense():
         w = e / e.sum(axis=-1, keepdims=True)
         ref = np.einsum("ht,thd->hd", w, ctx_v[:ln])
         np.testing.assert_allclose(out_lax[row], ref, atol=1e-5)
-        np.testing.assert_allclose(out_pls[row], ref, atol=1e-5)
 
 
 @pytest.mark.parametrize("kv_dtype,q_dtype,atol", [
@@ -225,8 +223,8 @@ def test_single_query_lax_attends_stored_rows(kv_dtype, q_dtype, atol):
     and each page's slots past its row's length: K rows (an int8
     pool's K scales) NaN, V rows and scales 1e30 (a weight of exactly 0
     times 1e30 is 0; times NaN it would be NaN in any kernel) — so a
-    masked row read into a result shows. The Pallas kernel, interpreted
-    here, has to agree on the same pools."""
+    masked row read into a result shows. (The in-place kernel against
+    this form: `test_in_place_kernel_matches_lax` below.)"""
     from mxnet_tpu.decoding import quant
 
     rs = np.random.RandomState(11)
@@ -271,9 +269,96 @@ def test_single_query_lax_attends_stored_rows(kv_dtype, q_dtype, atol):
                         held["v"][row])
         np.testing.assert_allclose(out[row], ref, atol=atol,
                                    err_msg=f"row {row}")
-    out_pls = np.asarray(dec.paged_attention_pallas(
-        q, *layers, table, lengths), np.float64)
-    np.testing.assert_allclose(out, out_pls, atol=atol)
+
+
+# the in-place kernel, interpreted: page_size 4, blocks of 2 pages, a
+# bucket of 5 pages (not a multiple of the block). Lengths: an empty
+# row, one token, exactly one page, a block's edge, one past it, two
+# blocks, the full bucket, and empty rows between and after live ones
+_KERNEL_LENGTHS = (0, 1, 4, 8, 9, 0, 16, 20, 13, 0)
+
+
+def _ragged_pools(kv_dtype, lengths, page, bucket, heads, dim, seed,
+                  foreign):
+    """(K layer, V layer, page table) of two-layer pools holding
+    `lengths` tokens a row on layer 1. Every slot no row may read —
+    pages no row owns, the scratch page the table's padding points at,
+    and the slots of owned pages past each length — holds `foreign`
+    values (seeded), finite as pool contents are."""
+    from mxnet_tpu.decoding import quant
+
+    rs = np.random.RandomState(seed)
+    n = 1 + sum(pages_needed(ln, page) for ln in lengths) + 6
+    fs = np.random.RandomState(foreign)
+    pools = []
+    for _ in "kv":
+        pool = quant.make_pool((2, n, page, heads, dim), kv_dtype)
+        pool, _ = quant.kv_scatter(
+            pool, 1, np.repeat(np.arange(n), page),
+            np.tile(np.arange(page), n),
+            jnp.asarray(3.0 * fs.randn(n * page, heads, dim), jnp.float32))
+        pools.append(pool)
+    table = np.zeros((len(lengths), bucket), np.int32)
+    free = iter(rs.permutation(np.arange(1, n)))
+    for row, ln in enumerate(lengths):
+        pages = np.asarray(
+            [next(free) for _ in range(pages_needed(ln, page))], np.int32)
+        table[row, :len(pages)] = pages
+        at = np.arange(ln)
+        for i in range(2):
+            pools[i], _ = quant.kv_scatter(
+                pools[i], 1, pages[at // page], at % page,
+                jnp.asarray(rs.randn(ln, heads, dim), jnp.float32))
+    return pools[0].layer(1), pools[1].layer(1), table
+
+
+@pytest.mark.parametrize("kv_dtype,q_dtype,atol", [
+    ("float32", "float32", 1e-5), ("bf16", "float32", 1e-5),
+    ("int8", "float32", 1e-5),
+    ("bf16", "bfloat16", 2e-2), ("int8", "bfloat16", 2e-2)])
+@pytest.mark.parametrize("block_pages", [2, None])
+def test_in_place_kernel_matches_lax(kv_dtype, q_dtype, atol, block_pages):
+    """`paged_attention_pallas` against `paged_attention_lax` on the
+    same pools, for every storage type, over `_KERNEL_LENGTHS` (blocks
+    of 2 pages; the default, which the bucket caps: all 5 pages in one
+    block). An empty row comes out zeros (nothing reads it).
+    INDEPENDENCE: pools that differ only in what no row owns — foreign
+    pages, the scratch page, an owned page's slots past the length —
+    give the same output to the last bit: nothing a row does not own
+    reaches a sum."""
+    h, d, page, bucket = 4, 8, 4, 5
+    lengths = np.asarray(_KERNEL_LENGTHS, np.int32)
+    q = jnp.asarray(np.random.RandomState(5).randn(len(lengths), h, d),
+                    q_dtype)
+    outs = []
+    for foreign in (101, 202):
+        k, v, table = _ragged_pools(kv_dtype, lengths, page, bucket, h, d,
+                                    seed=17, foreign=foreign)
+        outs.append(np.asarray(dec.paged_attention_pallas(
+            q, k, v, table, lengths, block_pages=block_pages), np.float32))
+    live = lengths > 0
+    ref = np.asarray(dec.paged_attention_lax(q, k, v, table, lengths),
+                     np.float32)
+    assert np.isfinite(outs[0]).all()
+    np.testing.assert_allclose(outs[0][live], ref[live], atol=atol)
+    np.testing.assert_array_equal(outs[0][~live], 0.0)
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
+def test_in_place_kernel_reads_no_page_past_the_table():
+    """A length past the bucket (a row at its cap) attends the table's
+    pages and no further, as the lax form's mask over the gathered
+    context does: the page ids past the table are not there to read."""
+    h, d, page, bucket = 4, 8, 4, 5
+    k, v, table = _ragged_pools("float32", [bucket * page, 7], page,
+                                bucket, h, d, seed=3, foreign=4)
+    q = jnp.asarray(np.random.RandomState(6).randn(2, h, d), jnp.float32)
+    past = np.asarray([bucket * page + 3, 7], np.int32)
+    np.testing.assert_allclose(
+        np.asarray(dec.paged_attention_pallas(q, k, v, table, past,
+                                              block_pages=2)),
+        np.asarray(dec.paged_attention_lax(q, k, v, table, past)),
+        atol=1e-5)
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "bf16", "int8"])
@@ -342,6 +427,24 @@ def test_get_kernel():
     assert dec.get_kernel("pallas") is dec.paged_attention_pallas
     with pytest.raises(ValueError):
         dec.get_kernel("nope")
+
+
+@pytest.mark.parametrize("named,backend,want", [
+    (None, "cpu", "lax"), (None, "tpu", "pallas"),
+    ("lax", "tpu", "lax"), ("pallas", "cpu", "pallas")])
+def test_default_kernel_follows_the_backend(monkeypatch, named, backend,
+                                            want):
+    """With MXNET_DECODE_KERNEL unset the backend decides — the lax
+    form off the TPU, where the kernel would be interpreted, the
+    in-place kernel on one — and an explicit value wins."""
+    from mxnet_tpu.decoding import config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if named is None:
+        monkeypatch.delenv("MXNET_DECODE_KERNEL", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_DECODE_KERNEL", named)
+    assert config.kernel() == want
 
 
 def test_kernel_switch_is_read_from_the_environment(monkeypatch):
@@ -643,6 +746,7 @@ def test_decoding_stats_view_shape_pinned():
             "submitted", "completed", "failed", "rejected", "expired",
             "cancelled", "preemptions", "readmissions", "prefills",
             "prefill_tokens", "decode_tokens", "steps", "prefill_chunks",
+            "live_pages", "bucket_pages", "live_page_share",
             "spec_proposed", "spec_accepted", "spec_acceptance_rate",
             "tokens_per_target_step",
             "nonfinite_logit_steps", "nonfinite_logits",
@@ -984,6 +1088,53 @@ def test_run_ahead_keeps_steps_in_flight_and_tiles_the_turns():
             == {"decoding.step"}, name
     assert sum(s.attrs["tokens"] for s in spans
                if s.name == "decoding.emit") == 23
+
+
+@pytest.mark.parametrize("run_ahead", [0, 3])
+def test_step_spans_and_stats_count_live_pages(run_ahead):
+    """`live_pages` on every `decoding.step` span is the page table's
+    own count: the entries of the step's active rows that hold their
+    `lengths + 1` positions, none of them the scratch page and none
+    left over. `DecodeStats` sums them beside the `rows x bucket` page
+    slots the programs were given."""
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    ttrace.set_capacity(4096)
+    try:
+        m = _model(max_tokens=24, page_buckets=(1, 2, 4, 8),
+                   merged_step=False, run_ahead=run_ahead)
+        seen = []
+        name = "launch_step" if run_ahead else "step"
+        inner = getattr(m.engine, name)
+
+        def step(tokens, table, lengths, active, *samp):
+            seen.append((np.array(table), np.array(lengths),
+                         np.array(active)))
+            return inner(tokens, table, lengths, active, *samp)
+
+        setattr(m.engine, name, step)
+        futs = [m.submit([3, 4, 5, 6, 7], max_new_tokens=20),
+                m.submit([9, 8, 7], max_new_tokens=9)]
+        assert [len(f.result(timeout=120)) for f in futs] == [20, 9]
+        snap = m.stats.snapshot()
+        m.close()
+        spans = [s for s in ttrace.recent_spans()
+                 if s.name == "decoding.step"
+                 and (s.attrs or {}).get("model") == m.key]
+    finally:
+        ttrace.set_capacity(ttrace._env_capacity())
+    assert len(spans) == len(seen) >= 19
+    page = m.engine.page_size
+    for span, (table, lengths, active) in zip(spans, seen):
+        owned = (table[active] != SCRATCH_PAGE).sum(axis=1)
+        np.testing.assert_array_equal(
+            owned, -(-(lengths[active] + 1) // page))
+        assert span.attrs["live_pages"] == owned.sum()
+        assert table.shape[1] == span.attrs["bucket"]
+    assert snap["live_pages"] == sum(s.attrs["live_pages"] for s in spans)
+    assert snap["bucket_pages"] == sum(t.size for t, _, _ in seen)
+    assert snap["live_page_share"] == round(
+        snap["live_pages"] / snap["bucket_pages"], 4)
 
 
 def test_run_ahead_admits_into_a_free_row_with_steps_in_flight():

@@ -379,6 +379,18 @@ def test_rotary_layouts_by_hand():
          1 * s[0] + 3 * c[0], 2 * s[1] + 4 * c[1]], rtol=1e-6)
 
 
+def _instructions_only(text):
+    """A compiled program's instructions alone: the tables of files
+    and stack frames and each instruction's metadata (where in the
+    Python source it was traced) left out."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    return "\n".join(
+        ln for ln in text.splitlines()
+        if ln.strip() and not re.match(r"\s*\d+ ", ln)
+        and ln.strip() not in ("FileNames", "FunctionNames",
+                               "FileLocations", "StackFrames"))
+
+
 # (h) the dense block's decode program is what it was before the engine
 # took a model contract: the same text as the block's own forward jitted
 # with K and V as two arguments, names aside
@@ -403,21 +415,64 @@ def test_dense_decode_program_text_is_unchanged(kv_dtype):
         *args[:2], *args[2], *args[3:]).compile().as_text()
 
     def plain(text):
-        """Instructions alone: the module's name, the tables of files
-        and stack frames and each instruction's metadata (where in the
-        Python source it was traced) left out."""
         text = re.sub(r"jit_?\w*decode_p8", "M", text)
         text = text.replace("pools_0__", "k_pages_").replace(
             "pools_1__", "v_pages_")
-        text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
-        return "\n".join(
-            ln for ln in text.splitlines()
-            if ln.strip() and not re.match(r"\s*\d+ ", ln)
-            and ln.strip() not in ("FileNames", "FunctionNames",
-                                   "FileLocations", "StackFrames"))
+        return _instructions_only(text)
 
     assert plain(eng.decode_program_text(8)) == plain(was)
     assert eng.step_program(8) == "jit_decode_p8"
+
+
+# (i) no choice of the single-query kernel reaches this block's programs:
+# it attends through `sparse_latent_attention` and `quant.gather_rows`
+@pytest.mark.parametrize("named", ["lax", "pallas", None])
+def test_kernel_choice_does_not_reach_the_sparse_programs(
+        monkeypatch, params, named):
+    """The sparse latent decode and chunk programs are the same text
+    under MXNET_DECODE_KERNEL=lax, =pallas and unset on a TPU backend
+    (where the tier's default is the in-place kernel), names aside, and
+    neither form of the single-query paged attention is ever called
+    while they are traced: `dsv32_docsessions_closed` runs the program
+    it ran before the default moved."""
+    from mxnet_tpu.decoding import attention
+
+    def text_of(eng):
+        decode = eng._build_decode_fn(16).lower(
+            *eng._masked_step_args(16)).compile().as_text()
+        chunk = eng._build_chunk_fn(8, 16).lower(
+            eng._params, np.zeros((1, 8), np.int32), jnp.int32(0),
+            jnp.int32(0), eng._pools, np.zeros((16,), np.int32),
+            *eng._samp_scalars()).compile().as_text()
+        return _instructions_only(decode + chunk)
+
+    def build():
+        eng = dec.DecodeEngine(
+            params, config_object(TINY), max_batch=3, page_size=PAGE,
+            num_pages=64, page_buckets=(16,), prefix_cache=True,
+            chunk_buckets=(8,), context_buckets=(16,))
+        eng._donate = False     # the backend's, not the kernel's, to say
+        return eng
+
+    monkeypatch.setenv("MXNET_DECODE_KERNEL", "lax")
+    want = text_of(build())
+
+    def never(*_a, **_k):
+        raise AssertionError("single-query paged attention called")
+
+    for table in (attention._KERNELS, attention._RAGGED_KERNELS):
+        for name in table:
+            monkeypatch.setitem(table, name, never)
+    if named is None:
+        monkeypatch.delenv("MXNET_DECODE_KERNEL")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    else:
+        monkeypatch.setenv("MXNET_DECODE_KERNEL", named)
+    eng = build()
+    assert eng.kernel_name == (named or "pallas")
+    got = text_of(eng)
+    assert got == want
+    assert "paged_attention" not in got
 
 
 def test_planes_of_both_configurations():

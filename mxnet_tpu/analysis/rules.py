@@ -109,10 +109,14 @@ HOT_PATH_MANIFEST = {
         "DecodeEngine.fetch_step", "DecodeEngine.next_tokens",
         "DecodeEngine.spec_step",
         "DecodeEngine.copy_page", "DecodeEngine.pool_stats",
+        "DecodeEngine.prefill_pages", "DecodeEngine.table_shape",
     ),
     # the second block's forwards run inside the jitted chunk-prefill
     # and decode programs: pure jax on traced values
     "mxnet_tpu/decoding/sparse_latent.py": "*",
+    # what the blocks share, and the third block: the same
+    "mxnet_tpu/decoding/layers.py": "*",
+    "mxnet_tpu/decoding/window_mixed.py": "*",
     "mxnet_tpu/decoding/scheduler.py": (
         "ContinuousScheduler._admit", "ContinuousScheduler._grow",
         "ContinuousScheduler._step", "ContinuousScheduler._preempt",
@@ -126,12 +130,18 @@ HOT_PATH_MANIFEST = {
         "ContinuousScheduler._launch_ahead",
         "ContinuousScheduler._retire", "ContinuousScheduler._settle",
         "ContinuousScheduler._pending",
+        "ContinuousScheduler._release_windows",
+        "ContinuousScheduler._grow_side",
+        "ContinuousScheduler._drop_pages",
+        "ContinuousScheduler._step_attrs",
+        "ContinuousScheduler._note_step",
     ),
     "mxnet_tpu/decoding/stats.py": (
         "DecodeStats.note_step", "DecodeStats.note_prefill",
         "DecodeStats.note_preempted", "DecodeStats.note_pool",
         "DecodeStats.note_spec", "DecodeStats.note_prefix_reuse",
         "DecodeStats.note_quant_clips", "DecodeStats.note_counters",
+        "DecodeStats.note_released",
     ),
     # KV quantization (quant PR): quantize-at-scatter / dequantize-at-
     # gather run INSIDE the jitted prefill/decode/attention programs —

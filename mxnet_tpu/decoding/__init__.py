@@ -33,8 +33,17 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
              which they hold (`experts_held`), a shared expert, an
              untied head over the vocabulary slice held here; prompts
              go through the pages in chunks
+  layers     what more than one block is made of: norm, gated
+             feed-forward, rotary positions, the router over all
+             experts and the held experts' grouped computation
+  window_mixed  the third instance (`WindowMixedConfig`): window and
+             full attention layers mixed, each kind with its own KV
+             heads, planes and page group (the window group's pages
+             behind a row's window are released while it decodes),
+             grouped queries, partial rotary with a base a kind, a
+             learned sink, routed experts without a shared one
   engine     DecodeEngine — owns the device page pool (one buffer per
-             plane, one page table for all) and a pre-traced
+             plane, one page table a page group) and a pre-traced
              fixed-shape program grid (zero steady-state retraces);
              dispatches on the configuration object alone
   scheduler  ContinuousScheduler + DecodedModel — per-step admission,
@@ -69,9 +78,10 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
 Knobs: MXNET_DECODE_* (docs/env_vars.md). Guide: docs/serving.md
 ("Continuous decoding").
 """
-from . import attention, blocks, config, engine, model, prefix, \
-    quant, sampling, scheduler, sparse_latent, speculative, stats
-from .blocks import (SCRATCH_PAGE, BlockAllocator, PageError,
+from . import attention, blocks, config, engine, layers, model, prefix, \
+    quant, sampling, scheduler, sparse_latent, speculative, stats, \
+    window_mixed
+from .blocks import (SCRATCH_PAGE, BlockAllocator, PageError, PageGroup,
                      PagePoolExhausted, pages_needed)
 from .attention import (get_kernel, get_multi_kernel,
                         paged_attention_lax, paged_attention_pallas)
@@ -80,6 +90,7 @@ from .quant import KVPool, Plane
 from .model import DecoderConfig, init_decoder_params, reference_logits
 from .sparse_latent import (SparseLatentConfig,
                             init_sparse_latent_params)
+from .window_mixed import WindowMixedConfig, init_window_mixed_params
 from .prefix import PrefixCache, page_digests
 from .sampling import SamplingParams
 from .scheduler import (ContinuousScheduler, DecodeFuture,
@@ -89,14 +100,17 @@ from .stats import DecodeStats, decoding_stats, reset_decoding_stats
 __all__ = [
     "BlockAllocator", "ContinuousScheduler", "DecodeEngine",
     "DecodeFuture", "DecodeStats", "DecodedModel", "DecoderConfig",
-    "KVPool", "PageError", "PagePoolExhausted", "Plane", "PrefixCache",
+    "KVPool", "PageError", "PageGroup", "PagePoolExhausted", "Plane",
+    "PrefixCache",
     "RequestHandedOff", "SCRATCH_PAGE", "SamplingParams",
-    "SparseLatentConfig", "TokenStream", "attention", "blocks", "config",
-    "decoding_stats", "engine", "get_kernel", "get_multi_kernel",
-    "init_decoder_params", "init_sparse_latent_params", "model",
+    "SparseLatentConfig", "TokenStream", "WindowMixedConfig", "attention",
+    "blocks", "config", "decoding_stats", "engine", "get_kernel",
+    "get_multi_kernel", "init_decoder_params",
+    "init_sparse_latent_params", "init_window_mixed_params", "layers",
+    "model",
     "page_digests",
     "paged_attention_lax", "paged_attention_pallas", "pages_needed",
     "prefix", "quant", "quant_parity_probe", "reference_logits",
     "reset_decoding_stats", "sampling", "scheduler", "sparse_latent",
-    "speculative", "stats",
+    "speculative", "stats", "window_mixed",
 ]

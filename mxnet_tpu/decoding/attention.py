@@ -71,6 +71,24 @@ the tier crawl.
 The `ragged_paged_attention_*` entries below serve MIXED
 prefill+decode batches for the merged-step engine
 (MXNET_DECODE_MERGED_STEP).
+
+Three arguments, in every form, for a block whose layers are not the
+dense block's (`window_mixed`); left out, a form is what it was:
+
+  kv_heads   KV heads of the stored rows where they are fewer than the
+             query's heads: query head j reads KV head
+             j // (heads / kv_heads). The K row is kv_heads*D wide and
+             the V row kv_heads*Dv, Dv its own; out is (B, H, Dv)
+  window     positions a query reads, its own included: a query at
+             position p attends p - window + 1 .. p. The lax forms
+             gather, and the kernel copies, the pages of those
+             positions alone; pages behind them may have been released
+             (their table entries then point at the scratch page)
+  sink       (H,) float32, a learned logit a head that joins the
+             softmax's denominator and has no value row:
+             P = exp(s - m) / (sum_j exp(s_j - m) + exp(sink - m))
+
+With any of them the pools are float (no int8 scales).
 """
 from __future__ import annotations
 
@@ -107,7 +125,8 @@ def _check_shapes(q, k_pages, v_pages, page_table, lengths):
 
 
 def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
-                        scale=None):
+                        scale=None, *, kv_heads=None, window=None,
+                        sink=None):
     """Gather-based single-query kernel (see module docstring): the
     gathered rows are attended AS STORED, (B, T, H*D) in the pool's
     type, with the query spread over the heads' lanes. Softmax and
@@ -117,6 +136,10 @@ def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
     the arithmetic of dequantizing the rows."""
     k_pages = _quant.as_layer(k_pages)
     v_pages = _quant.as_layer(v_pages)
+    if kv_heads or window or sink is not None:
+        return _grouped_attention_lax(
+            q, k_pages, v_pages, page_table, lengths, scale,
+            kv_heads or q.shape[1], window, sink)
     b, h, d, p, bp = _check_shapes(
         q, k_pages, v_pages, page_table, lengths)
     if scale is None:
@@ -153,8 +176,81 @@ def paged_attention_lax(q, k_pages, v_pages, page_table, lengths,
     return out.astype(q.dtype)
 
 
+def _check_grouped(q_heads, d, k_pages, v_pages, kv_heads):
+    """(page size, value head size) of a grouped read: float pools whose
+    K row is kv_heads*d wide."""
+    _, p, wk = k_pages.shape
+    if k_pages.pool.scale is not None or v_pages.pool.scale is not None:
+        raise ValueError("grouped or windowed attention reads float pools")
+    if q_heads % kv_heads or wk != kv_heads * d \
+            or v_pages.shape[2] % kv_heads or v_pages.shape[:2] != (
+                k_pages.shape[0], p):
+        raise ValueError(
+            f"pool rows {wk}/{v_pages.shape[2]} do not hold {kv_heads} KV "
+            f"heads for {q_heads} query heads of {d}")
+    return p, v_pages.shape[2] // kv_heads
+
+
+def _softmax_with_sink(s, sink):
+    """Softmax over the last axis of s (B, G, Q, ..., T) float32 with
+    the masked scores at NEG_INF; `sink` (G, Q) joins the denominator."""
+    m = s.max(axis=-1, keepdims=True)
+    if sink is not None:
+        sink = sink.reshape((1,) + sink.shape + (1,) * (s.ndim - 3))
+        m = jnp.maximum(m, sink)
+    e = jnp.exp(s - m)
+    den = e.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sink - m)
+    return e / den
+
+
+def _grouped_attention_lax(q, k_pages, v_pages, page_table, lengths,
+                           scale, kv_heads, window, sink):
+    """`paged_attention_lax` with `kv_heads`, `window` or `sink` (module
+    docstring): the gathered rows are split into their KV heads and a
+    head's group of queries is batched against it. With a window only
+    its ceil(window / P) + 1 pages are gathered, from the page the
+    row's first attended position lies in."""
+    b, h, d = q.shape
+    p, dv = _check_grouped(h, d, k_pages, v_pages, kv_heads)
+    bp = page_table.shape[1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    lower = jnp.zeros_like(lengths)
+    offset = lower
+    if window:
+        n = min(bp, -(-window // p) + 1)
+        lower = jnp.maximum(lengths - window, 0)
+        first = jnp.minimum(lower // p, bp - n)
+        page_table = jnp.take_along_axis(
+            page_table, first[:, None] + jnp.arange(n)[None], axis=1)
+        offset = first * p
+    k_rows, _ = _quant.gather_stored(k_pages, page_table)
+    v_rows, _ = _quant.gather_stored(v_pages, page_table)
+    t = k_rows.shape[1]
+    highest = jax.lax.Precision.HIGHEST
+    ct = jnp.promote_types(q.dtype, k_rows.dtype)
+    s = jnp.einsum(
+        "bgqd,btgd->bgqt", q.reshape(b, kv_heads, -1, d).astype(ct),
+        k_rows.reshape(b, t, kv_heads, d).astype(ct),
+        precision=highest if ct == jnp.float32 else None,
+        preferred_element_type=jnp.float32) * scale
+    pos = offset[:, None] + jnp.arange(t)[None]
+    mask = (pos >= lower[:, None]) & (pos < lengths[:, None])
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    w = _softmax_with_sink(
+        s, None if sink is None
+        else sink.astype(jnp.float32).reshape(kv_heads, -1))
+    o = jnp.einsum("bgqt,btgd->bgqd", w,
+                   v_rows.reshape(b, t, kv_heads, dv).astype(jnp.float32),
+                   precision=highest, preferred_element_type=jnp.float32)
+    return o.reshape(b, h, dv).astype(q.dtype)
+
+
 def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
-                              q_positions, scale=None):
+                              q_positions, scale=None, *, kv_heads=None,
+                              window=None, sink=None):
     """Multi-query variant: S queries per row over the same paged
     context, each masked by its OWN absolute position.
 
@@ -171,6 +267,10 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
     """
     k_pages = _quant.as_layer(k_pages)
     v_pages = _quant.as_layer(v_pages)
+    if kv_heads or window or sink is not None:
+        return _grouped_attention_lax_multi(
+            q, k_pages, v_pages, page_table, q_positions, scale,
+            kv_heads or q.shape[2], window, sink)
     b, s, h, d = q.shape
     p = _check_pool(k_pages, v_pages, h, d)
     if page_table.shape[0] != b or q_positions.shape != (b, s):
@@ -190,6 +290,83 @@ def paged_attention_lax_multi(q, k_pages, v_pages, page_table,
     w = e / e.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bhst,bthd->bshd", w, v_ctx,
                      preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+# key positions a block of the grouped multi-query form scores at once:
+# bounds its (B, H, S, block) float32 scores whatever the context
+KEY_BLOCK = 512
+
+
+def _grouped_attention_lax_multi(q, k_pages, v_pages, page_table,
+                                 q_positions, scale, kv_heads, window,
+                                 sink):
+    """`paged_attention_lax_multi` with `kv_heads`, `window` or `sink`:
+    the keys are walked in blocks of `KEY_BLOCK` positions, gathered
+    from their pages as stored, with the softmax online in float32
+    across them, from the block the first query's window begins in to
+    the block of the last query — the work is the queries' reach, not
+    the table's, and no score matrix over the whole context exists.
+    Operands are in the wider of the query's and the pool's type
+    (float32 ones at `highest`); the weights are rounded to a bfloat16
+    pool's type for the value product."""
+    b, s, h, d = q.shape
+    p, dv = _check_grouped(h, d, k_pages, v_pages, kv_heads)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    bp = page_table.shape[1]
+    kb = max(1, min(KEY_BLOCK // p, bp))        # pages a block
+    kt = kb * p
+    blocks = -(-bp // kb)
+    table = jnp.pad(page_table, ((0, 0), (0, blocks * kb - bp)))
+    highest = jax.lax.Precision.HIGHEST
+    ct = jnp.promote_types(q.dtype, k_pages.pool.data.dtype)
+    exact = highest if ct == jnp.float32 else None
+    qg = q.reshape(b, s, kv_heads, h // kv_heads, d).astype(ct)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(
+            1, kv_heads, h // kv_heads, 1, 1)
+    last = jnp.clip(jnp.max(q_positions) // kt, 0, blocks - 1)
+    first = 0
+    if window:
+        first = jnp.clip((jnp.min(q_positions) - window + 1) // kt, 0, last)
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, i * kb, kb, axis=1)
+        k_rows, _ = _quant.gather_stored(k_pages, pages)
+        v_rows, _ = _quant.gather_stored(v_pages, pages)
+        sc = jnp.einsum(
+            "bsgqd,btgd->bgqst", qg,
+            k_rows.reshape(b, kt, kv_heads, d).astype(ct), precision=exact,
+            preferred_element_type=jnp.float32) * scale
+        pos = i * kt + jnp.arange(kt)
+        mask = pos[None, None, :] <= q_positions[:, :, None]
+        if window:
+            mask = mask & (pos[None, None, :]
+                           > q_positions[:, :, None] - window)
+        sc = jnp.where(mask[:, None, None], sc, NEG_INF)
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        e = jnp.exp(sc - m_new)
+        l_new = l_prev * corr + e.sum(axis=-1, keepdims=True)
+        vt = v_rows.dtype
+        pv = jnp.einsum(
+            "bgqst,btgd->bgqsd", e.astype(vt),
+            v_rows.reshape(b, kt, kv_heads, dv),
+            precision=highest if vt == jnp.float32 else None,
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    shape = (b, kv_heads, h // kv_heads, s)
+    init = (jnp.full(shape + (1,), NEG_INF, jnp.float32),
+            jnp.zeros(shape + (1,), jnp.float32)) if sink is None else (
+        jnp.broadcast_to(sink, shape + (1,)),
+        jnp.ones(shape + (1,), jnp.float32))
+    _, l_end, acc = jax.lax.fori_loop(
+        first, last + 1, block,
+        init + (jnp.zeros(shape + (dv,), jnp.float32),))
+    out = (acc / l_end).transpose(0, 3, 1, 2, 4).reshape(b, s, h, dv)
     return out.astype(q.dtype)
 
 
@@ -260,7 +437,7 @@ _BLOCK_TOKENS = 128
 
 
 def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
-                       quantized):
+                       quantized, kv_heads=None, window=None, sink=False):
     """Kernel body on a grid over the batch rows: a row's own blocks of
     `block_pages` pages are walked by a loop whose trip count is the
     row's length, so neither a padding page nor an empty row costs a
@@ -289,7 +466,17 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
     stacked (3H, G*P), so ONE pass over the block keeps exactly the
     products that `highest` keeps of a float32 weight and a value whose
     low terms are zero; a float32 pool takes `highest` itself. An int8
-    pool's scales, (H, G*P) a block, go onto the scores and weights."""
+    pool's scales, (H, G*P) a block, go onto the scores and weights.
+
+    With `kv_heads` (the grouped form; module docstring) the query comes
+    ALREADY spread over its KV head's lanes, (H, kv_heads*D) a row, the
+    V row is kv_heads*Dv wide, and each KV head's group of query heads
+    keeps that head's Dv lanes of the (H, kv_heads*Dv) accumulator: out
+    is (H, Dv) float32 a row. With `window` a row's walk begins at the
+    block its first attended position lies in, pages behind that
+    position are neither copied nor waited for, and positions before it
+    are masked. With `sink` a (H, 1) float32 input is the softmax's
+    starting maximum beside a starting denominator of 1."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -303,6 +490,7 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
     def kernel(pt_ref, len_ref, layer_ref, q_ref, *refs):
         k_hbm, v_hbm = refs[:2]
         ks_ref, vs_ref = refs[2:4] if quantized else (None, None)
+        sink_ref = refs[4 if quantized else 2] if sink else None
         o_ref, k_buf, v_buf, acc_ref, sems, slot_ref = refs[-6:]
         b = pl.program_id(0)
         layer = layer_ref[0]
@@ -312,6 +500,13 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
             # lax form's mask over the gathered context does
             return jnp.minimum(len_ref[row], bucket * p)
 
+        def lower_of(row):
+            # the first position a row's query attends
+            return jnp.maximum(length_of(row) - window, 0) if window else 0
+
+        def first_block_of(row):
+            return lower_of(row) // gp if window else 0
+
         length = length_of(b)
 
         def copies(row, blk, slot, act):
@@ -319,6 +514,8 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
             # owns in its block `blk`: the same descriptors both times
             first = blk * g
             owned = jnp.minimum(pl.cdiv(length_of(row), p) - first, g)
+            begin = jnp.maximum(lower_of(row) // p - first, 0) \
+                if window else 0
 
             def one(j, _):
                 page = pt_ref[row, first + j]
@@ -329,7 +526,7 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
                         hbm.at[layer, page], buf.at[slot, at],
                         sems.at[n, slot]))
 
-            jax.lax.fori_loop(0, owned, one, None)
+            jax.lax.fori_loop(begin, owned, one, None)
 
         def start(row, blk, slot):
             copies(row, blk, slot, lambda c: c.start())
@@ -343,7 +540,7 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
 
             @pl.when(nxt < rows)
             def _():
-                start(nxt, 0, slot)
+                start(nxt, first_block_of(jnp.minimum(nxt, rows - 1)), slot)
 
         @pl.when(b == 0)
         def _first():
@@ -357,18 +554,23 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
 
         @pl.when(length > 0)
         def _row():
-            hd = q_ref.shape[-1]
-            d = hd // heads
-            own = (jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 1) // d
-                   == jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0))
             ct = jnp.promote_types(
                 q_ref.dtype,
                 jnp.bfloat16 if quantized else k_buf.dtype)
-            # (H, H*D); the select in float32: Mosaic has no relayout
-            # of the 32-bit mask for 16-bit operands
-            q_heads = jnp.where(own, q_ref[0].astype(jnp.float32),
-                                0).astype(ct)
+            if kv_heads:
+                q_heads = q_ref[0].astype(ct)       # (H, kv_heads*D)
+            else:
+                hd = q_ref.shape[-1]
+                d = hd // heads
+                own = (jax.lax.broadcasted_iota(
+                    jnp.int32, (heads, hd), 1) // d
+                    == jax.lax.broadcasted_iota(jnp.int32, (heads, hd), 0))
+                # (H, H*D); the select in float32: Mosaic has no
+                # relayout of the 32-bit mask for 16-bit operands
+                q_heads = jnp.where(own, q_ref[0].astype(jnp.float32),
+                                    0).astype(ct)
             blocks = pl.cdiv(length, gp)
+            lower = lower_of(b)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
             def block(i, carry):
@@ -394,7 +596,10 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
                     s = s * ks_ref[0, i]
                 pos = i * gp + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 1)
-                s = jnp.where(pos < length, s, NEG_INF)
+                seen = pos < length
+                if window:
+                    seen = seen & (pos >= lower)
+                s = jnp.where(seen, s, NEG_INF)
                 m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
                 corr = jnp.exp(m_prev - m_new)
                 e = jnp.exp(s - m_new)
@@ -416,18 +621,30 @@ def _paged_attn_kernel(rows, bucket, heads, page_size, block_pages, scale,
                 acc_ref[...] = acc_ref[...] * corr + pv
                 return m_new, l_new
 
-            _, l_end = jax.lax.fori_loop(
-                0, blocks, block,
-                (jnp.full((heads, 1), NEG_INF, jnp.float32),
-                 jnp.zeros((heads, 1), jnp.float32)))
-            out = jnp.where(own, acc_ref[...] / l_end, 0)
-            o_ref[0] = out.sum(axis=0, keepdims=True).astype(o_ref.dtype)
+            init = (sink_ref[...], jnp.ones((heads, 1), jnp.float32)) \
+                if sink else (jnp.full((heads, 1), NEG_INF, jnp.float32),
+                              jnp.zeros((heads, 1), jnp.float32))
+            _, l_end = jax.lax.fori_loop(first_block_of(b), blocks, block,
+                                         init)
+            if kv_heads:
+                # a KV head's group of query heads keeps its Dv lanes
+                out = acc_ref[...] / l_end
+                grp, dv = heads // kv_heads, out.shape[1] // kv_heads
+                for j in range(kv_heads):
+                    o_ref[0, j * grp:(j + 1) * grp, :] = out[
+                        j * grp:(j + 1) * grp,
+                        j * dv:(j + 1) * dv].astype(o_ref.dtype)
+            else:
+                out = jnp.where(own, acc_ref[...] / l_end, 0)
+                o_ref[0] = out.sum(axis=0, keepdims=True).astype(
+                    o_ref.dtype)
 
     return kernel
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
-                           scale=None, block_pages=None):
+                           scale=None, block_pages=None, *, kv_heads=None,
+                           window=None, sink=None):
     """The in-place kernel (`_paged_attn_kernel`): only the pages a
     row owns ever move HBM->VMEM, straight from the pool as it is
     stored, `block_pages` of them a block (default: 128 tokens' worth,
@@ -435,8 +652,15 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     a TPU, interpreted elsewhere (utils.pallas_interpret)."""
     k_pages = _quant.as_layer(k_pages)
     v_pages = _quant.as_layer(v_pages)
-    b, h, d, p, bp = _check_shapes(
-        q, k_pages, v_pages, page_table, lengths)
+    grouped = bool(kv_heads or window or sink is not None)
+    if grouped:
+        b, h, d = q.shape
+        kv_heads = kv_heads or h
+        p, _ = _check_grouped(h, d, k_pages, v_pages, kv_heads)
+        bp = page_table.shape[1]
+    else:
+        b, h, d, p, bp = _check_shapes(
+            q, k_pages, v_pages, page_table, lengths)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     g = min(block_pages or max(1, _BLOCK_TOKENS // p), bp)
@@ -444,14 +668,18 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     # same layer of K and V
     layer = jnp.asarray(k_pages.index, jnp.int32).reshape(1)
     return _paged_call(q, k_pages.pool, v_pages.pool, page_table, lengths,
-                       layer, scale=float(scale), block_pages=g,
-                       interpret=_utils.pallas_interpret())
+                       layer, sink, scale=float(scale), block_pages=g,
+                       interpret=_utils.pallas_interpret(),
+                       kv_heads=kv_heads if grouped else None,
+                       window=window)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "block_pages", "interpret"))
-def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
-                block_pages, interpret):
+                   static_argnames=("scale", "block_pages", "interpret",
+                                    "kv_heads", "window"))
+def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, sink=None,
+                *, scale, block_pages, interpret, kv_heads=None,
+                window=None):
     """The kernel's call, a jitted function of its own with the layer
     a traced index: a decode program's 24 layers then share ONE trace
     and ONE lowering of the kernel (Mosaic's lowering runs in every
@@ -460,7 +688,11 @@ def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
     stores a page's scales as one row of page_size*heads lanes, which
     Mosaic cannot turn into the (H, G*P) the scores want; they come as
     a per-call view of the GATHERED scale rows, (B, blocks, H, G*P) —
-    1/head_dim of the context's bytes — never of the pool."""
+    1/head_dim of the context's bytes — never of the pool.
+
+    The grouped form's query is spread over its KV head's lanes HERE,
+    (B, H, kv_heads*D): in the kernel the spread would be a
+    concatenation at lanes no multiple of 128."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -470,11 +702,29 @@ def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
     g = block_pages
     blocks = -(-bp // g)
     quantized = k_pool.scale is not None
-    row_spec = pl.BlockSpec((1, 1, h * d),
-                            lambda bb, pt, ln, ly: (bb, 0, 0))
+    wk, wv = k_pool.data.shape[-1], v_pool.data.shape[-1]
+    if kv_heads:
+        own = (jnp.arange(wk)[None, :] // d
+               == jnp.arange(h)[:, None] // (h // kv_heads))
+        q_rows = jnp.where(own, jnp.tile(q, (1, 1, kv_heads)), 0)
+        row_spec = pl.BlockSpec((1, h, wk),
+                                lambda bb, pt, ln, ly: (bb, 0, 0))
+        out_spec = pl.BlockSpec((1, h, wv // kv_heads),
+                                lambda bb, pt, ln, ly: (bb, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((b, h, wv // kv_heads),
+                                         jnp.float32)
+    else:
+        q_rows = q.reshape(b, 1, h * d)
+        row_spec = out_spec = pl.BlockSpec(
+            (1, 1, h * d), lambda bb, pt, ln, ly: (bb, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((b, 1, h * d), q.dtype)
     pools = (k_pool, v_pool)
     in_specs = [row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
-    operands = [q.reshape(b, 1, h * d)] + [pool.data for pool in pools]
+    operands = [q_rows] + [pool.data for pool in pools]
+    if sink is not None:
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
+        in_specs.append(pl.BlockSpec((h, 1),
+                                     lambda bb, pt, ln, ly: (0, 0)))
     if quantized:
         pad = [(0, 0), (0, blocks * g - bp), (0, 0)]
         for pool in pools:
@@ -489,23 +739,25 @@ def _paged_call(q, k_pool, v_pool, page_table, lengths, layer, *, scale,
         num_scalar_prefetch=3,   # page_table, lengths, layer
         grid=(b,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, g * p, h * d), stored),          # K blocks
-            pltpu.VMEM((2, g * p, h * d), stored),          # V blocks
-            pltpu.VMEM((h, h * d), jnp.float32),            # accumulator
+            pltpu.VMEM((2, g * p, wk), stored),             # K blocks
+            pltpu.VMEM((2, g * p, wv), stored),             # V blocks
+            pltpu.VMEM((h, wv), jnp.float32),               # accumulator
             pltpu.SemaphoreType.DMA((2, 2)),                # (K|V, buffer)
             pltpu.SMEM((1,), jnp.int32),                    # buffer in use
         ],
     )
     fn = pl.pallas_call(
-        _paged_attn_kernel(b, bp, h, p, g, scale, quantized),
+        _paged_attn_kernel(b, bp, h, p, g, scale, quantized, kv_heads,
+                           window, sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         name="paged_attention",
     )
-    return fn(page_table, lengths, layer, *operands).reshape(b, h, d)
+    out = fn(page_table, lengths, layer, *operands)
+    return out.astype(q.dtype) if kv_heads else out.reshape(b, h, d)
 
 
 # ---------------------------------------------------------------- ragged

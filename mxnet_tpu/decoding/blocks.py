@@ -22,10 +22,20 @@ inactive batch rows point at it, so the device kernel can always
 gather/scatter a full (max_batch, pages_bucket) grid with no branch —
 garbage lands in (or comes from) page 0 and is masked out by sequence
 length. Page 0 is never handed to a sequence.
+
+A model whose layers do not all keep the same positions states several
+page GROUPS (`PageGroup`): each has a pool, an allocator and, for every
+sequence, a page table of its own, all indexed alike (entry i holds
+positions i*page_size ..). A WINDOWED group's layers read only a row's
+last `window` positions: a page that falls wholly behind the window
+goes back to the allocator while the row lives (`release_behind`) and
+its table entry becomes the scratch page, which no query reads
+unmasked.
 """
 from __future__ import annotations
 
 import threading
+from typing import NamedTuple, Optional
 
 from ..base import MXNetError
 
@@ -44,6 +54,49 @@ class PagePoolExhausted(PageError):
 def pages_needed(num_tokens, page_size):
     """Pages covering `num_tokens` positions (ceil division; 0 -> 0)."""
     return (int(num_tokens) + page_size - 1) // page_size
+
+
+class PageGroup(NamedTuple):
+    """One page table of a model's pool, as its configuration states
+    it: the planes that name it share its page ids. `window` is how
+    many positions a query of the group's layers reads, its own
+    included (None: every earlier one)."""
+
+    name: str = ""
+    window: Optional[int] = None
+
+    def first_page(self, position, page_size):
+        """Index of the first page a query at `position` reads."""
+        if not self.window:
+            return 0
+        return max(0, position - self.window + 1) // page_size
+
+
+def cover(allocator, table, num_tokens, first=0):
+    """Extend the position-indexed `table` to the pages of `num_tokens`
+    positions: new entries before index `first` (behind a window) are
+    the scratch page, the rest fresh pages. All or nothing."""
+    need = pages_needed(num_tokens, allocator.page_size) - len(table)
+    if need > 0:
+        behind = max(0, min(first - len(table), need))
+        fresh = allocator.alloc(need - behind)
+        table.extend([SCRATCH_PAGE] * behind + fresh)
+
+
+def release_behind(allocator, table, first):
+    """Give back the pages of `table` before index `first` (wholly
+    behind the window of the row's next query), leaving the scratch
+    page in their place; returns how many. What is released is the run
+    of held pages that ends there, so the cost is the run's."""
+    i = min(first, len(table))
+    gone = []
+    while i > 0 and table[i - 1] != SCRATCH_PAGE:
+        i -= 1
+        gone.append(table[i])
+        table[i] = SCRATCH_PAGE
+    if gone:
+        allocator.free(gone)
+    return len(gone)
 
 
 class BlockAllocator:
@@ -79,6 +132,11 @@ class BlockAllocator:
         # instead of allocating, so this counter, not occupancy, is
         # what the decode-gate's shared-prefix arm compares
         self._allocated_total = 0
+        # every allocator of the pool this one belongs to, itself
+        # first: the engine of a model with several page groups sets
+        # it on the first group's allocator, so that `check()` there
+        # covers them all
+        self.groups = (self,)
 
     # ------------------------------------------------------------ state
     def free_pages(self):
@@ -188,7 +246,12 @@ class BlockAllocator:
 
     # ------------------------------------------------------- validation
     def check(self):
-        """Raise PageError on any broken invariant (test hook)."""
+        """Raise PageError on any broken invariant, in this allocator
+        or another group's of the same pool (test hook)."""
+        for a in self.groups:
+            a._check()
+
+    def _check(self):
         with self._lock:
             free = set(self._free)
             if len(free) != len(self._free):

@@ -3,11 +3,17 @@
 Owns the paged pool — one pre-allocated (layers, pages, page_size,
 width) buffer per PLANE the model's configuration states (`cfg.planes`:
 `k` and `v` of heads*head_dim for the dense block, a latent row and an
-index key for the sparse latent block; quant.py says why that order) —
-the block allocator over it (one page table for every plane), and a
-FIXED grid of jitted programs. The engine names no block: it takes the
-planes, the step functions and the program names from the
-configuration object (the model contract, `model.DecoderConfig`):
+index key for the sparse latent block, a `k`/`v` pair for each kind of
+layer of a block that mixes window and full layers; quant.py says why
+that order), each at the layers the plane covers — a block allocator
+and a page table a sequence for every PAGE GROUP the configuration
+states (`cfg.page_groups`: one for all planes, unless layers keep
+different positions), and a FIXED grid of jitted programs. The engine
+names no block: it takes the planes, the groups, the step functions and
+the program names from the configuration object (the model contract,
+`model.DecoderConfig`). With several groups `num_pages` gives each
+group's pages, a step's page table is the groups' tables stacked,
+(groups, rows, bucket), and a prefill's is a list of their lists:
 
   prefill  one program per prompt length bucket (batch 1, dense causal
            attention — optionally ring attention for long buckets —
@@ -73,8 +79,8 @@ from . import speculative as _spec
 # warn-once latch for calibration-harvest failures (the serving
 # registry's convention: one WARN per process, not one per bucket)
 _calibration_warned = False
-from .blocks import SCRATCH_PAGE, BlockAllocator, PageError, \
-    pages_needed
+from .blocks import SCRATCH_PAGE, BlockAllocator, PageError, cover, \
+    pages_needed, release_behind
 
 
 class DecodeEngine:
@@ -94,8 +100,22 @@ class DecodeEngine:
             else _cfg.max_batch()
         self.page_size = page_size if page_size is not None \
             else _cfg.page_size()
-        self.num_pages = num_pages if num_pages is not None \
-            else _cfg.num_pages()
+        # the page groups (cfg.page_groups): the first holds every
+        # position of a live row, and is what `allocator`, `num_pages`
+        # and the page buckets speak of
+        self.groups = tuple(cfg.page_groups)
+        if self.groups[0].window:
+            raise PageError("the first page group keeps every position")
+        if num_pages is None:
+            num_pages = _cfg.num_pages()
+        self.group_pages = tuple(
+            int(n) for n in (num_pages if isinstance(num_pages, (
+                tuple, list)) else [num_pages] * len(self.groups)))
+        if len(self.group_pages) != len(self.groups):
+            raise PageError(
+                f"num_pages {num_pages!r} for {len(self.groups)} page "
+                f"groups {[g.name for g in self.groups]}")
+        self.num_pages = self.group_pages[0]
         if page_buckets is None:
             page_buckets = _cfg.page_buckets()
         if page_buckets is None:
@@ -119,6 +139,20 @@ class DecodeEngine:
             raise PageError(
                 f"page bucket {self.page_buckets[-1]} exceeds pool "
                 f"capacity {self.num_pages - 1}")
+
+        windowed = [g for g in self.groups if g.window]
+        if windowed and (prefix_cache or draft_params is not None):
+            # a hit (or a rolled-back draft) resumes a row at a position
+            # whose window lies in pages a live row has already given
+            # back: decoding/prefix.py would have to keep them at every
+            # hit boundary. Unasked, the cache is off for such a model
+            raise PageError(
+                f"page group {windowed[0].name!r} keeps a row's last "
+                f"{windowed[0].window} positions only: neither the "
+                "prefix cache nor a draft can resume a row from pages "
+                "it has released")
+        if windowed:
+            prefix_cache = False
 
         # chunked prefill (cfg.prefill_chunk): the chunk programs'
         # token buckets, and the page buckets they gather over (a
@@ -145,15 +179,25 @@ class DecodeEngine:
                     "speculation verifies with the dense block's "
                     "multi-query kernel: not for a chunk-prefilled "
                     "configuration")
-
-        self.allocator = BlockAllocator(self.num_pages, self.page_size)
+        elif len(self.groups) > 1:
+            raise PageError(
+                "several page groups are filled by chunked prefill "
+                "(cfg.prefill_chunk)")
+        self.allocators = tuple(BlockAllocator(n, self.page_size)
+                                for n in self.group_pages)
+        self.allocator = self.allocators[0]
+        self.allocator.groups = self.allocators
         self._attn = _attn.get_kernel(self.kernel_name)
         self._attn_multi = _attn.get_multi_kernel(self.kernel_name)
         self._params = jax.tree_util.tree_map(jnp.asarray, dict(params))
+        names = [g.name for g in self.groups]
+        self._plane_group = tuple(names.index(plane.group)
+                                  for plane in cfg.planes)
         self._pools = tuple(
-            _quant.make_plane(cfg.n_layers, self.num_pages,
-                              self.page_size, plane, self.kv_dtype)
-            for plane in cfg.planes)
+            _quant.make_plane(plane.layers or cfg.n_layers,
+                              self.group_pages[gi], self.page_size, plane,
+                              self.kv_dtype)
+            for plane, gi in zip(cfg.planes, self._plane_group))
         self.prefix_cache_enabled = prefix_cache if prefix_cache \
             is not None else _cfg.prefix_cache()
         self.spec_k = int(spec_k) if spec_k is not None \
@@ -213,6 +257,8 @@ class DecodeEngine:
         # (cfg.step_counters), for the scheduler's spans and stats
         self.last_step_counters = {}
         self.last_prefill = {"chunks": 1}
+        # pages a windowed group got back during the newest prefill
+        self.last_released = 0
         self._draft_prefill_fns = {}
         self._draft_tail_fns = {}
         self._propose_fns = {}
@@ -237,7 +283,9 @@ class DecodeEngine:
         import hashlib as _hashlib
 
         self._digest = _hashlib.sha1(repr(
-            (cfg, self.max_batch, self.page_size, self.num_pages,
+            (cfg, self.max_batch, self.page_size,
+             self.num_pages if len(self.groups) == 1
+             else self.group_pages,
              self.kernel_name, self.draft_cfg,
              self.spec_k if self.spec_enabled else 0,
              self.step_rows if self.merged_step_enabled else 0,
@@ -316,7 +364,15 @@ class DecodeEngine:
         # number is the capacity multiplier ci/check_quant.py
         # reports
         per_tok = sum(_quant.kv_bytes_per_token(p) for p in self._pools)
+        more = {}
+        for g, a in zip(self.groups[1:], self.allocators[1:]):
+            gs = a.stats()
+            more.update({f"{g.name}_pages_total": gs["pages_total"],
+                         f"{g.name}_pages_free": gs["pages_free"],
+                         f"{g.name}_free_low_watermark":
+                             gs["free_low_watermark"]})
         return {
+            **more,
             "pages_total": st["pages_total"],
             "pages_free": st["pages_free"],
             "kv_occupancy": round(
@@ -513,9 +569,16 @@ class DecodeEngine:
         harvest and `decode_program_text` dispatch or lower."""
         r = self.step_rows
         return (self._params, np.zeros((r,), np.int32), self._pools,
-                np.zeros((r, bucket), np.int32),
+                np.zeros(self.table_shape(r, bucket), np.int32),
                 np.zeros((r,), np.int32), np.zeros((r,), bool),
                 *self._samp_arrays(None, None, None, None))
+
+    def table_shape(self, *shape):
+        """The shape of a page-table array of `shape` = (rows, bucket)
+        or (bucket,): as it is for one page group, the groups' tables
+        stacked in front for several."""
+        return shape if len(self.groups) == 1 \
+            else (len(self.groups),) + shape
 
     def warmup(self):
         """Pre-trace the full program grid: every prefill length
@@ -538,7 +601,7 @@ class DecodeEngine:
                 tok, self._pools = self._chunk_fns[tb, cb](
                     self._params, np.zeros((1, tb), np.int32),
                     jnp.int32(0), jnp.int32(0), self._pools,
-                    np.zeros((cb,), np.int32), *sargs)
+                    np.zeros(self.table_shape(cb), np.int32), *sargs)
                 tok.block_until_ready()
         for lb in () if self.chunk_buckets else self.prefill_buckets:
             tokens = np.zeros((1, lb), np.int32)
@@ -640,7 +703,11 @@ class DecodeEngine:
                 temperature=0.0, top_k=0, top_p=1.0):
         """Fill `table`'s pages with the prompt's K/V; returns the
         first generated token (host int). `table` must already cover
-        pages_needed(len(token_ids)).
+        pages_needed(len(token_ids)); for a model of several page
+        groups it is the list of the groups' tables, of which the
+        first must cover the prompt and the others are covered, and a
+        windowed one's pages released behind the window, chunk by
+        chunk (`_launch_chunks`).
 
         `start > 0` is the prefix-cache hit path: positions < start
         already live in (shared) pages, so only the tail runs —
@@ -722,6 +789,9 @@ class DecodeEngine:
         prompt)."""
         n = len(token_ids)
         outs, pos = [], start
+        tables = [table] if len(self.groups) == 1 else table
+        side = list(zip(self.groups, self.allocators, tables))[1:]
+        self.last_released = 0
         while pos < n:
             m = min(self.cfg.prefill_chunk, n - pos)
             tb = pick_bucket(m, self.chunk_buckets)
@@ -729,14 +799,37 @@ class DecodeEngine:
                              self.context_buckets)
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :m] = token_ids[pos:pos + m]
-            page_ids = np.full((cb,), SCRATCH_PAGE, np.int32)
-            page_ids[:min(len(table), cb)] = table[:cb]
+            for g, alloc, tbl in side:
+                # the chunk's pages, and none behind its first window
+                cover(alloc, tbl, pos + m,
+                      g.first_page(pos, self.page_size))
+            page_ids = np.full((len(tables), cb), SCRATCH_PAGE, np.int32)
+            for gi, tbl in enumerate(tables):
+                page_ids[gi, :min(len(tbl), cb)] = tbl[:cb]
             out, self._pools = self._chunk_fns[tb, cb](
                 self._params, tokens, jnp.int32(pos), jnp.int32(pos + m),
-                self._pools, page_ids, *sargs)
+                self._pools, page_ids.reshape(self.table_shape(cb)),
+                *sargs)
             outs.append(out)
             pos += m
+            for g, alloc, tbl in side:
+                # what the next query (the next chunk's first, or the
+                # row's first decode step) no longer reads
+                self.last_released += release_behind(
+                    alloc, tbl, g.first_page(pos, self.page_size))
         return outs
+
+    def prefill_pages(self, num_tokens):
+        """Free pages each group needs before a prompt of `num_tokens`
+        is prefilled from position 0, first group first: its whole
+        table; a windowed group's most at once, a chunk's and the
+        window's before it."""
+        p = self.page_size
+        chunk = self.cfg.prefill_chunk or num_tokens
+        return [pages_needed(num_tokens, p) if not g.window
+                else min(pages_needed(num_tokens, p),
+                         pages_needed(chunk + g.window - 1, p) + 1)
+                for g in self.groups]
 
     def _sum_counters(self, rows):
         """Counter rows (n, len(step_counters)) as one row: sums, and
@@ -772,18 +865,19 @@ class DecodeEngine:
         """The step's first half: returns its output still on the
         device. `tokens` may be `next_tokens(out)` of the step launched
         before, so that nothing waits for the host between them."""
-        bucket = page_table.shape[1]
+        bucket = page_table.shape[-1]
         r = self.step_rows
         with _trace.span("engine.launch"):
             if not isinstance(tokens, jax.Array):
                 tokens = self._pad_rows(tokens, np.int32, 0)
             lengths = self._pad_rows(lengths, np.int32, 0)
             active = self._pad_rows(active, bool, False)
-            if page_table.shape[0] < r:
+            if page_table.shape[-2] < r:
                 page_table = np.concatenate(
                     [np.asarray(page_table, np.int32),
-                     np.full((r - page_table.shape[0], bucket),
-                             SCRATCH_PAGE, np.int32)])
+                     np.full(self.table_shape(
+                         r - page_table.shape[-2], bucket),
+                         SCRATCH_PAGE, np.int32)], axis=-2)
             sarr = self._samp_arrays(seeds, temps, top_ks, top_ps)
             return self._run_decode(
                 self._decode_fns[bucket], self._params, tokens,
@@ -840,14 +934,15 @@ class DecodeEngine:
             host_toks, host_n = jax.device_get((tokens_out, n_emit))
         return np.asarray(host_toks), np.asarray(host_n)
 
-    def copy_page(self, src, dst):
-        """Device copy of one page (all pools — the draft pools track
-        the target's COW decisions): the COW half of
-        `BlockAllocator.make_writable`."""
+    def copy_page(self, src, dst, group=0):
+        """Device copy of one page of a page group (all its planes' pools
+        — the draft pools track the target's COW decisions): the COW
+        half of `BlockAllocator.make_writable`."""
         src = jnp.int32(src)
         dst = jnp.int32(dst)
-        self._pools = tuple(self._copy_fn(p, src, dst)
-                            for p in self._pools)
+        self._pools = tuple(
+            self._copy_fn(p, src, dst) if gi == group else p
+            for p, gi in zip(self._pools, self._plane_group))
         if self._draft_params is not None:
             self._dk = self._copy_fn(self._dk, src, dst)
             self._dv = self._copy_fn(self._dv, src, dst)

@@ -34,7 +34,7 @@ import numpy as np
 
 from . import quant as _quant
 from . import sampling as _sampling
-from .blocks import SCRATCH_PAGE
+from .blocks import SCRATCH_PAGE, PageGroup
 
 NEG_INF = -1e30
 
@@ -67,9 +67,14 @@ class DecoderConfig:
     # `step_counters` names the int32 values a step's output carries
     # after its tokens. `kernels` is the engine's choice of paged
     # attention (`attn`, `attn_multi`), for a block that uses it.
+    # `page_groups` are the page tables a sequence has: one, for every
+    # plane and layer, unless layers keep different positions (a
+    # `blocks.PageGroup` each, named by the planes it addresses; the
+    # step functions then take the tables stacked, (groups, ..)).
     program_family = ""
     step_counters = ()
     prefill_chunk = None
+    page_groups = (PageGroup(),)
 
     @property
     def planes(self):

@@ -20,11 +20,15 @@ Storage layout (one for float32, bf16 and int8; no knob):
   data   [layers, pages, page_size, width]
   scale  [layers, pages, page_size*groups]   float32, int8 pools only
 
-A model's pool is a tuple of such planes on ONE page table (`Plane`:
-a name, a width a token, the scale groups of a row). The dense block
-has `k` and `v` of width heads*head_dim; a latent-attention block has
-one shared latent row and one index key a token. The allocator, the
-refcounts and the radix cache know page ids alone.
+A model's pool is a tuple of such planes (`Plane`: a name, a width a
+token, the scale groups of a row, the layers it covers, its page
+group). The dense block has `k` and `v` of width heads*head_dim on one
+page table; a latent-attention block has one shared latent row and one
+index key a token; a block that mixes window and full layers has a
+`k`/`v` pair for each kind, at each kind's own KV heads and layer
+count, in two page groups (`blocks.PageGroup`), each with a page table
+of its own. The allocators, the refcounts and the radix cache know page
+ids alone.
 
 The minor dimension of `data` is a whole K (or V) row of one token,
 all heads side by side; that of `scale` is a page's scales, slot by
@@ -188,12 +192,17 @@ class Plane(NamedTuple):
     it: what a token of a layer stores under this name. `width` values
     a token a layer; `groups` is how many int8 scales a row carries
     (the heads of a per-head K or V row; 1 for a row that is one
-    vector). Every plane of a model shares ONE page table: page `p`,
-    slot `s` is the same token in each."""
+    vector). `layers` is how many of the model's layers store a row
+    here (0: every one; the block maps a layer to its index in the
+    plane) and `group` names the page group (`blocks.PageGroup`) whose
+    page table addresses it. The planes of one group share that table:
+    page `p`, slot `s` is the same token in each."""
 
     name: str
     width: int
     groups: int = 1
+    layers: int = 0
+    group: str = ""
 
     @property
     def stored_width(self):

@@ -62,7 +62,9 @@ from ..serving.batcher import (DeadlineExceededError, ServerBusyError,
                                pick_bucket)
 from ..telemetry import trace as _trace
 from . import config as _cfg
-from .blocks import SCRATCH_PAGE, PagePoolExhausted, pages_needed
+from .blocks import SCRATCH_PAGE, PagePoolExhausted, pages_needed, \
+    release_behind
+from .blocks import cover as _cover
 from .engine import DecodeEngine
 from .prefix import PrefixCache
 from .sampling import SamplingParams
@@ -210,7 +212,7 @@ class _Sequence:
                  "trace_id", "order", "sampling", "use_draft",
                  "generated", "table", "length", "last_token",
                  "preempted", "t_submit_pc", "pending_tail",
-                 "tail_meta", "ahead")
+                 "tail_meta", "ahead", "side")
 
     def __init__(self, prompt, max_new, priority, deadline, future,
                  trace_id, order, sampling, use_draft):
@@ -225,6 +227,10 @@ class _Sequence:
         self.use_draft = use_draft     # speculative opt-in for this row
         self.generated = []
         self.table = None              # page ids while active
+        # the tables of the engine's further page groups, indexed like
+        # `table` (a windowed group's released entries hold the scratch
+        # page); empty for a model with one group
+        self.side = []
         self.length = 0                # tokens materialized in cache
         self.last_token = -1
         self.preempted = False
@@ -523,8 +529,7 @@ class ContinuousScheduler:
         """Terminal transition: free pages, clear the row, settle the
         future exactly once."""
         if seq.table is not None:
-            self.engine.allocator.free(seq.table)
-            seq.table = None
+            self._drop_pages(seq)
         with self._cond:
             # rows are loop-thread-owned but read under the cond by
             # depth(); publish the clear through the same lock
@@ -549,6 +554,14 @@ class ContinuousScheduler:
              "tokens": len(seq.generated),
              "latency_us": round((t_r1 - seq.t_submit_pc) * 1e6, 1)})
 
+    def _drop_pages(self, seq):
+        """A sequence's pages of every group back to their allocators
+        (a table's scratch entries are no one's)."""
+        for alloc, table in zip(self.engine.allocators,
+                                [seq.table] + seq.side):
+            alloc.free(table)
+        seq.table, seq.side = None, []
+
     def _preempt(self, seq):
         """Evict for pages: drop the sequence's pages but keep its
         token history; it re-prefills on readmission (bit-identical
@@ -556,8 +569,7 @@ class ContinuousScheduler:
         self._settle()      # its tokens in flight come out first
         if seq.table is None:
             return          # one of them was its last
-        self.engine.allocator.free(seq.table)
-        seq.table = None
+        self._drop_pages(seq)
         seq.preempted = True
         # a merged-step tail in flight dies with the pages: readmission
         # re-plans the whole prompt (possibly re-matching the cache)
@@ -709,8 +721,13 @@ class ContinuousScheduler:
                     tokens, (len(tokens) - 1) // P)
                 self.stats.note_prefix_reuse(len(matched))
             need = need_total - len(matched)
+            # further page groups are covered chunk by chunk as the
+            # prompt is prefilled: what they need at most must be free
+            side_need = self.engine.prefill_pages(len(tokens))[1:]
             ok = True
-            while alloc.free_pages() < need:
+            while alloc.free_pages() < need or any(
+                    a.free_pages() < n for a, n in zip(
+                        self.engine.allocators[1:], side_need)):
                 if not self._free_one_page(seq):
                     # nothing reclaimable below this priority: requeue
                     # and stop admitting (pages may free up later)
@@ -723,6 +740,7 @@ class ContinuousScheduler:
                     self._waiting.append(seq)
                 return
             seq.table = matched + alloc.alloc(need)
+            seq.side = [[] for _ in side_need]
             with self._cond:
                 row = self._rows.index(None)
                 self._rows[row] = seq
@@ -741,7 +759,8 @@ class ContinuousScheduler:
                                  len(matched))
                 continue
             launched = self.engine.launch_prefill(
-                tokens, seq.table, start=start,
+                tokens, [seq.table] + seq.side if seq.side else seq.table,
+                start=start,
                 seed=seq.sampling.seed,
                 temperature=seq.sampling.temperature,
                 top_k=seq.sampling.top_k, top_p=seq.sampling.top_p)
@@ -759,6 +778,8 @@ class ContinuousScheduler:
             # counts (engine.cfg.step_counters) counted over them
             counted = self.engine.last_prefill
             self.stats.note_counters(counted)
+            if seq.side:
+                self.stats.note_released(self.engine.last_released)
             _trace.record_span(
                 "decoding.prefill", seq.trace_id, t0, t0 + dt,
                 {"model": self.key, "tokens": len(tokens),
@@ -796,6 +817,10 @@ class ContinuousScheduler:
         alloc = self.engine.allocator
         P = self.engine.page_size
         k = self.engine.spec_k if self.engine.spec_enabled else 0
+        side = list(zip(self.engine.groups,
+                        self.engine.allocators))[1:]
+        if side:
+            self._release_windows()
         for seq in self._active():
             if seq.table is None or seq.pending_tail:
                 continue    # tail seqs: write range planned below
@@ -814,7 +839,10 @@ class ContinuousScheduler:
                     victim = self._reclaim_one(None)
                     if victim is None:
                         break
-            if seq.table is None or len(seq.table) < need:
+            if side and seq.table is not None:
+                self._grow_side(seq, side, cover)
+            if seq.table is None or len(seq.table) < need or (
+                    side and any(len(t) < need for t in seq.side)):
                 continue    # preempted itself; back in the queue
             first = (seq.length + seq.ahead) // P
             last = min((cover - 1) // P, len(seq.table) - 1)
@@ -888,6 +916,40 @@ class ContinuousScheduler:
                 self._tail_plan.append((seq, chunk))
                 budget -= chunk
 
+    def _grow_side(self, seq, side, cover):
+        """`_grow` for a row's tables of the further page groups: the
+        same `cover` positions as the first group's (no page is shared
+        there: nothing to make writable)."""
+        P = self.engine.page_size
+        need = pages_needed(cover, P)
+        for (g, a), table in zip(side, list(seq.side)):
+            while seq.table is not None and len(table) < need:
+                try:
+                    _cover(a, table, cover,
+                           g.first_page(seq.length + seq.ahead, P))
+                except PagePoolExhausted:
+                    if self._reclaim_one(None) is None:
+                        break
+
+    def _release_windows(self):
+        """Windowed groups: every live row's pages that lie wholly
+        behind the window of its next query go back to the allocator.
+        Steps in flight were launched with the tables they read, and
+        whoever gets a page next writes it in a program launched after
+        them."""
+        P = self.engine.page_size
+        released = 0
+        for seq in self._active():
+            if seq.table is None or self._steps_left(seq) <= 0:
+                continue    # takes no further step: resolved as it is
+            at = seq.length + seq.ahead
+            for g, a, table in zip(self.engine.groups[1:],
+                                   self.engine.allocators[1:], seq.side):
+                if g.window:
+                    released += release_behind(a, table,
+                                               g.first_page(at, P))
+        self.stats.note_released(released)
+
     # -------------------------------------------------------------- step
     def _step(self):
         """One engine step over the live rows, as three spans that
@@ -905,17 +967,8 @@ class ContinuousScheduler:
         with _trace.span("decoding.pack"):
             (tokens, table, lengths, active, use_draft, *samp), \
                 tail_rows, bucket = self._pack(live)
-            step_attrs = {
-                "trace_ids": tuple(s.trace_id for _, s in live),
-                "model": self.key, "live": len(live), "bucket": bucket,
-                # context positions this step's attention reads: what
-                # a roofline of the attention kernel is owed
-                "ctx_tokens": int(lengths[active].sum())
-                + int(active.sum()) * (k + 1),
-                # pages those positions lie in: what the in-place
-                # kernel copies, of the `rows x bucket` it is given
-                "live_pages": self._live_pages(lengths, active, k + 1),
-                "program": engine.step_program(bucket)}
+            step_attrs = self._step_attrs(live, bucket, lengths, active,
+                                          k + 1)
         with _trace.span("decoding.step", **step_attrs) as step_span:
             t0 = _trace.now()
             if spec:
@@ -932,8 +985,7 @@ class ContinuousScheduler:
             emitted = self._emit(live, tail_rows, out,
                                  n_emit if spec else None)
             emit_span.note(tokens=emitted)
-            self.stats.note_step(emitted, dt, step_attrs["live_pages"],
-                                 engine.step_rows * bucket)
+            self._note_step(emitted, dt, step_attrs)
             self.stats.note_pool()
             if engine._guard and self.stats.steps % 16 == 0:
                 # interval drain of the numerics guard (one fetch per
@@ -946,11 +998,41 @@ class ContinuousScheduler:
                     if clips:
                         self.stats.note_quant_clips(clips)
 
-    def _live_pages(self, lengths, active, new):
-        """Pages that hold the context a step's attention reads: each
-        active row's `lengths + new` positions, in whole pages."""
+    def _step_attrs(self, live, bucket, lengths, active, new):
+        """What a `decoding.step` span says of the step, from its packed
+        rows (`new` positions a row are written and read beside its
+        context)."""
+        engine = self.engine
         reach = lengths[active] + new
-        return int((-(-reach // self.engine.page_size)).sum())
+        attrs = {
+            "trace_ids": tuple(s.trace_id for _, s in live),
+            "model": self.key, "live": len(live), "bucket": bucket,
+            # context positions this step's attention reads: what a
+            # roofline of the attention kernel is owed
+            "ctx_tokens": int(reach.sum()),
+            # pages those positions lie in: what the in-place kernel
+            # copies, of the `rows x bucket` it is given
+            "live_pages": int(
+                (-(-reach // engine.page_size)).sum()),
+            # pages every group's allocator has handed out
+            "pages_held": tuple(a.pages_in_use()
+                                for a in engine.allocators),
+            "program": engine.step_program(bucket)}
+        for g in engine.groups:
+            if g.window:
+                # the positions of them a windowed group's layers read
+                attrs["window_tokens"] = int(
+                    np.minimum(reach, g.window).sum())
+        return attrs
+
+    def _note_step(self, emitted, seconds, attrs):
+        """A finished step into the stats' running sums."""
+        self.stats.note_step(
+            emitted, seconds, attrs["live_pages"],
+            self.engine.step_rows * attrs["bucket"],
+            ctx_tokens=attrs["ctx_tokens"],
+            window_tokens=attrs.get("window_tokens", 0),
+            pages_held=attrs["pages_held"])
 
     def _pack(self, live):
         """The step's fixed-shape row arrays: returns ((tokens, table,
@@ -964,7 +1046,8 @@ class ContinuousScheduler:
         span = max(len(s.table) for _, s in live)
         bucket = pick_bucket(span, engine.page_buckets)
         tokens = np.zeros((r,), np.int32)
-        table = np.full((r, bucket), SCRATCH_PAGE, np.int32)
+        table = np.full(engine.table_shape(r, bucket), SCRATCH_PAGE,
+                        np.int32)
         lengths = np.zeros((r,), np.int32)
         active = np.zeros((r,), bool)
         use_draft = np.zeros((r,), bool)
@@ -976,7 +1059,11 @@ class ContinuousScheduler:
             if s.pending_tail:
                 continue    # fed through the ragged tail rows below
             tokens[row] = s.last_token
-            table[row, :len(s.table)] = s.table
+            if s.side:
+                for gi, t in enumerate([s.table] + s.side):
+                    table[gi, row, :len(t)] = t
+            else:
+                table[row, :len(s.table)] = s.table
             lengths[row] = s.length + s.ahead
             active[row] = True
             use_draft[row] = s.use_draft
@@ -1105,17 +1192,22 @@ class ContinuousScheduler:
             # copy into for each row at most
             if engine.allocator.free_pages() < 2 * len(live):
                 return False
+            if len(engine.groups) > 1:
+                # a further group's pool is sized to its rows' windows:
+                # count what this step really takes, after the releases
+                self._release_windows()
+                P = engine.page_size
+                for gi, a in enumerate(engine.allocators[1:]):
+                    short = sum(
+                        len(s.side[gi]) * P <= s.length + s.ahead
+                        for _, s in live)
+                    if a.free_pages() < short:
+                        return False
             self._grow()
         with _trace.span("decoding.pack"):
             (tokens, table, lengths, active, _draft, *samp), _tails, \
                 bucket = self._pack(live)
-            attrs = {
-                "trace_ids": tuple(s.trace_id for _, s in live),
-                "model": self.key, "live": len(live), "bucket": bucket,
-                "ctx_tokens": int(lengths[active].sum())
-                + int(active.sum()),
-                "live_pages": self._live_pages(lengths, active, 1),
-                "program": engine.step_program(bucket)}
+            attrs = self._step_attrs(live, bucket, lengths, active, 1)
             if self._ahead:
                 tokens = engine.next_tokens(self._ahead[-1][1])
         out = engine.launch_step(tokens, table, lengths, active, *samp)
@@ -1148,9 +1240,8 @@ class ContinuousScheduler:
             emit_span.note(tokens=emitted)
             # the step's own seconds: from its launch, or from the
             # step before's tokens where it waited behind that
-            self.stats.note_step(
-                emitted, t_out - max(t_launch, self._t_retired),
-                attrs["live_pages"], engine.step_rows * attrs["bucket"])
+            self._note_step(
+                emitted, t_out - max(t_launch, self._t_retired), attrs)
             self._t_retired = t_out
             self.stats.note_pool()
             if engine._guard and self.stats.steps % 16 == 0:

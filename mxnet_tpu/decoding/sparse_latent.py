@@ -48,13 +48,14 @@ import numpy as np
 
 from . import attention as _attn
 from . import quant as _quant
-from .blocks import SCRATCH_PAGE
-from .model import _pick_token, _sample_rows
+from .blocks import SCRATCH_PAGE, PageGroup
+from .layers import held_experts, mm as _mm, rms as _rms, rotate, route, \
+    step_output, swiglu as _swiglu
+from .model import _pick_token
 
 # queries of a chunk attended at a time: bounds the index scores
 # (block x index heads x context, float32) and the gathered rows
 QUERY_BLOCK = 32
-_HI = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -100,6 +101,7 @@ class SparseLatentConfig:
     program_family = "sparse_latent_"
     step_counters = ("selected_tokens", "expert_rows", "experts_hit",
                      "expert_rows_max")
+    page_groups = (PageGroup(),)
 
     @property
     def planes(self):
@@ -219,89 +221,12 @@ def yarn_freqs(cfg):
     return freqs.astype(np.float32)
 
 
-def rotate(x, pos, freqs, interleaved):
-    """Rotary positions on the last axis of x (..., T, heads.., R) at
-    `pos` (..., T): pairs (x0,x1),(x2,x3).. when `interleaved`, else
-    the first half with the second. float32 in and out."""
-    ang = pos[..., None].astype(jnp.float32) * freqs
-    ang = ang.reshape(ang.shape[:-1] + (1,) * (x.ndim - ang.ndim)
-                      + ang.shape[-1:])
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    half = x.shape[-1] // 2
-    if interleaved:
-        a, b = x[..., 0::2], x[..., 1::2]
-    else:
-        a, b = x[..., :half], x[..., half:]
-    ra, rb = a * cos - b * sin, a * sin + b * cos
-    if interleaved:
-        return jnp.stack([ra, rb], axis=-1).reshape(x.shape)
-    return jnp.concatenate([ra, rb], axis=-1)
-
-
 # ---------------------------------------------------------------- pieces
-def _rms(x, g, eps):
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32)
-
-
 def _layer_norm(x, g, b, eps):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32) \
         + b.astype(jnp.float32)
-
-
-def _mm(a, w):
-    """a @ w, operands in the weights' type, float32 out."""
-    return jnp.dot(a.astype(w.dtype), w,
-                   preferred_element_type=jnp.float32)
-
-
-def _swiglu(x, w1, w3, w2):
-    return _mm(jax.nn.silu(_mm(x, w1)) * _mm(x, w3), w2)
-
-
-def route(params, i, xh, cfg):
-    """The router over ALL experts for rows xh (N, D) float32:
-    (chosen expert ids (N, k) int32, their weights (N, k) float32)."""
-    n = xh.shape[0]
-    s = jax.nn.sigmoid(jnp.dot(
-        xh, params[f"l{i}.gate"].astype(jnp.float32), precision=_HI))
-    biased = s + params[f"l{i}.gate_bias"].astype(jnp.float32)
-    groups = biased.reshape(n, cfg.n_group, -1)
-    group_score = jax.lax.top_k(groups, 2)[0].sum(-1)
-    _, keep = jax.lax.top_k(group_score, cfg.topk_group)
-    kept = jnp.any(keep[..., None] == jnp.arange(cfg.n_group), axis=1)
-    biased = jnp.where(jnp.repeat(kept, groups.shape[-1], axis=1),
-                       biased, -jnp.inf)
-    _, chosen = jax.lax.top_k(biased, cfg.experts_per_token)
-    w = jnp.take_along_axis(s, chosen, axis=1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scale
-    return chosen.astype(jnp.int32), w
-
-
-def held_experts(params, i, xh, chosen, weights, counted, cfg):
-    """The held experts' part of the expert layer for rows xh (N, D):
-    sum over held e of weight[n, e] * E_e(xh[n]), one grouped
-    computation over all held experts and all rows. Returns (out
-    (N, D) float32, [assignments, experts hit, busiest expert's rows]
-    int32 over the rows `counted` (N,) bool)."""
-    first, held = cfg.experts_held
-    hit = chosen[..., None] == (first + jnp.arange(held))   # (N, k, E)
-    comb = jnp.sum(jnp.where(hit, weights[..., None], 0.0), axis=1)
-    w1, w3, w2 = (params[f"l{i}.experts_{n}"] for n in ("w1", "w3", "w2"))
-    x = xh.astype(w1.dtype)
-    h = jax.nn.silu(jnp.einsum("nd,edf->enf", x, w1,
-                               preferred_element_type=jnp.float32)) \
-        * jnp.einsum("nd,edf->enf", x, w3,
-                     preferred_element_type=jnp.float32)
-    h = h * comb.T[..., None]
-    out = jnp.einsum("enf,efd->nd", h.astype(w2.dtype), w2,
-                     preferred_element_type=jnp.float32)
-    rows = jnp.sum(jnp.any(hit, axis=1) & counted[:, None], axis=0)
-    stats = jnp.stack([jnp.sum(rows), jnp.sum(rows > 0), jnp.max(rows)])
-    return out, stats.astype(jnp.int32)
 
 
 def _forward(params, tokens, pos, valid, pools, page_table, cfg,
@@ -486,16 +411,8 @@ def decode_forward(params, tokens, pools, page_table, lengths, active,
     numerics guard's [nonfinite rows, quant clips]."""
     logits, pools, counters = decode_logits(
         params, tokens, pools, page_table, lengths, active, cfg=cfg)
-    with jax.named_scope("sample"):
-        next_tokens = _sample_rows(logits, seeds, lengths + 1, temps,
-                                   top_ks, top_ps)
-    out = jnp.concatenate([next_tokens, counters])
-    if with_stats:
-        bad = jnp.any(~jnp.isfinite(logits), axis=-1)
-        guard = jnp.stack([jnp.sum((active & bad).astype(jnp.int32)),
-                           jnp.int32(0)])
-        return out, pools, guard
-    return out, pools
+    return step_output(logits, counters, pools, lengths, active, seeds,
+                       temps, top_ks, top_ps, with_stats)
 
 
 def decode_probe(params, tokens, pools, page_table, lengths, active, *,

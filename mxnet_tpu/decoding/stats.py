@@ -146,6 +146,14 @@ class DecodeStats:
             # (rows x bucket) their programs were given
             self.live_pages = 0
             self.bucket_pages = 0
+            # running sums over the steps: context positions their
+            # attention read, those of them a windowed page group's
+            # layers read, the pages each page group had handed out;
+            # and the pages a windowed group got back from live rows
+            self.ctx_tokens = 0
+            self.window_tokens = 0
+            self.pages_held = []
+            self.window_pages_released = 0
             self.nonfinite_logit_steps = 0
             self.nonfinite_logits = 0
             self.quant_clip_steps = 0
@@ -212,15 +220,24 @@ class DecodeStats:
         _PREFILL_LATENCY_MS.observe(seconds * 1e3, model=self._key)
 
     def note_step(self, live_rows, seconds, live_pages=0,
-                  bucket_pages=0):
+                  bucket_pages=0, ctx_tokens=0, window_tokens=0,
+                  pages_held=()):
         """One continuous-decode step: `live_rows` tokens emitted, its
-        context in `live_pages` pages of the program's
-        `bucket_pages` slots."""
+        context of `ctx_tokens` positions (`window_tokens` of them in
+        reach of a windowed group's layers) in `live_pages` pages of
+        the program's `bucket_pages` slots, with `pages_held` pages
+        out of each page group's allocator."""
         with self._lock:
             self.steps += 1
             self.decode_tokens += live_rows
             self.live_pages += live_pages
             self.bucket_pages += bucket_pages
+            self.ctx_tokens += ctx_tokens
+            self.window_tokens += window_tokens
+            if len(self.pages_held) < len(pages_held):
+                self.pages_held = [0] * len(pages_held)
+            for i, n in enumerate(pages_held):
+                self.pages_held[i] += n
             self._decode_s += seconds
             if live_rows:
                 per_tok = seconds / live_rows
@@ -241,6 +258,13 @@ class DecodeStats:
                         self.counters.get(name, 0), v)
                 else:
                     self.counters[name] = self.counters.get(name, 0) + v
+
+    def note_released(self, pages):
+        """Pages a windowed page group got back from rows still live
+        (behind their windows)."""
+        if pages:
+            with self._lock:
+                self.window_pages_released += pages
 
     def note_nonfinite(self, rows, steps=1):
         """Guard trip: `rows` active rows produced NaN/Inf logits
@@ -316,6 +340,10 @@ class DecodeStats:
                 "live_page_share": round(
                     self.live_pages / self.bucket_pages, 4)
                 if self.bucket_pages else 0.0,
+                "ctx_tokens": self.ctx_tokens,
+                "window_tokens": self.window_tokens,
+                "pages_held": list(self.pages_held),
+                "window_pages_released": self.window_pages_released,
                 "nonfinite_logit_steps": self.nonfinite_logit_steps,
                 "nonfinite_logits": self.nonfinite_logits,
                 "quant_clip_steps": self.quant_clip_steps,

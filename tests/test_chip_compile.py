@@ -466,6 +466,92 @@ def test_sparse_latent_program_holds_no_pool_sized_copy(v5e, sparse_engine,
     assert temps[1] - temps[0] <= 0.05 * temps[0], temps
 
 
+# the third block at its published attention widths (MiMo-V2.5: 64 heads
+# of 192/128 against 4 and 8 KV heads, window 128), two experts held
+WINDOW_MIXED = {"rows": 8, "page_size": 64, "bucket": 48, "chunk": 128}
+
+
+@pytest.fixture(scope="module")
+def window_mixed_engine():
+    from mxnet_tpu import decoding as dec
+
+    c = WINDOW_MIXED
+    cfg = dec.WindowMixedConfig(
+        vocab=1024, d_model=4096, n_heads=64, head_dim=192, v_head_dim=128,
+        kv_heads=4, window_kv_heads=8, window=128,
+        layer_pattern=(0, 1, 1), expert_layers=(0, 1, 1), rotary_dim=64,
+        d_ff=2048, d_expert=2048, n_experts=256, experts_held=(0, 2),
+        experts_per_token=8, eos_id=-1, prefill_chunk=c["chunk"])
+    eng = dec.DecodeEngine(
+        {}, cfg, max_batch=c["rows"], page_size=c["page_size"],
+        num_pages=(c["bucket"] + 1, 16), page_buckets=(c["bucket"],),
+        kernel="pallas", kv_dtype="bf16")
+    eng._donate = True
+    return eng
+
+
+def _window_mixed_program(eng, program, pages):
+    from mxnet_tpu.decoding import window_mixed
+
+    c, cfg = WINDOW_MIXED, eng.cfg
+    params = {n: _s(shape, jnp.float32 if n.endswith(("gate_bias", "sink"))
+                    else jnp.bfloat16)
+              for n, shape in window_mixed.param_shapes(cfg).items()}
+    pools = tuple(jax.eval_shape(
+        lambda pl=pl, gi=gi: quant.make_plane(
+            pl.layers, pages[gi], c["page_size"], pl, "bf16"))
+        for pl, gi in zip(cfg.planes, eng._plane_group))
+    r, i32 = c["rows"], _s((), jnp.int32)
+    if program == "decode":
+        fn = eng._build_decode_fn(c["bucket"])
+        args = (params, _s((r,), jnp.int32), pools,
+                _s((2, r, c["bucket"]), jnp.int32), _s((r,), jnp.int32),
+                _s((r,), jnp.bool_), _s((r,), jnp.uint32),
+                _s((r,), jnp.float32), _s((r,), jnp.int32),
+                _s((r,), jnp.float32))
+    else:
+        fn = eng._build_chunk_fn(c["chunk"], c["bucket"])
+        args = (params, _s((1, c["chunk"]), jnp.int32), i32, i32, pools,
+                _s((2, c["bucket"]), jnp.int32), _s((), jnp.uint32),
+                _s((), jnp.float32), i32, _s((), jnp.float32))
+    return getattr(fn, "fn", fn), args, pools
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_window_mixed_program_holds_no_pool_sized_copy(
+        v5e, window_mixed_engine, program, monkeypatch):
+    """PR 26's property for the third configuration: the four planes of
+    both page groups are written in place (all four widths are
+    multiples of 128 lanes), every read comes from the pool (a decode
+    step's through the in-place kernel, one `tpu_custom_call` a layer
+    with grouped queries, key 192 beside value 128, the window and the
+    sink: Mosaic takes them at the published widths), and the
+    temporaries do not grow with either pool."""
+    monkeypatch.setattr(utils, "pallas_interpret", lambda: False)
+    temps = []
+    # pool sizes that no gathered context (rows x bucket pages) equals
+    for pages in ((1536, 512), (3072, 1024)):
+        fn, args, pools = _window_mixed_program(window_mixed_engine,
+                                                program, pages)
+        placed = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            args)
+        compiled = fn.lower(*placed).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        if pages[0] == 1536:
+            text = compiled.as_text()
+            assert f"jit_window_mixed_{program}" in text.split("\n", 1)[0]
+            assert [p.data.shape[-1] for p in pools] == [
+                768, 512, 1536, 1024]
+            if program == "decode":
+                assert text.count("tpu_custom_call") >= 3
+            for pool in pools:
+                # a chunk's key blocks are a loop that carries the pools
+                assert not _pool_sized_ops(text, pool.data.shape,
+                                           carried=("while",))
+    assert temps[1] - temps[0] <= 0.05 * temps[0], temps
+
+
 def test_rtc_pallas_kernel_compiles(compile_for_chip):
     def double_kernel(x_ref, o_ref):
         o_ref[...] = x_ref[...] * 2.0

@@ -345,6 +345,127 @@ def test_in_place_kernel_matches_lax(kv_dtype, q_dtype, atol, block_pages):
     np.testing.assert_array_equal(outs[0], outs[1])
 
 
+def _grouped_pools(kv_dtype, lengths, page, bucket, kv_heads, dk, dv,
+                   window, seed, foreign):
+    """(K layer, V layer, page table, K rows, V rows) of two-layer
+    pools for grouped queries: `kv_heads` heads of `dk` in a K row and
+    of `dv` in a V row, `lengths` tokens a row on layer 1. With a
+    `window` the pages wholly behind a row's last `window` positions
+    are RELEASED: their table entries point at the scratch page. What no
+    row may read holds `foreign` values."""
+    from mxnet_tpu.decoding import quant
+
+    rs = np.random.RandomState(seed)
+    n = 1 + sum(pages_needed(ln, page) for ln in lengths) + 6
+    fs = np.random.RandomState(foreign)
+    table = np.zeros((len(lengths), bucket), np.int32)
+    free = iter(rs.permutation(np.arange(1, n)))
+    owned = [np.asarray([next(free) for _ in range(pages_needed(ln, page))],
+                        np.int32) for ln in lengths]
+    layers, rows = [], []
+    for name, width in (("k", dk), ("v", dv)):
+        pool = quant.make_plane(
+            2, n, page, quant.Plane(name, kv_heads * width, kv_heads),
+            kv_dtype)
+        pool, _ = quant.kv_scatter(
+            pool, 1, np.repeat(np.arange(n), page),
+            np.tile(np.arange(page), n), jnp.asarray(
+                3.0 * fs.randn(n * page, kv_heads * width), jnp.float32))
+        kept = []
+        for row, ln in enumerate(lengths):
+            at = np.arange(ln)
+            vals = rs.randn(ln, kv_heads * width).astype(np.float32)
+            if ln:
+                pool, _ = quant.kv_scatter(
+                    pool, 1, owned[row][at // page], at % page,
+                    jnp.asarray(vals))
+            kept.append(np.asarray(jnp.asarray(vals).astype(
+                pool.data.dtype).astype(jnp.float32)))
+        layers.append(pool.layer(1))
+        rows.append(kept)
+    for row, ln in enumerate(lengths):
+        first = max(0, ln - window) // page if window else 0
+        table[row, first:len(owned[row])] = owned[row][first:]
+    return layers[0], layers[1], table, rows[0], rows[1]
+
+
+@pytest.mark.parametrize("kv_dtype,atol", [("float32", 1e-5),
+                                           ("bf16", 1e-5)])
+@pytest.mark.parametrize("window,with_sink", [
+    (None, False), (8, False), (8, True), (None, True)])
+def test_grouped_kernel_matches_lax_and_dense(kv_dtype, atol, window,
+                                              with_sink):
+    """The in-place kernel (interpreted) against the lax form, and both
+    against attention worked out row by row, for what the window-mixed
+    block asks of them: a group of 4 query heads against each of 2 KV
+    heads, keys of 12 beside values of 8 (the served 192 and 128 in
+    proportion), a window of 8 = 2 pages with the pages behind it
+    released, a sink a head, on ragged lengths with rows shorter than
+    the window. Pools that differ only in what no row may read (the
+    released pages' scratch page among it) give the same output to the
+    last bit."""
+    h, kv, dk, dv, page, bucket = 8, 2, 12, 8, 4, 5
+    lengths = np.asarray(_KERNEL_LENGTHS, np.int32)
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(len(lengths), h, dk), jnp.float32)
+    sink = jnp.asarray(rs.randn(h), jnp.float32) if with_sink else None
+    how = {"kv_heads": kv, "window": window, "sink": sink}
+    outs = []
+    for foreign in (101, 202):
+        k, v, table, k_rows, v_rows = _grouped_pools(
+            kv_dtype, lengths, page, bucket, kv, dk, dv, window, seed=17,
+            foreign=foreign)
+        outs.append(np.asarray(dec.paged_attention_pallas(
+            q, k, v, table, lengths, block_pages=2, **how), np.float32))
+    lax = np.asarray(dec.paged_attention_lax(q, k, v, table, lengths,
+                                             **how), np.float32)
+    live = lengths > 0
+    assert outs[0].shape == (len(lengths), h, dv)
+    np.testing.assert_allclose(outs[0][live], lax[live], atol=atol)
+    np.testing.assert_array_equal(outs[0][~live], 0.0)
+    np.testing.assert_array_equal(outs[0], outs[1])
+    for row in np.nonzero(live)[0]:
+        ln = int(lengths[row])
+        lo = max(0, ln - window) if window else 0
+        for head in range(h):
+            g = head // (h // kv)
+            keys = k_rows[row][lo:, g * dk:(g + 1) * dk]
+            vals = v_rows[row][lo:, g * dv:(g + 1) * dv]
+            sc = keys @ np.asarray(q[row, head]) / np.sqrt(dk)
+            m = max(sc.max(), float(sink[head]) if with_sink else -np.inf)
+            e = np.exp(sc - m)
+            den = e.sum() + (np.exp(float(sink[head]) - m)
+                             if with_sink else 0.0)
+            np.testing.assert_allclose(lax[row, head], (e / den) @ vals,
+                                       atol=1e-5)
+
+
+def test_grouped_multi_query_form_matches_single_queries():
+    """The multi-query form with grouped heads, a window and a sink,
+    its keys walked in blocks with the softmax online across them,
+    against the single-query form one query at a time."""
+    from mxnet_tpu.decoding import attention
+
+    h, kv, dk, dv, page, bucket, window = 8, 2, 12, 8, 4, 8, 8
+    k, v, table, _, _ = _grouped_pools(
+        "float32", [30], page, bucket, kv, dk, dv, None, seed=3, foreign=4)
+    rs = np.random.RandomState(6)
+    q = jnp.asarray(rs.randn(1, 9, h, dk), jnp.float32)
+    sink = jnp.asarray(rs.randn(h), jnp.float32)
+    pos = np.arange(21, 30, dtype=np.int32)[None]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(attention, "KEY_BLOCK", 8)
+        for how in ({"kv_heads": kv},
+                    {"kv_heads": kv, "window": window, "sink": sink}):
+            many = np.asarray(dec.attention.paged_attention_lax_multi(
+                q, k, v, table, pos, **how))
+            for j in range(9):
+                one = dec.paged_attention_lax(
+                    q[:, j], k, v, table, pos[:, j] + 1, **how)
+                np.testing.assert_allclose(many[:, j], np.asarray(one),
+                                           atol=1e-5)
+
+
 def test_in_place_kernel_reads_no_page_past_the_table():
     """A length past the bucket (a row at its cap) attends the table's
     pages and no further, as the lax form's mask over the gathered
@@ -747,6 +868,8 @@ def test_decoding_stats_view_shape_pinned():
             "cancelled", "preemptions", "readmissions", "prefills",
             "prefill_tokens", "decode_tokens", "steps", "prefill_chunks",
             "live_pages", "bucket_pages", "live_page_share",
+            "ctx_tokens", "window_tokens", "pages_held",
+            "window_pages_released",
             "spec_proposed", "spec_accepted", "spec_acceptance_rate",
             "tokens_per_target_step",
             "nonfinite_logit_steps", "nonfinite_logits",

@@ -276,22 +276,39 @@ def _spans(name):
     return [s for s in trace.recent_spans() if s.name == name]
 
 
-# (e) the share ties to the model: 16 shares of one expert each, the
-# shared expert counted once, sum to the uncut layer
-def test_expert_shares_sum_to_uncut_layer(ref, params):
-    cfg = config_object(TINY)
+# (e) the share ties to the model: 16 shares of one expert each, what
+# every chip computes alike (a shared expert, where the block has one)
+# counted once, sum to the uncut layer: for both blocks with experts
+def _expert_blocks(ref, params):
+    import test_window_mixed as twm
+
+    spec = importlib.util.spec_from_file_location(
+        "ref_mimo_v25_ep16", os.path.join(
+            ROOT, "perfbench/references/mimo_v25_ep16.py"))
+    wm_ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(wm_ref)
+    with jax.default_matmul_precision("highest"):
+        wm_params = wm_ref.make_params(7, twm.TINY, jnp.float32)
+    return {"sparse_latent": (ref, params, TINY, config_object(TINY), True),
+            "window_mixed": (wm_ref, wm_params, twm.TINY,
+                             twm.config_object(twm.TINY), False)}
+
+
+@pytest.mark.parametrize("block", ["sparse_latent", "window_mixed"])
+def test_expert_shares_sum_to_uncut_layer(ref, params, block):
+    ref, params, tiny, cfg, has_shared = _expert_blocks(ref, params)[block]
     x = jnp.asarray(np.random.RandomState(3).randn(24, 64), jnp.float32)
     layer = 1
-    whole = np.asarray(ref.expert_layer(params, layer, x, TINY))
+    whole = np.asarray(ref.expert_layer(params, layer, x, tiny))
     xh = sl._rms(x, params[f"l{layer}.ffn_norm"], cfg.rms_eps)
-    shared = np.asarray(sl._swiglu(xh, params[f"l{layer}.shared_w1"],
-                                   params[f"l{layer}.shared_w3"],
-                                   params[f"l{layer}.shared_w2"]))
+    shared = np.asarray(sl._swiglu(
+        xh, params[f"l{layer}.shared_w1"], params[f"l{layer}.shared_w3"],
+        params[f"l{layer}.shared_w2"])) if has_shared \
+        else np.zeros((24, 64), np.float32)
     chosen, weights = sl.route(params, layer, xh, cfg)
     total, rows = shared.copy(), 0
     for share in range(16):
-        one = config_object(TINY)
-        one = type(one)(**{**one.__dict__, "experts_held": (share, 1)})
+        one = type(cfg)(**{**cfg.__dict__, "experts_held": (share, 1)})
         held = {k: (v[share:share + 1] if "experts_" in k else v)
                 for k, v in params.items()}
         part, stats = sl.held_experts(held, layer, xh, chosen, weights,
@@ -301,9 +318,9 @@ def test_expert_shares_sum_to_uncut_layer(ref, params):
         # the reference, given the same share, gives the same part
         np.testing.assert_allclose(
             np.asarray(part) + shared,
-            np.asarray(ref.expert_layer(held, layer, x, TINY,
+            np.asarray(ref.expert_layer(held, layer, x, tiny,
                                         share=(share, 1))), atol=2e-5)
-    assert rows == 24 * TINY["num_experts_per_tok"]
+    assert rows == 24 * tiny["num_experts_per_tok"]
     np.testing.assert_allclose(total, whole, atol=5e-5)
 
 
@@ -475,13 +492,40 @@ def test_kernel_choice_does_not_reach_the_sparse_programs(
     assert "paged_attention" not in got
 
 
-def test_planes_of_both_configurations():
+def test_planes_of_the_three_configurations():
+    import test_window_mixed as twm
+
     dense = dec.DecoderConfig(d_model=32, n_heads=2)
     assert [(p.name, p.width, p.groups) for p in dense.planes] == [
         ("k", 32, 2), ("v", 32, 2)]
     sparse = config_object(TINY)
     assert [(p.name, p.width, p.groups) for p in sparse.planes] == [
         ("latent", 24, 1), ("index_key", 16, 1)]
+    # one page group over every layer, for both
+    for cfg in (dense, sparse):
+        assert cfg.page_groups == (dec.PageGroup(),)
+        assert all((p.layers, p.group) == (0, "") for p in cfg.planes)
+    # the third: a k/v pair for each kind of layer, at that kind's KV
+    # heads and layer count, in two page groups of which one has a window
+    mixed = twm.config_object(twm.TINY)
+    assert list(mixed.planes) == [
+        dec.Plane("k", 24, 2, 2, "full"), dec.Plane("v", 16, 2, 2, "full"),
+        dec.Plane("k_win", 48, 4, 5, "window"),
+        dec.Plane("v_win", 32, 4, 5, "window")]
+    assert mixed.page_groups == (dec.PageGroup("full"),
+                                 dec.PageGroup("window", 8))
+    weng = dec.DecodeEngine({}, mixed, max_batch=2, page_size=PAGE,
+                            num_pages=(32, 12), page_buckets=(16,),
+                            kernel="lax")
+    assert [p.data.shape for p in weng._pools] == [
+        (2, 32, PAGE, 24), (2, 32, PAGE, 16), (5, 12, PAGE, 48),
+        (5, 12, PAGE, 32)]
+    assert [a.capacity() for a in weng.allocators] == [31, 11]
+    assert weng.pool_stats()["kv_bytes_per_token"] == (
+        2 * (24 + 16) + 5 * (48 + 32)) * 4
+    assert weng.pool_stats()["window_pages_total"] == 11
+    assert weng.step_program(16) == "jit_window_mixed_decode_p16"
+    assert weng.prefill_pages(100) == [25, 5]   # 8 + 7 positions + 1
     eng = _engine_shapes(sparse)
     assert quant_plane_widths(eng) == [24, 16]
     # a 576-wide row is stored in whole 128-lane tiles, a toy row as is

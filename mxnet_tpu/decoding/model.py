@@ -201,23 +201,26 @@ def reference_logits(params, tokens, cfg, attn_fn=None):
 # -------------------------------------------------------------- sampling
 def _pick_token(logits, seed, position, temperature, top_k, top_p):
     """Single-position token choice: greedy argmax when no sampling
-    params were threaded through (seed None — the PR 8 call shape),
-    else the counter-keyed sampler (sampling.sample_token)."""
+    params were threaded through (seed None — the PR 8 call shape) or
+    the request's temperature is 0, else the counter-keyed sampler
+    (sampling.sample_token); the branch is taken on the device."""
     if seed is None:
         return jnp.argmax(logits).astype(jnp.int32)
-    return _sampling.sample_token(logits, seed, position, temperature,
-                                  top_k, top_p)
+    return jax.lax.cond(
+        temperature > 0.0,
+        lambda lg: _sampling.sample_token(lg, seed, position, temperature,
+                                          top_k, top_p),
+        lambda lg: jnp.argmax(lg).astype(jnp.int32), logits)
 
 
 def _sample_rows(logits, seeds, positions, temps, top_ks, top_ps):
     """Each row's next token: argmax without sampling arrays, else
-    drawn per row on its (seed, position) stream."""
+    drawn per row on its (seed, position) stream
+    (sampling.sample_rows)."""
     if seeds is None:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.vmap(
-        lambda lg, sd, p, tm, tk, tp: _sampling.sample_token(
-            lg, sd, p, tm, tk, tp))(
-        logits, seeds, positions, temps, top_ks, top_ps)
+    return _sampling.sample_rows(logits, seeds, positions, temps, top_ks,
+                                 top_ps)
 
 
 # --------------------------------------------------------------- prefill

@@ -148,6 +148,24 @@ def sample_token(logits, seed, position, temperature, top_k, top_p,
                      sampled).astype(jnp.int32)
 
 
+def sample_rows(logits, seeds, positions, temps, top_ks, top_ps):
+    """Each row's token (B,) int32 from `logits` (B, V), drawn on its
+    (seed, position) stream. The branch is chosen once for the batch,
+    on the device: a batch with no row above temperature 0 takes the
+    plain argmax (no sort, no Gumbel draw); any other takes every
+    row's `sample_token`, whose greedy rows give that same argmax. The
+    predicate stays outside the `vmap`: under it a `cond` turns into a
+    `select` that runs both branches."""
+    def sampled(lg):
+        return jax.vmap(sample_token)(lg, seeds, positions, temps,
+                                      top_ks, top_ps)
+
+    def greedy(lg):
+        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temps > 0.0), sampled, greedy, logits)
+
+
 def sample_from_dist(dist, seed, position, salt):
     """Draw from an explicit probability vector (V,) via Gumbel-max on
     log-probabilities (speculative residual resampling)."""

@@ -968,7 +968,7 @@ class ContinuousScheduler:
             (tokens, table, lengths, active, use_draft, *samp), \
                 tail_rows, bucket = self._pack(live)
             step_attrs = self._step_attrs(live, bucket, lengths, active,
-                                          k + 1)
+                                          samp[1], k + 1)
         with _trace.span("decoding.step", **step_attrs) as step_span:
             t0 = _trace.now()
             if spec:
@@ -998,7 +998,7 @@ class ContinuousScheduler:
                     if clips:
                         self.stats.note_quant_clips(clips)
 
-    def _step_attrs(self, live, bucket, lengths, active, new):
+    def _step_attrs(self, live, bucket, lengths, active, temps, new):
         """What a `decoding.step` span says of the step, from its packed
         rows (`new` positions a row are written and read beside its
         context)."""
@@ -1017,7 +1017,10 @@ class ContinuousScheduler:
             # pages every group's allocator has handed out
             "pages_held": tuple(a.pages_in_use()
                                 for a in engine.allocators),
-            "program": engine.step_program(bucket)}
+            "program": engine.step_program(bucket),
+            # rows that sample: with none the step's sampler takes its
+            # argmax branch (sampling.sample_rows)
+            "sampled_rows": int((active & (temps > 0)).sum())}
         for g in engine.groups:
             if g.window:
                 # the positions of them a windowed group's layers read
@@ -1032,7 +1035,8 @@ class ContinuousScheduler:
             self.engine.step_rows * attrs["bucket"],
             ctx_tokens=attrs["ctx_tokens"],
             window_tokens=attrs.get("window_tokens", 0),
-            pages_held=attrs["pages_held"])
+            pages_held=attrs["pages_held"],
+            greedy=not attrs["sampled_rows"])
 
     def _pack(self, live):
         """The step's fixed-shape row arrays: returns ((tokens, table,
@@ -1207,7 +1211,8 @@ class ContinuousScheduler:
         with _trace.span("decoding.pack"):
             (tokens, table, lengths, active, _draft, *samp), _tails, \
                 bucket = self._pack(live)
-            attrs = self._step_attrs(live, bucket, lengths, active, 1)
+            attrs = self._step_attrs(live, bucket, lengths, active,
+                                     samp[1], 1)
             if self._ahead:
                 tokens = engine.next_tokens(self._ahead[-1][1])
         out = engine.launch_step(tokens, table, lengths, active, *samp)

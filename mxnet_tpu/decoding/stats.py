@@ -142,6 +142,9 @@ class DecodeStats:
             self.prefill_tokens = 0
             self.decode_tokens = 0
             self.steps = 0
+            # steps launched with no row above temperature 0: their
+            # sampler took the argmax branch
+            self.greedy_steps = 0
             # pages the steps' contexts lay in, of the page slots
             # (rows x bucket) their programs were given
             self.live_pages = 0
@@ -221,14 +224,16 @@ class DecodeStats:
 
     def note_step(self, live_rows, seconds, live_pages=0,
                   bucket_pages=0, ctx_tokens=0, window_tokens=0,
-                  pages_held=()):
+                  pages_held=(), greedy=False):
         """One continuous-decode step: `live_rows` tokens emitted, its
         context of `ctx_tokens` positions (`window_tokens` of them in
         reach of a windowed group's layers) in `live_pages` pages of
         the program's `bucket_pages` slots, with `pages_held` pages
-        out of each page group's allocator."""
+        out of each page group's allocator; `greedy` where no row of
+        it sampled."""
         with self._lock:
             self.steps += 1
+            self.greedy_steps += bool(greedy)
             self.decode_tokens += live_rows
             self.live_pages += live_pages
             self.bucket_pages += bucket_pages
@@ -335,6 +340,7 @@ class DecodeStats:
                 "prefill_tokens": self.prefill_tokens,
                 "decode_tokens": self.decode_tokens,
                 "steps": self.steps,
+                "greedy_steps": self.greedy_steps,
                 "live_pages": self.live_pages,
                 "bucket_pages": self.bucket_pages,
                 "live_page_share": round(

@@ -675,3 +675,34 @@ def test_chip_smoke_without_a_tpu_fails_and_prints_no_result():
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout, proc.stdout
     assert "needs a TPU" in proc.stderr
+
+
+@pytest.mark.parametrize("block", ["dense", "sparse_latent", "window_mixed"])
+def test_greedy_batch_runs_no_sort_of_the_vocabulary(
+        v5e, block, cell_engine, sparse_engine, window_mixed_engine,
+        monkeypatch):
+    """Compiled for the chip, each block's decode program keeps the
+    sampler's sort in the conditional's branch that a batch with a
+    sampled row takes: a greedy batch runs none (what the chat cell's
+    `sort.5` was). The sparse block's selection keeps its own."""
+    from test_decoding import assert_sampler_sorts_in_branch
+
+    monkeypatch.setattr(utils, "pallas_interpret", lambda: False)
+    if block == "dense":
+        fn, args, _ = _cell_program(cell_engine, "decode", "bf16",
+                                    CELL["pages"])
+    elif block == "sparse_latent":
+        fn, args, _ = _sparse_program(sparse_engine, "decode", 1536)
+    else:
+        fn, args, _ = _window_mixed_program(window_mixed_engine, "decode",
+                                            (1536, 512))
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        args)
+    always = assert_sampler_sorts_in_branch(
+        fn.lower(*placed).compile().as_text())
+    # what the program always sorts: the selection's and the router's
+    # top-k, by the scope that holds each
+    assert {s.split("/")[-2] for s in always} == {
+        "dense": set(), "sparse_latent": {"index", "router"},
+        "window_mixed": {"router"}}[block], always

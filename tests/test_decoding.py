@@ -866,7 +866,8 @@ def test_decoding_stats_view_shape_pinned():
         assert sorted(snap) == sorted((
             "submitted", "completed", "failed", "rejected", "expired",
             "cancelled", "preemptions", "readmissions", "prefills",
-            "prefill_tokens", "decode_tokens", "steps", "prefill_chunks",
+            "prefill_tokens", "decode_tokens", "steps", "greedy_steps",
+            "prefill_chunks",
             "live_pages", "bucket_pages", "live_page_share",
             "ctx_tokens", "window_tokens", "pages_held",
             "window_pages_released",
@@ -1449,3 +1450,175 @@ def test_merged_step_off_without_prefix_cache():
         assert m.engine.step_rows == m.engine.max_batch
     finally:
         m.close()
+
+
+# ------------------------------------------------------- sampler branch
+from mxnet_tpu.decoding import sampling as _sampling  # noqa: E402
+
+SAMPLER_ROWS = 6
+# per row: temperature, top_k, top_p
+SAMPLER_CASES = {
+    "all_greedy": ([0.0] * 6, [0] * 6, [1.0] * 6),
+    "one_sampled": ([0.0, 0.0, 0.8, 0.0, 0.0, 0.0], [0, 5, 0, 0, 5, 0],
+                    [1.0, 0.9, 1.0, 1.0, 1.0, 0.9]),
+    "sampled_k0_p1": ([0.7] * 6, [0] * 6, [1.0] * 6),
+    "sampled_k5_p1": ([1.3] * 6, [5] * 6, [1.0] * 6),
+    "sampled_k0_p09": ([0.9] * 6, [0] * 6, [0.9] * 6),
+    "sampled_k5_p09": ([1.0] * 6, [5] * 6, [0.9] * 6),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+@pytest.mark.parametrize("case", sorted(SAMPLER_CASES))
+def test_sample_rows_is_the_per_row_sampler(case, seed):
+    """`sampling.sample_rows` gives every row the token that each row's
+    own `sample_token` gives it, bit for bit, whichever branch the batch
+    takes; a batch of greedy rows gives the argmax whatever the seeds."""
+    temps, top_ks, top_ps = (np.asarray(v, t) for v, t in zip(
+        SAMPLER_CASES[case], (np.float32, np.int32, np.float32)))
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(size=(SAMPLER_ROWS, 97)).astype(np.float32) * 3
+    seeds = rng.integers(0, 2**32, SAMPLER_ROWS, dtype=np.uint32)
+    positions = rng.integers(1, 4096, SAMPLER_ROWS).astype(np.int32)
+    args = (logits, seeds, positions, temps, top_ks, top_ps)
+    got = np.asarray(jax.jit(_sampling.sample_rows)(*args))
+    per_row = np.asarray(jax.jit(jax.vmap(_sampling.sample_token))(*args))
+    np.testing.assert_array_equal(got, per_row)
+    assert got.dtype == np.int32
+    if not temps.any():
+        other = np.asarray(jax.jit(_sampling.sample_rows)(
+            logits, seeds ^ np.uint32(0x5A5A5A5A), positions + 1, temps,
+            top_ks, top_ps))
+        np.testing.assert_array_equal(other, got)
+        np.testing.assert_array_equal(got, logits.argmax(axis=-1))
+
+
+def sort_places(text):
+    """A compiled program's sorts by where they run, each as its
+    instruction's `op_name`: (those the program always runs, those
+    inside a conditional's branch). A computation reached from the
+    entry through anything but a branch (a fusion, a loop, a call)
+    always runs."""
+    import re
+
+    from mxnet_tpu.profiling import timeline
+
+    entry, comps = timeline._computations(text)
+    branch_of = re.compile(r"(?:branch_computations|true_computation|"
+                           r"false_computation)=(\{[^}]*\}|%[\w.\-]+)")
+    name_of = re.compile(r"%([\w.\-]+)")
+
+    def refs(comp):
+        always, branch = set(), set()
+        for ln in comps[comp]:
+            ln = ln.split("metadata={")[0]
+            br = {n for g in branch_of.findall(ln)
+                  for n in name_of.findall(g)}
+            for n in name_of.findall(ln):
+                if n in comps:
+                    (branch if n in br else always).add(n)
+        return always, branch
+
+    def reach(starts, through_branches):
+        seen, todo, entered = set(), list(starts), set()
+        while todo:
+            comp = todo.pop()
+            if comp in seen:
+                continue
+            seen.add(comp)
+            always, branch = refs(comp)
+            todo.extend(always)
+            if through_branches:
+                todo.extend(branch)
+            entered |= branch
+        return seen, entered
+
+    always_run, branches = reach([entry], False)
+    in_branch, _ = reach(branches, True)
+
+    def sorts(comp_names):
+        out = []
+        for comp in comp_names:
+            for ln in comps[comp]:
+                if " sort(" in ln:
+                    m = re.search(r'op_name="([^"]*)"', ln)
+                    out.append(m.group(1) if m else "")
+        return out
+
+    return sorts(always_run), sorts(in_branch - always_run)
+
+
+def assert_sampler_sorts_in_branch(text):
+    """The sampler's sort lies in a conditional's branch alone: the
+    program always runs no sort of the `sample` scope. Returns the
+    sorts it always runs (another layer's)."""
+    always, branch = sort_places(text)
+    assert not [s for s in always if "/sample/" in s], always
+    assert [s for s in branch if "/sample/" in s], branch
+    return always
+
+
+def chunk_program_text(eng, tokens_bucket, pages_bucket):
+    """Compiled text of a warmed chunk-prefill program (the sparse-latent
+    and window-mixed blocks'), lowered over the warmup's arguments."""
+    return eng._chunk_fns[tokens_bucket, pages_bucket].lower(
+        eng._params, np.zeros((1, tokens_bucket), np.int32), jnp.int32(0),
+        jnp.int32(0), eng._pools,
+        np.zeros(eng.table_shape(pages_bucket), np.int32),
+        *eng._samp_scalars()).compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_p2", "decode_p4", "prefill_t4"])
+def test_dense_programs_sort_only_in_the_sampled_branch(program):
+    """`jit_decode_p{b}` and `jit_prefill_t{t}` of the dense block: the
+    vocabulary's sort runs only in the branch that a batch with a
+    sampled row, or a sampled prompt, takes."""
+    m = _model()
+    eng = m.engine
+    try:
+        if program.startswith("decode"):
+            text = eng.decode_program_text(int(program[len("decode_p"):]))
+        else:
+            lb = int(program[len("prefill_t"):])
+            text = eng._prefill_fns[lb].lower(
+                eng._params, np.zeros((1, lb), np.int32), jnp.int32(0),
+                eng._pools, np.zeros((pages_needed(lb, 4),), np.int32),
+                *eng._samp_scalars()).compile().as_text()
+        assert assert_sampler_sorts_in_branch(text) == []
+    finally:
+        m.close(drain=False)
+
+
+@pytest.mark.parametrize("run_ahead", [0, 3])
+def test_greedy_steps_count_the_steps_without_a_sampled_row(run_ahead):
+    """`decoding.step` spans carry `sampled_rows`, the active rows above
+    temperature 0, and `DecodeStats.greedy_steps` counts the steps with
+    none: every step of greedy requests; none of the steps a sampled
+    request is live in."""
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    sp = dec.SamplingParams(temperature=0.9, top_k=8, seed=11)
+    ttrace.set_capacity(4096)
+    try:
+        m = _model(max_tokens=16, merged_step=False, run_ahead=run_ahead)
+        futs = [m.submit([3, 4, 5], max_new_tokens=10),
+                m.submit([9, 8], max_new_tokens=6)]
+        [f.result(timeout=120) for f in futs]
+        greedy = m.stats.snapshot()
+        f = m.submit([7, 7, 2], max_new_tokens=8, sampling=sp)
+        f.result(timeout=120)
+        snap = m.stats.snapshot()
+        m.close()
+        spans = [s.attrs for s in ttrace.recent_spans()
+                 if s.name == "decoding.step"
+                 and (s.attrs or {}).get("model") == m.key]
+    finally:
+        ttrace.set_capacity(ttrace._env_capacity())
+    assert greedy["steps"] >= 9
+    assert greedy["greedy_steps"] == greedy["steps"]
+    assert [a["sampled_rows"] for a in spans[:greedy["steps"]]] \
+        == [0] * greedy["steps"]
+    sampled = [a["sampled_rows"] for a in spans[greedy["steps"]:]]
+    assert len(sampled) == snap["steps"] - greedy["steps"] >= 7
+    assert sampled == [1] * len(sampled)
+    assert snap["greedy_steps"] == greedy["steps"]

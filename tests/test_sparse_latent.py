@@ -556,3 +556,14 @@ def test_copy_page_covers_every_plane(eng):
                         eng.read_page(layer, spare)):
             np.testing.assert_array_equal(a, b)
             assert np.abs(a).sum() > 0
+
+
+# (j) a greedy batch runs no sort of the vocabulary: the sampler's sort
+# lies in the branch a sampled row takes, the selection's top-k sorts
+# where they were
+def test_sampler_sorts_only_in_the_sampled_branch(eng):
+    from test_decoding import assert_sampler_sorts_in_branch, \
+        chunk_program_text
+
+    assert_sampler_sorts_in_branch(eng.decode_program_text(16))
+    assert_sampler_sorts_in_branch(chunk_program_text(eng, 8, 16))

@@ -392,3 +392,14 @@ def test_copy_page_moves_one_group(params, eng):
                 assert np.abs(a).sum() > 0
         eng.allocators[group].free([spare])
     _free(eng, tables)
+
+
+def test_sampler_sorts_only_in_the_sampled_branch(eng):
+    """A greedy batch runs no sort of the vocabulary: in the decode and
+    the chunk programs the sampler's sort lies in the branch a sampled
+    row takes."""
+    from test_decoding import assert_sampler_sorts_in_branch, \
+        chunk_program_text
+
+    assert_sampler_sorts_in_branch(eng.decode_program_text(16))
+    assert_sampler_sorts_in_branch(chunk_program_text(eng, 8, 16))

@@ -50,7 +50,13 @@ package applies the Ragged Paged Attention recipe (PAPERS.md) instead:
              eviction, priority preemption, streaming DecodeFuture
              (whose TokenStream owns/cancels the request); with
              `run_ahead` it keeps steps in flight so that the device
-             does not wait for the host between them
+             does not wait for the host between them. Unset, the
+             backend decides (`config.run_ahead`): `RUN_AHEAD` (1)
+             on a TPU for the plain step, enough to hide the host's
+             turn behind the device's step; 0, the turn that waits
+             for each step, with a draft, the merged step or off the
+             TPU. Each `decoding.step` span's `in_flight` counts the
+             steps still launched as its tokens came out
   prefix     PrefixCache — radix index over cached prompt KV pages;
              admission maps shared prefixes via the fork path and
              prefills only the tail

@@ -263,8 +263,10 @@ class ContinuousScheduler:
         self.key = key
         # steps kept in flight beyond the one whose tokens the loop
         # waits for (see _turn_ahead); 0 is the turn that launches a
-        # step and waits for it
-        self.run_ahead = int(run_ahead or 0)
+        # step and waits for it. Unset, the backend decides
+        # (decoding.config.run_ahead)
+        self.run_ahead = int(run_ahead) if run_ahead is not None \
+            else _cfg.run_ahead(engine)
         if self.run_ahead and (engine.spec_enabled
                                or engine.merged_step_enabled):
             raise ServingError(
@@ -969,7 +971,8 @@ class ContinuousScheduler:
                 tail_rows, bucket = self._pack(live)
             step_attrs = self._step_attrs(live, bucket, lengths, active,
                                           samp[1], k + 1)
-        with _trace.span("decoding.step", **step_attrs) as step_span:
+        with _trace.span("decoding.step", in_flight=0,
+                         **step_attrs) as step_span:
             t0 = _trace.now()
             if spec:
                 out, n_emit = engine.spec_step(
@@ -1229,7 +1232,8 @@ class ContinuousScheduler:
         live, out, attrs, t_launch = self._ahead.popleft()
         host = engine.fetch_step(out, engine.max_batch)
         t_out = _trace.now()
-        step_span.note(**attrs)
+        # steps still launched while these tokens came out
+        step_span.note(in_flight=len(self._ahead), **attrs)
         if engine.cfg.step_counters:
             step_span.note(**engine.last_step_counters)
             self.stats.note_counters(engine.last_step_counters)

@@ -7,6 +7,7 @@ zero-retrace guarantee over the pre-traced decode grid, and the
 `decodingStats` view's pinned key shape."""
 import random
 import time
+from unittest import mock
 
 import jax
 import numpy as np
@@ -14,7 +15,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import decoding as dec
-from mxnet_tpu import serving
+from mxnet_tpu import serving, utils
 from mxnet_tpu.decoding import attention as attn
 from mxnet_tpu.decoding.blocks import (BlockAllocator, PageError,
                                        PagePoolExhausted, SCRATCH_PAGE,
@@ -45,13 +46,19 @@ CFG = dec.DecoderConfig(vocab=32, d_model=16, n_layers=2, n_heads=2,
 PARAMS = dec.init_decoder_params(CFG, seed=0)
 
 
-def _model(**kw):
+def _model(tpu=False, **kw):
+    """A toy decoder; `tpu` builds it where the backend reads as a TPU,
+    the fact the tier's defaults are resolved from (the kernel is then
+    named, so that nothing is built for the chip)."""
     kw.setdefault("max_batch", 2)
     kw.setdefault("page_size", 4)
     kw.setdefault("num_pages", 32)
     kw.setdefault("page_buckets", (1, 2, 4))
     kw.setdefault("max_tokens", 8)
-    return dec.DecodedModel("lm", 1, PARAMS, CFG, **kw)
+    if not tpu:
+        return dec.DecodedModel("lm", 1, PARAMS, CFG, **kw)
+    with mock.patch.object(utils, "pallas_interpret", lambda: False):
+        return dec.DecodedModel("lm", 1, PARAMS, CFG, kernel="lax", **kw)
 
 
 def _ref_greedy(prompt, n, cfg=CFG, eos=None, max_context=None):
@@ -669,11 +676,14 @@ def test_continuous_batching_parity_concurrent():
         m.close()
 
 
-# with steps in flight a victim's tokens come out before it is preempted
-AHEAD = [{}, {"run_ahead": 3, "merged_step": False}]
+# with steps in flight a victim's tokens come out before it is preempted;
+# the third keeps them by the backend's default, the argument unset
+AHEAD = [{}, {"run_ahead": 3, "merged_step": False},
+         {"tpu": True, "merged_step": False}]
+AHEAD_IDS = ["one_step", "run_ahead", "tpu_default"]
 
 
-@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+@pytest.mark.parametrize("ahead", AHEAD, ids=AHEAD_IDS)
 def test_preempt_then_readmit_bit_identical(ahead):
     """A pool far too small for the offered load: sequences are
     preempted (pages dropped) and readmitted (re-prefilled); the
@@ -702,7 +712,7 @@ def test_preempt_then_readmit_bit_identical(ahead):
         m.close()
 
 
-@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+@pytest.mark.parametrize("ahead", AHEAD, ids=AHEAD_IDS)
 def test_pool_exhaustion_never_crashes(ahead):
     """CI gate iii at unit scale: offered load >> pool capacity keeps
     resolving every future (no OOM, no dead scheduler)."""
@@ -795,7 +805,7 @@ def test_admission_errors():
 
 
 # ------------------------------------------------------- randomized soak
-@pytest.mark.parametrize("ahead", AHEAD, ids=["one_step", "run_ahead"])
+@pytest.mark.parametrize("ahead", AHEAD, ids=AHEAD_IDS)
 def test_randomized_soak(ahead):
     """Randomized continuous traffic (seeded via mx.random.py_rng —
     MX005-clean): mixed lengths, budgets, priorities, deadlines. Every
@@ -1212,23 +1222,33 @@ def test_run_ahead_keeps_steps_in_flight_and_tiles_the_turns():
             == {"decoding.step"}, name
     assert sum(s.attrs["tokens"] for s in spans
                if s.name == "decoding.emit") == 23
+    # the steps still launched as each one's tokens came out: three
+    # behind it until the row's budget is launched, then none left
+    assert [s.attrs["in_flight"] for s in steps] == [3] * 20 + [2, 1, 0]
 
 
-@pytest.mark.parametrize("run_ahead", [0, 3])
+# None: the argument unset where the backend reads as a TPU
+@pytest.mark.parametrize("run_ahead", [0, 3, None])
 def test_step_spans_and_stats_count_live_pages(run_ahead):
     """`live_pages` on every `decoding.step` span is the page table's
     own count: the entries of the step's active rows that hold their
     `lengths + 1` positions, none of them the scratch page and none
     left over. `DecodeStats` sums them beside the `rows x bucket` page
-    slots the programs were given."""
+    slots the programs were given. `in_flight` counts the steps still
+    launched as a span's tokens came out: none in the waited-for turn,
+    up to the depth with steps in flight."""
     from mxnet_tpu.telemetry import trace as ttrace
 
     ttrace.set_capacity(4096)
     try:
         m = _model(max_tokens=24, page_buckets=(1, 2, 4, 8),
-                   merged_step=False, run_ahead=run_ahead)
+                   merged_step=False, run_ahead=run_ahead,
+                   tpu=run_ahead is None)
+        depth = m.scheduler.run_ahead
+        assert depth == (dec.config.RUN_AHEAD if run_ahead is None
+                         else run_ahead)
         seen = []
-        name = "launch_step" if run_ahead else "step"
+        name = "launch_step" if depth else "step"
         inner = getattr(m.engine, name)
 
         def step(tokens, table, lengths, active, *samp):
@@ -1259,6 +1279,8 @@ def test_step_spans_and_stats_count_live_pages(run_ahead):
     assert snap["bucket_pages"] == sum(t.size for t, _, _ in seen)
     assert snap["live_page_share"] == round(
         snap["live_pages"] / snap["bucket_pages"], 4)
+    in_flight = [s.attrs["in_flight"] for s in spans]
+    assert max(in_flight) == depth and min(in_flight) == 0
 
 
 def test_run_ahead_admits_into_a_free_row_with_steps_in_flight():
@@ -1333,6 +1355,36 @@ def test_run_ahead_settles_before_a_decision():
 def test_run_ahead_is_the_plain_steps():
     with pytest.raises(serving.ServingError):
         _model(run_ahead=2, draft="self", spec_k=2)
+
+
+@pytest.mark.parametrize("tpu,kw,want", [
+    (True, {"merged_step": False}, dec.config.RUN_AHEAD),
+    (False, {"merged_step": False}, 0),
+    (True, {"draft": "self", "spec_k": 2}, 0),
+    (True, {}, 0),                        # the merged step: prefix cache on
+    (True, {"merged_step": False, "run_ahead": 0}, 0),
+    (False, {"merged_step": False, "run_ahead": 3}, 3),
+    (True, {"draft": "self", "spec_k": 2, "run_ahead": 2}, "raises"),
+    (True, {"run_ahead": 2}, "raises"),   # the merged step
+], ids=["tpu", "cpu", "tpu_draft", "tpu_merged", "explicit_0",
+        "explicit_d", "explicit_d_draft", "explicit_d_merged"])
+def test_run_ahead_resolves_by_backend(tpu, kw, want):
+    """Unset, the steps kept in flight follow what the engine can see:
+    the default depth on a TPU for the plain step, none off the TPU
+    or with a draft or the merged step. An explicit value wins, 0
+    included; an explicit depth beside a draft or the merged step is
+    refused."""
+    if want == "raises":
+        with pytest.raises(serving.ServingError):
+            _model(tpu=tpu, warmup=False, **kw)
+        return
+    m = _model(tpu=tpu, warmup=False, **kw)
+    try:
+        assert m.engine.merged_step_enabled == (
+            "merged_step" not in kw and "draft" not in kw)
+        assert m.scheduler.run_ahead == want
+    finally:
+        m.close()
 
 
 def test_reply_span_of_a_cancelled_request_is_the_handoff_alone():

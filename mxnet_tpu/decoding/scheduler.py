@@ -211,7 +211,7 @@ class _Sequence:
     __slots__ = ("prompt", "max_new", "priority", "deadline", "future",
                  "trace_id", "order", "sampling", "use_draft",
                  "generated", "table", "length", "last_token",
-                 "preempted", "t_submit_pc", "pending_tail",
+                 "preempted", "t_submit_pc", "t_queued", "pending_tail",
                  "tail_meta", "ahead", "side")
 
     def __init__(self, prompt, max_new, priority, deadline, future,
@@ -235,6 +235,8 @@ class _Sequence:
         self.last_token = -1
         self.preempted = False
         self.t_submit_pc = _trace.now()
+        # since when it waits for a row: its submit, or its preemption
+        self.t_queued = self.t_submit_pc
         # merged-step tail prefill (engine.merged_step_enabled): the
         # uncached prompt tail still to be fed through step() rows,
         # and (t0, n_ctx, start, need_total, n_matched) bookkeeping
@@ -274,6 +276,10 @@ class ContinuousScheduler:
                 "with speculation or the merged step")
         self._ahead = collections.deque()   # launched, tokens not out
         self._t_retired = 0.0
+        # prefills launched and launched steps taken out, ever: what a
+        # `decoding.admit` span counts of each inside it
+        self._n_prefills = 0
+        self._n_retired = 0
         self.queue_cap = queue_cap if queue_cap is not None \
             else _cfg.queue_cap()
         self.default_max_tokens = max_tokens if max_tokens is not None \
@@ -577,6 +583,7 @@ class ContinuousScheduler:
         # re-plans the whole prompt (possibly re-matching the cache)
         seq.pending_tail = None
         seq.tail_meta = None
+        seq.t_queued = _trace.now()
         with self._cond:
             for row, s in enumerate(self._rows):
                 if s is seq:
@@ -715,6 +722,7 @@ class ContinuousScheduler:
                 seq = min(self._waiting,
                           key=lambda s: (-s.priority, s.order))
                 self._waiting.remove(seq)
+            queued = _trace.now() - seq.t_queued
             tokens = seq.context_tokens()
             need_total = pages_needed(len(tokens), P)
             matched, start = [], 0
@@ -746,7 +754,6 @@ class ContinuousScheduler:
             with self._cond:
                 row = self._rows.index(None)
                 self._rows[row] = seq
-            t0 = _trace.now()
             if start and self.engine.merged_step_enabled:
                 # merged-step deferral: no tail-prefill dispatch here —
                 # the uncached tail rides the next decode step(s) as
@@ -757,37 +764,45 @@ class ContinuousScheduler:
                 # completion (_finish_tail), when the pages are real.
                 seq.pending_tail = list(tokens[start:])
                 seq.length = start
-                seq.tail_meta = (t0, len(tokens), start, need_total,
-                                 len(matched))
+                seq.tail_meta = (_trace.now(), len(tokens), start,
+                                 need_total, len(matched))
                 continue
-            launched = self.engine.launch_prefill(
-                tokens, [seq.table] + seq.side if seq.side else seq.table,
-                start=start,
-                seed=seq.sampling.seed,
-                temperature=seq.sampling.temperature,
-                top_k=seq.sampling.top_k, top_p=seq.sampling.top_p)
+            behind, launched = 0.0, None
             if self._ahead:
-                # the prefill waits on the device behind the steps in
+                # the prefill queues on the device behind the steps in
                 # flight: their tokens go out as they arrive, and the
                 # span is the prefill's own time
+                launched, launch_s = self._launch_prefill(seq, tokens,
+                                                          start)
+                t_launched = _trace.now()
                 self._settle()
+                behind = _trace.now() - t_launched
+            with _trace.span(
+                    "decoding.prefill", seq.trace_id, model=self.key,
+                    tokens=len(tokens), cached_tokens=start,
+                    pages=need_total, pages_reused=len(matched),
+                    readmission=seq.preempted) as fill:
                 t0 = _trace.now()
-            first = self.engine.fetch_prefill(launched)
-            dt = _trace.now() - t0
+                if launched is None:
+                    launched, launch_s = self._launch_prefill(
+                        seq, tokens, start)
+                first = self.engine.fetch_prefill(launched)
+                dt = _trace.now() - t0
+                # how many programs the prompt took, and what a block
+                # that counts (engine.cfg.step_counters) counted over
+                # them; how long the request waited for a row, the
+                # dispatch's host time, the wait behind the steps in
+                # flight
+                counted = self.engine.last_prefill
+                fill.note(queued_us=round(queued * 1e6),
+                          launch_us=round(launch_s * 1e6),
+                          behind_us=round(behind * 1e6), **counted)
+            self._n_prefills += 1
             self.stats.note_prefill(len(tokens) - start, dt,
                                     readmission=seq.preempted)
-            # how many programs the prompt took, and what a block that
-            # counts (engine.cfg.step_counters) counted over them
-            counted = self.engine.last_prefill
             self.stats.note_counters(counted)
             if seq.side:
                 self.stats.note_released(self.engine.last_released)
-            _trace.record_span(
-                "decoding.prefill", seq.trace_id, t0, t0 + dt,
-                {"model": self.key, "tokens": len(tokens),
-                 "cached_tokens": start, "pages": need_total,
-                 "pages_reused": len(matched),
-                 "readmission": seq.preempted, **counted})
             seq.length = len(tokens)
             if self.cache is not None:
                 # publish this prompt's full pages (existing runs keep
@@ -806,6 +821,32 @@ class ContinuousScheduler:
                 seq.last_token = seq.generated[-1]
             else:
                 self._handle_token(seq, int(first))
+
+    def _launch_prefill(self, seq, tokens, start):
+        """A sequence's prefill dispatched, every chunk of it: returns
+        what `fetch_prefill` takes and the dispatch's host seconds."""
+        t0 = _trace.now()
+        launched = self.engine.launch_prefill(
+            tokens, [seq.table] + seq.side if seq.side else seq.table,
+            start=start,
+            seed=seq.sampling.seed,
+            temperature=seq.sampling.temperature,
+            top_k=seq.sampling.top_k, top_p=seq.sampling.top_p)
+        return launched, _trace.now() - t0
+
+    def _admit_turn(self):
+        """A turn's decisions and admissions as one `decoding.admit`
+        span: deadlines, cancels, admission, page growth. The span says
+        how many prefills it launched and how many launched steps it
+        took out (`prefills`, `drained`)."""
+        prefills, retired = self._n_prefills, self._n_retired
+        with _trace.span("decoding.admit") as admit_span:
+            self._check_deadlines(time.monotonic())
+            self._check_cancelled()
+            self._admit()
+            self._grow()
+            admit_span.note(prefills=self._n_prefills - prefills,
+                            drained=self._n_retired - retired)
 
     # ------------------------------------------------------------ growth
     def _grow(self):
@@ -971,7 +1012,7 @@ class ContinuousScheduler:
                 tail_rows, bucket = self._pack(live)
             step_attrs = self._step_attrs(live, bucket, lengths, active,
                                           samp[1], k + 1)
-        with _trace.span("decoding.step", in_flight=0,
+        with _trace.span("decoding.step", in_flight=0, queued=0,
                          **step_attrs) as step_span:
             t0 = _trace.now()
             if spec:
@@ -1230,6 +1271,7 @@ class ContinuousScheduler:
         `decoding.step` span that holds it."""
         engine = self.engine
         live, out, attrs, t_launch = self._ahead.popleft()
+        self._n_retired += 1
         host = engine.fetch_step(out, engine.max_batch)
         t_out = _trace.now()
         # steps still launched while these tokens came out
@@ -1288,15 +1330,15 @@ class ContinuousScheduler:
         if pending == "settle":
             self._settle()
         if pending is not None:
-            with _trace.span("decoding.admit"):
-                self._check_deadlines(time.monotonic())
-                self._check_cancelled()
-                self._admit()
-                self._grow()
+            self._admit_turn()
             self._settle()
         if not any(self._rows):
             return
-        with _trace.span("decoding.step") as step_span:
+        # `queued`: the steps already in flight at the turn's first
+        # launch; 0 where the device waits for this turn's pack and
+        # launch
+        with _trace.span("decoding.step",
+                         queued=len(self._ahead)) as step_span:
             while len(self._ahead) <= self.run_ahead \
                     and self._launch_ahead():
                 pass
@@ -1337,16 +1379,12 @@ class ContinuousScheduler:
                 return
             try:
                 # one turn = decoding.admit, then _step's three spans;
-                # prefills launched by _admit record decoding.prefill
-                # inside (parent decoding.admit)
+                # each prefill _admit launches is a decoding.prefill
+                # span inside it
                 if self.run_ahead:
                     self._turn_ahead()
                     continue
-                with _trace.span("decoding.admit"):
-                    self._check_deadlines(time.monotonic())
-                    self._check_cancelled()
-                    self._admit()
-                    self._grow()
+                self._admit_turn()
                 self._step()
             except Exception as exc:  # never kill the loop silently
                 # what was launched is dropped unread with its rows

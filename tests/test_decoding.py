@@ -1413,6 +1413,145 @@ def test_reply_span_of_a_cancelled_request_is_the_handoff_alone():
     assert reply.t0 > t_submit + lifetime / 2
 
 
+# ------------------------------------------------ the admission's spans
+def _loop_spans(run, **kw):
+    """The ring's spans of one decoder's life: `run(m)` drives it."""
+    from mxnet_tpu.telemetry import trace as ttrace
+
+    ttrace.set_capacity(8192)
+    try:
+        m = _model(merged_step=False, **kw)
+        try:
+            run(m)
+        finally:
+            m.close()
+        return m, ttrace.recent_spans()
+    finally:
+        ttrace.set_capacity(ttrace._env_capacity())
+
+
+def _mixed_budgets(m):
+    """More requests than rows with budgets of their own, so that rows
+    free up one at a time while the others decode."""
+    jobs = [([3, 4, 5], 14), ([9, 8, 7, 6], 5), ([2, 6], 9),
+            ([5, 5, 5, 5, 5], 3), ([7, 3], 11), ([4, 9, 2], 6)]
+    futs = [m.submit(p, max_new_tokens=n) for p, n in jobs]
+    assert [len(f.result(120)) for f in futs] == [n for _, n in jobs]
+
+
+@pytest.mark.parametrize("run_ahead", [0, 3])
+def test_prefill_span_is_in_a_profiler_capture_under_its_own_name(
+        run_ahead, tmp_path):
+    """`decoding.prefill` is a `with` block: a capture's host plane
+    holds every one under its own name, as long as the ring's record
+    of it, and its parent is still `decoding.admit`."""
+    from mxnet_tpu.profiling import timeline
+
+    def run(m):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _mixed_budgets(m)
+        finally:
+            jax.profiler.stop_trace()
+
+    _m, spans = _loop_spans(run, max_tokens=16, run_ahead=run_ahead,
+                            page_buckets=(1, 2, 4, 8))
+    fills = sorted((s for s in spans if s.name == "decoding.prefill"),
+                   key=lambda s: s.t0)
+    assert len(fills) == 6
+    assert {s.parent for s in fills} == {"decoding.admit"}
+    host = sorted((t0, t1) for n, t0, t1 in
+                  timeline.read_xplane(str(tmp_path))["host"]
+                  if n == "decoding.prefill")
+    assert len(host) == len(fills)
+    diffs = sorted(abs((b - a) - (s.t1 - s.t0))
+                   for (a, b), s in zip(host, fills))
+    assert diffs[len(diffs) // 2] < 200e-6, diffs
+
+
+@pytest.mark.parametrize("run_ahead", [0, 3])
+def test_admission_spans_say_what_a_first_token_waited_for(run_ahead):
+    """`decoding.prefill` says how long its request waited for a row
+    (`queued_us`, from its submit), the prefill's dispatch (`launch_us`)
+    and its wait behind the steps in flight (`behind_us`: none in the
+    waited-for turn); `decoding.admit` counts the prefills it launched
+    and the launched steps it took out; `decoding.step` says how many
+    steps were in flight at the turn's first launch: none after an
+    admission, at least one on a turn that follows a turn."""
+    _m, spans = _loop_spans(_mixed_budgets, max_tokens=16,
+                            run_ahead=run_ahead, page_buckets=(1, 2, 4, 8))
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    submitted = {s.trace_id: s.t0 for s in by["decoding.submit"]}
+    fills = by["decoding.prefill"]
+    assert len(fills) == 6
+    for s in fills:
+        a = s.attrs
+        assert not a["readmission"]
+        assert min(a["queued_us"], a["launch_us"], a["behind_us"]) >= 0
+        assert all(isinstance(a[k], int)
+                   for k in ("queued_us", "launch_us", "behind_us"))
+        # taken from the queue after its submit and before its span
+        assert a["queued_us"] * 1e-6 <= s.t0 - submitted[s.trace_id] + 1e-6
+        if not run_ahead:
+            assert a["behind_us"] == 0
+    admits = by["decoding.admit"]
+    assert sum(a.attrs["prefills"] for a in admits) == len(fills)
+    # what an admission took out are the step spans that lie inside it
+    nested = sum(1 for s in by["decoding.step"] for a in admits
+                 if a.t0 <= s.t0 and s.t1 <= a.t1)
+    assert sum(a.attrs["drained"] for a in admits) == nested
+    turns = sorted(admits + [s for s in by["decoding.step"]
+                             if "queued" in s.attrs], key=lambda s: s.t0)
+    follows = {"decoding.admit": [], "decoding.step": []}
+    for prev, cur in zip(turns, turns[1:]):
+        if cur.name == "decoding.step":
+            follows[prev.name].append(cur.attrs["queued"])
+    assert follows["decoding.admit"] and set(
+        follows["decoding.admit"]) == {0}
+    if run_ahead:
+        # some request was admitted with the steps still in flight
+        assert max(s.attrs["behind_us"] for s in fills) > 0
+        assert nested > 0
+        assert follows["decoding.step"] \
+            and min(follows["decoding.step"]) >= 1
+    else:
+        assert nested == 0 and not follows["decoding.step"]
+        assert {s.attrs["queued"] for s in by["decoding.step"]} == {0}
+
+
+@pytest.mark.parametrize("run_ahead", [0, 3])
+def test_a_readmission_waits_from_its_preemption(run_ahead):
+    """A preempted request's `queued_us` runs from its preemption, not
+    from its submit: shorter than the time since its first prefill."""
+    def run(m):
+        prompts = [[int(t) for t in
+                    np.random.RandomState(i).randint(2, 32, size=6)]
+                   for i in range(6)]
+        futs = [m.submit(p, max_new_tokens=10, priority=i % 2)
+                for i, p in enumerate(prompts)]
+        for f in futs:
+            f.result(240)
+        assert m.stats.snapshot()["preemptions"] > 0
+
+    _m, spans = _loop_spans(run, max_batch=4, num_pages=9, max_tokens=12,
+                            queue_cap=64, run_ahead=run_ahead)
+    first = {}
+    again = []
+    for s in sorted(spans, key=lambda s: s.t0):
+        if s.name != "decoding.prefill":
+            continue
+        if s.attrs["readmission"]:
+            again.append(s)
+        else:
+            first.setdefault(s.trace_id, s)
+    assert again
+    for s in again:
+        assert 0 <= s.attrs["queued_us"] * 1e-6 \
+            < s.t0 - first[s.trace_id].t1
+
+
 # ------------------------------------------------- ragged attention
 def test_ragged_kernel_mixed_prefill_decode_matches_dense():
     """ONE fixed-shape ragged call serving decode rows (full context)
